@@ -14,6 +14,7 @@ import time
 
 import numpy as np
 
+import repro
 from benchmarks.common import emit, get_task, node_spec, run_fl
 
 
@@ -96,7 +97,6 @@ def fig7_divergence(full: bool = False) -> None:
 def method_ablation(full: bool = False) -> None:
     """Beyond-paper ablation: FedAvg vs FedProx (mu=0.1) vs FedAdp on the
     5 IID + 5 one-class split (rounds to 85%)."""
-    import repro
     from repro.data import synthetic
 
     train, test = get_task()
@@ -206,7 +206,6 @@ def engine_ab(full: bool = False, tiny: bool = False) -> None:
     import jax
     import jax.numpy as jnp
 
-    import repro
     from benchmarks.roofline import flat_round_hbm_bound_us
 
     ks = (4, 8) if tiny else (8, 32, 64, 128)
@@ -215,7 +214,7 @@ def engine_ab(full: bool = False, tiny: bool = False) -> None:
     engines = ["tree", "flat"]
     mesh = None
     if jax.device_count() > 1:
-        mesh = jax.make_mesh((jax.device_count(),), ("data",))
+        mesh = repro.make_mesh((jax.device_count(),), ("data",))
         engines.append("flat_sharded")
     mode = (
         "interpret-correctness-path"
@@ -301,7 +300,7 @@ def engine_ab(full: bool = False, tiny: bool = False) -> None:
         )
         for cdim in sorted({2, dc // 2}):
             mdim = dc // cdim
-            m2 = jax.make_mesh((cdim, mdim), ("data", "model"))
+            m2 = repro.make_mesh((cdim, mdim), ("data", "model"))
             hbm2 = flat_round_hbm_bound_us(K2, n2, devices=dc)
             with m2:
                 for engine in ("tree", "flat_sharded"):
@@ -381,7 +380,6 @@ def transport_sweep(full: bool = False, tiny: bool = False) -> None:
     import jax.numpy as jnp
 
     from repro import transport as transport_mod
-    import repro
 
     ks = (4, 8) if tiny else (8, 32, 64, 128)
     d = 1 << 10 if tiny else (1 << 16 if full else 1 << 14)
@@ -613,7 +611,6 @@ def driver_ab(full: bool = False, tiny: bool = False) -> None:
     """
     import json
 
-    import repro
     from repro.data import synthetic
 
     ks = (4, 8) if tiny else (8, 32, 64, 128)
@@ -777,6 +774,7 @@ def main() -> None:
     ap.add_argument("--tiny", action="store_true", help="CI smoke shapes")
     ap.add_argument("--only", default=None, choices=sorted(BENCHES))
     args = ap.parse_args()
+    repro.use_compile_cache()
     names = [args.only] if args.only else list(BENCHES)
     print("name,us_per_call,derived")
     for name in names:

@@ -30,6 +30,7 @@ from repro.core.server import (  # noqa: F401
     fixed_arrival_schedule,
 )
 from repro import telemetry  # noqa: F401
+from repro.launch.mesh import make_mesh, use_compile_cache  # noqa: F401
 from repro.telemetry.manifest import run_manifest  # noqa: F401
 from repro.telemetry.sinks import (  # noqa: F401
     CSVSink,
@@ -49,9 +50,11 @@ __all__ = [
     "SpanTimer",
     "fixed_arrival_schedule",
     "init_round_state",
+    "make_mesh",
     "make_round_fn",
     "run_manifest",
     "state_from_tree",
     "state_to_tree",
     "telemetry",
+    "use_compile_cache",
 ]

@@ -170,9 +170,6 @@ class FLConfig:
     # (transport.downlink.init_downlink_error_feedback) allocated by
     # `init_round_state` and updated by round_fn each round.
     downlink_error_feedback: bool = False
-    # Pallas interpret mode for engine="flat": None = auto (interpret
-    # everywhere except a real TPU backend), or force True/False.
-    interpret: Optional[bool] = None
     # beyond-paper: restrict angle statistics to non-expert parameters —
     # MoE routing makes expert deltas sparse/noisy, polluting the cosine.
     angle_filter: str = "all"  # all | dense_only
@@ -761,12 +758,6 @@ def _lr_at(fl: FLConfig, round_idx):
     return fl.base_lr * fl.lr_decay ** jnp.asarray(round_idx, jnp.float32)
 
 
-def _resolve_interpret(fl: FLConfig) -> bool:
-    if fl.interpret is not None:
-        return fl.interpret
-    return jax.default_backend() != "tpu"
-
-
 def _weight_entropy(w):
     """Shannon entropy of the (re-normalized) aggregation weights — a
     one-scalar collapse detector: ln K under FedAvg-with-equal-sizes,
@@ -881,7 +872,7 @@ def _make_parallel_round(loss_fn, fl: FLConfig, delta_constraint, angle_pred=Non
         if not wire_2d:
             round_ops = fl_shard_map.make_round_ops(
                 mesh, alpha=fl.alpha, method=fl.method,
-                interpret=_resolve_interpret(fl), transport=fl.transport,
+                transport=fl.transport,
                 group_size=fl.group_size)
             row_sharding = fl_shard_map.flat_client_sharding(mesh)
     elif wire_2d:
@@ -1019,7 +1010,7 @@ def _make_parallel_round(loss_fn, fl: FLConfig, delta_constraint, angle_pred=Non
             round_ops_2d = fl_shard_map.make_round_ops_2d(
                 mesh, deltas_p, _derive_param_pspecs(params, mesh),
                 alpha=fl.alpha, method=fl.method,
-                interpret=_resolve_interpret(fl), transport=fl.transport,
+                transport=fl.transport,
                 group_size=fl.group_size, keep=keep)
             # padded rows: zero deltas, zero data size -> -inf softmax
             # logit -> exactly zero weight and zero stats contribution.
@@ -1073,7 +1064,6 @@ def _make_parallel_round(loss_fn, fl: FLConfig, delta_constraint, angle_pred=Non
             # (chunked over the client axis, so any K fits the VMEM
             # envelope). Quantized wire buffers flow through the
             # fused-dequant kernel variants untouched.
-            interpret = _resolve_interpret(fl)
             if fl.transport == "f32":
                 flat, unravel = treemath.tree_ravel_stacked(deltas)
                 wire_x, wire_s = flat, None
@@ -1084,26 +1074,25 @@ def _make_parallel_round(loss_fn, fl: FLConfig, delta_constraint, angle_pred=Non
             def agg_wire(wvec):
                 if wire_s is None:
                     return weighted_agg_mod.weighted_agg(
-                        wvec, wire_x, interpret=interpret,
-                        out_dtype=jnp.float32)
+                        wvec, wire_x, out_dtype=jnp.float32)
                 if fl.transport == "int4":
                     return weighted_agg_mod.weighted_agg_q4(
                         wvec, wire_x, wire_s, n=flat0.shape[1],
-                        group_size=fl.group_size, interpret=interpret)
+                        group_size=fl.group_size)
                 return weighted_agg_mod.weighted_agg_q(
-                    wvec, wire_x, wire_s, interpret=interpret)
+                    wvec, wire_x, wire_s)
 
             g_flat = agg_wire(psi_avg)
             if wire_s is None:
                 dots, sqs, sqg = round_stats_mod.round_stats(
-                    wire_x, g_flat, maskv, interpret=interpret)
+                    wire_x, g_flat, maskv)
             elif fl.transport == "int4":
                 dots, sqs, sqg = round_stats_mod.round_stats_q4(
                     wire_x, wire_s, g_flat, maskv,
-                    group_size=fl.group_size, interpret=interpret)
+                    group_size=fl.group_size)
             else:
                 dots, sqs, sqg = round_stats_mod.round_stats_q(
-                    wire_x, wire_s, g_flat, maskv, interpret=interpret)
+                    wire_x, wire_s, g_flat, maskv)
             g_avg = unravel(g_flat, jnp.float32)
             theta = weighting.instantaneous_angle(dots, sqs, sqg)
         else:
@@ -1203,8 +1192,7 @@ def _make_buffered_round(loss_fn, fl: FLConfig, delta_constraint,
         # wire compression happens at admission (the buffer holds
         # dequantized f32 rows), so the flush region never needs scales.
         flush_ops = fl_shard_map.make_buffered_flush_ops(
-            mesh, alpha=fl.alpha, method=fl.method, beta=fl.staleness_beta,
-            interpret=_resolve_interpret(fl))
+            mesh, alpha=fl.alpha, method=fl.method, beta=fl.staleness_beta)
         row_sharding = fl_shard_map.flat_client_sharding(mesh)
         csize = fl_shard_map.client_axis_size(mesh)
         # 2D (client x model) mesh: the flush region also tiles the
@@ -1391,11 +1379,10 @@ def _make_buffered_round(loss_fn, fl: FLConfig, delta_constraint,
             g_avg = unravel0(g_flat, jnp.float32)
             delta = unravel0(delta_flat)
         elif fl.engine == "flat":
-            interpret = _resolve_interpret(fl)
             g_flat = weighted_agg_mod.weighted_agg(
-                psi_b, buf.data, interpret=interpret, out_dtype=jnp.float32)
+                psi_b, buf.data, out_dtype=jnp.float32)
             dots, sqs, sqg = round_stats_mod.round_stats(
-                buf.data, g_flat, maskv, interpret=interpret)
+                buf.data, g_flat, maskv)
             g_avg = unravel0(g_flat, jnp.float32)
             theta = weighting.instantaneous_angle(dots, sqs, sqg)
         else:
@@ -1428,8 +1415,7 @@ def _make_buffered_round(loss_fn, fl: FLConfig, delta_constraint,
             if fl.engine == "flat":
                 delta_flat = (g_flat if fl.method != "fedadp" else
                               weighted_agg_mod.weighted_agg(
-                                  w, buf.data, interpret=interpret,
-                                  out_dtype=jnp.float32))
+                                  w, buf.data, out_dtype=jnp.float32))
                 delta = unravel0(delta_flat)
             else:
                 delta = jax.tree.map(
@@ -1487,7 +1473,6 @@ def _make_sequential_round(loss_fn, fl: FLConfig, angle_pred=None,
         params, angle_state = state.params, state.angle
         prev_delta = state.prev_delta
         lr = _lr_at(fl, state.round)
-        interpret = _resolve_interpret(fl)
         # one stats implementation across modes: pass-2 statistics stream
         # through the round_stats kernel as a single-row (1, N) buffer per
         # scan step, with the MoE angle filter as a flat segment mask.
@@ -1524,7 +1509,7 @@ def _make_sequential_round(loss_fn, fl: FLConfig, angle_pred=None,
                                      grad_constraint)
             d_flat, _ = treemath.tree_ravel(d_i)
             dots_i, sqs_i, sqg_i = round_stats_mod.round_stats(
-                d_flat[None], g_flat, maskv, interpret=interpret)
+                d_flat[None], g_flat, maskv)
             dot, sq = dots_i[0], sqs_i[0]
             theta_i = weighting.instantaneous_angle(dot, sq, sqg_i)
             cnt = angle_state.count[idx_i].astype(jnp.float32) + 1.0

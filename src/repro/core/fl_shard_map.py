@@ -103,19 +103,6 @@ def flat_client_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P(caxes if len(caxes) > 1 else caxes[0]))
 
 
-def _shard_map(body, mesh: Mesh, in_specs, out_specs):
-    """shard_map across jax versions (check_vma / check_rep spelling)."""
-    try:
-        smap = jax.shard_map
-    except AttributeError:  # older jax
-        from jax.experimental.shard_map import shard_map as smap
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        return smap(body, check_vma=False, **kw)
-    except TypeError:  # jax < 0.6 spells it check_rep
-        return smap(body, check_rep=False, **kw)
-
-
 def _client_axis(mesh: Mesh):
     caxes = _client_axes(mesh)
     return caxes if len(caxes) > 1 else caxes[0]
@@ -127,7 +114,7 @@ def _shard_slots(values, caxis):
     return jax.lax.axis_index(caxis) * k_loc + jnp.arange(k_loc)
 
 
-def _shard_agg(w_loc, values, scales, interpret, *, transport, n,
+def _shard_agg(w_loc, values, scales, *, transport, n,
                group_size):
     """Per-shard weighted aggregation over the local rows, f32 out.
 
@@ -139,31 +126,29 @@ def _shard_agg(w_loc, values, scales, interpret, *, transport, n,
     """
     if scales is None:
         return weighted_agg_mod.weighted_agg(
-            w_loc, values, interpret=interpret, out_dtype=jnp.float32)
+            w_loc, values, out_dtype=jnp.float32)
     if transport == "int4":
         return weighted_agg_mod.weighted_agg_q4(
-            w_loc, values, scales, n=n, group_size=group_size,
-            interpret=interpret)
+            w_loc, values, scales, n=n, group_size=group_size)
     return weighted_agg_mod.weighted_agg_q(
-        w_loc, values, scales, interpret=interpret)
+        w_loc, values, scales)
 
 
-def _shard_stats(values, scales, g_flat, mask, interpret, *, transport,
+def _shard_stats(values, scales, g_flat, mask, *, transport,
                  group_size):
     """Per-shard fused angle statistics over the local rows."""
     if scales is None:
         return round_stats_mod.round_stats(
-            values, g_flat, mask, interpret=interpret)
+            values, g_flat, mask)
     if transport == "int4":
         return round_stats_mod.round_stats_q4(
-            values, scales, g_flat, mask, group_size=group_size,
-            interpret=interpret)
+            values, scales, g_flat, mask, group_size=group_size)
     return round_stats_mod.round_stats_q(
-        values, scales, g_flat, mask, interpret=interpret)
+        values, scales, g_flat, mask)
 
 
 def make_round_ops(mesh: Mesh, *, alpha: float, method: str = "fedadp",
-                   interpret: bool = True, transport: str = "f32",
+                   transport: str = "f32",
                    group_size: int = 0):
     """The whole aggregation round as ONE shard_map call.
 
@@ -211,10 +196,10 @@ def make_round_ops(mesh: Mesh, *, alpha: float, method: str = "fedadp",
         n = mask.shape[0]  # logical width (!= packed width for int4)
         kw = dict(transport=transport, group_size=group_size)
         g_flat = jax.lax.psum(
-            _shard_agg(psi[my], values, scales, interpret, n=n, **kw),
+            _shard_agg(psi[my], values, scales, n=n, **kw),
             caxis)
         d_loc, s_loc, sqg = _shard_stats(values, scales, g_flat, mask,
-                                         interpret, **kw)
+                                         **kw)
         k = psi.shape[0]
         dots = jax.lax.psum(
             jnp.zeros((k,), jnp.float32).at[my].set(d_loc), caxis)
@@ -226,7 +211,7 @@ def make_round_ops(mesh: Mesh, *, alpha: float, method: str = "fedadp",
         if method == "fedadp":
             w = weighting.fedadp_weights(theta_sm, data_sizes, alpha)
             delta_flat = jax.lax.psum(
-                _shard_agg(w[my], values, scales, interpret, n=n, **kw),
+                _shard_agg(w[my], values, scales, n=n, **kw),
                 caxis)
         else:  # w == psi: the stats' aggregate IS the round delta
             w = psi
@@ -235,12 +220,12 @@ def make_round_ops(mesh: Mesh, *, alpha: float, method: str = "fedadp",
 
     outs = (P(),) * 8
     if transport in ("int8", "int4"):
-        return _shard_map(_body, mesh,
-                          in_specs=(row_spec, row_spec) + (P(),) * 5,
-                          out_specs=outs)
-    return _shard_map(
-        lambda values, *rest: _body(values, None, *rest), mesh,
-        in_specs=(row_spec,) + (P(),) * 5, out_specs=outs)
+        return jax.shard_map(_body, mesh=mesh,
+                             in_specs=(row_spec, row_spec) + (P(),) * 5,
+                             out_specs=outs, check_vma=False)
+    return jax.shard_map(
+        lambda values, *rest: _body(values, None, *rest), mesh=mesh,
+        in_specs=(row_spec,) + (P(),) * 5, out_specs=outs, check_vma=False)
 
 
 def _spec_tree(pspecs):
@@ -291,7 +276,7 @@ def _blocked_unstack_local(vec, layout, *, dtypes=None, gather_rows=False):
 
 def make_round_ops_2d(mesh: Mesh, template_stacked: PyTree, pspecs: PyTree,
                       *, alpha: float, method: str = "fedadp",
-                      interpret: bool = True, transport: str = "f32",
+                      transport: str = "f32",
                       group_size: int = 0, keep=None):
     """`make_round_ops` on a 2D (client x model) mesh — tree in, tree out.
 
@@ -354,10 +339,10 @@ def make_round_ops_2d(mesh: Mesh, template_stacked: PyTree, pspecs: PyTree,
             values, scales = q.values, q.scales
         my = _shard_slots(x, caxis)
         g_loc = jax.lax.psum(
-            _shard_agg(psi[my], values, scales, interpret, n=n_loc, **kw),
+            _shard_agg(psi[my], values, scales, n=n_loc, **kw),
             caxis)
         d_loc, s_loc, sqg_loc = _shard_stats(values, scales, g_loc,
-                                             mask_const, interpret, **kw)
+                                             mask_const, **kw)
         kp = psi.shape[0]
         both = caxes + (MODEL_AXIS,)
         dots = jax.lax.psum(
@@ -371,7 +356,7 @@ def make_round_ops_2d(mesh: Mesh, template_stacked: PyTree, pspecs: PyTree,
         if method == "fedadp":
             w = weighting.fedadp_weights(theta_sm, data_sizes, alpha)
             delta_loc = jax.lax.psum(
-                _shard_agg(w[my], values, scales, interpret, n=n_loc, **kw),
+                _shard_agg(w[my], values, scales, n=n_loc, **kw),
                 caxis)
         else:  # w == psi: the stats' aggregate IS the round delta
             w = psi
@@ -388,7 +373,8 @@ def make_round_ops_2d(mesh: Mesh, template_stacked: PyTree, pspecs: PyTree,
     unstacked = tree_of(spec_leaves)
     in_specs = (_stacked_specs(pspecs, caxis), P(), P(), P(), P())
     out_specs = (unstacked, P(), P(), P(), unstacked, P(), P(), P())
-    return _shard_map(_body, mesh, in_specs, out_specs)
+    return jax.shard_map(_body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_blocked_roundtrip(mesh: Mesh, template_stacked: PyTree,
@@ -435,12 +421,12 @@ def make_blocked_roundtrip(mesh: Mesh, template_stacked: PyTree,
                                    gather_rows=True))
 
     stacked = _stacked_specs(pspecs, caxis)
-    return _shard_map(_body, mesh, in_specs=(stacked,), out_specs=stacked)
+    return jax.shard_map(_body, mesh=mesh, in_specs=(stacked,),
+                         out_specs=stacked, check_vma=False)
 
 
 def make_buffered_flush_ops(mesh: Mesh, *, alpha: float,
-                            method: str = "fedadp", beta: float = 0.0,
-                            interpret: bool = True):
+                            method: str = "fedadp", beta: float = 0.0):
     """The buffered-async flush as ONE shard_map call (core/fl.py's
     aggregation="buffered" under engine="flat_sharded").
 
@@ -482,11 +468,10 @@ def make_buffered_flush_ops(mesh: Mesh, *, alpha: float,
         my = _shard_slots(values, caxis)
         g_flat = jax.lax.psum(
             weighted_agg_mod.weighted_agg(
-                psi[my], values, interpret=interpret,
-                out_dtype=jnp.float32),
+                psi[my], values, out_dtype=jnp.float32),
             caxis)
         d_loc, s_loc, sqg = round_stats_mod.round_stats(
-            values, g_flat, mask, interpret=interpret)
+            values, g_flat, mask)
         k = psi.shape[0]
         dots = jax.lax.psum(
             jnp.zeros((k,), jnp.float32).at[my].set(d_loc), stat_axes)
@@ -504,23 +489,25 @@ def make_buffered_flush_ops(mesh: Mesh, *, alpha: float,
             w = weighting.buffered_fedavg_weights(sizes, age, landed, beta)
         delta_flat = jax.lax.psum(
             weighted_agg_mod.weighted_agg(
-                w[my], values, interpret=interpret, out_dtype=jnp.float32),
+                w[my], values, out_dtype=jnp.float32),
             caxis)
         return g_flat, dots, sqs, sqg, delta_flat, theta, theta_sm, w
 
     if msize > 1:
         col = P(MODEL_AXIS)
-        return _shard_map(
-            _body, mesh,
+        return jax.shard_map(
+            _body, mesh=mesh,
             in_specs=(P(caxis, MODEL_AXIS), P(), col) + (P(),) * 5,
-            out_specs=(col, P(), P(), P(), col, P(), P(), P()))
-    return _shard_map(_body, mesh, in_specs=(P(caxis),) + (P(),) * 7,
-                      out_specs=(P(),) * 8)
+            out_specs=(col, P(), P(), P(), col, P(), P(), P()),
+            check_vma=False)
+    return jax.shard_map(_body, mesh=mesh,
+                         in_specs=(P(caxis),) + (P(),) * 7,
+                         out_specs=(P(),) * 8, check_vma=False)
 
 
 def fedadp_aggregate(mesh: Mesh, delta_pspecs: PyTree, *, alpha: float,
                      method: str = "fedadp", engine: str = "tree",
-                     interpret: bool = True, transport: str = "f32",
+                     transport: str = "f32",
                      group_size: int = 0):
     """Build an aggregation fn over K-stacked deltas.
 
@@ -531,9 +518,8 @@ def fedadp_aggregate(mesh: Mesh, delta_pspecs: PyTree, *, alpha: float,
     model-axis-sharded leaves; engine="flat" ravels the stacked tree into a
     client-row-sharded (K, N) buffer and runs the fused Pallas kernels per
     shard in ONE shard_map region (`make_round_ops`) — it requires
-    client-only sharding and is the large-cohort fast path. `interpret` is
-    the Pallas interpret switch for the flat engine (True off-TPU);
-    `transport` (flat engine only) compresses the buffer to the wire dtype
+    client-only sharding and is the large-cohort fast path. `transport`
+    (flat engine only) compresses the buffer to the wire dtype
     before aggregation (repro.transport; f32 is the reference wire).
 
     Returns agg(deltas, data_sizes, smoothed_prev, count_prev) ->
@@ -544,8 +530,7 @@ def fedadp_aggregate(mesh: Mesh, delta_pspecs: PyTree, *, alpha: float,
     """
     if engine == "flat":
         return _fedadp_aggregate_flat(mesh, delta_pspecs, alpha=alpha,
-                                      method=method, interpret=interpret,
-                                      transport=transport,
+                                      method=method, transport=transport,
                                       group_size=group_size)
     if engine != "tree":
         raise ValueError(f"unknown engine {engine!r}")
@@ -642,11 +627,12 @@ def fedadp_aggregate(mesh: Mesh, delta_pspecs: PyTree, *, alpha: float,
     )
     in_specs = (tree_of(spec_leaves), P(), P(), P())
     out_specs = (tree_of(out_specs_leaves), P(), P(), P())
-    return _shard_map(body, mesh, in_specs, out_specs)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _fedadp_aggregate_flat(mesh: Mesh, delta_pspecs: PyTree, *, alpha: float,
-                           method: str, interpret: bool,
+                           method: str,
                            transport: str = "f32", group_size: int = 0):
     """The flat engine behind `fedadp_aggregate(engine="flat")`.
 
@@ -673,7 +659,7 @@ def _fedadp_aggregate_flat(mesh: Mesh, delta_pspecs: PyTree, *, alpha: float,
     if model_axis_size(mesh) > 1:
         return _fedadp_aggregate_flat_2d(
             mesh, delta_pspecs, alpha=alpha, method=method,
-            interpret=interpret, transport=transport, group_size=group_size)
+            transport=transport, group_size=group_size)
     for s in spec_leaves:
         if any(e is not None for e in tuple(s)[1:]):
             raise ValueError(
@@ -682,7 +668,7 @@ def _fedadp_aggregate_flat(mesh: Mesh, delta_pspecs: PyTree, *, alpha: float,
                 "(add a 'model' mesh axis for the 2D flat engine, or use "
                 "engine='tree' for model-axis-sharded leaves)")
     round_op = make_round_ops(mesh, alpha=alpha, method=method,
-                              interpret=interpret, transport=transport,
+                              transport=transport,
                               group_size=group_size)
     row_sharding = flat_client_sharding(mesh)
 
@@ -715,7 +701,7 @@ def _fedadp_aggregate_flat(mesh: Mesh, delta_pspecs: PyTree, *, alpha: float,
 
 
 def _fedadp_aggregate_flat_2d(mesh: Mesh, delta_pspecs: PyTree, *,
-                              alpha: float, method: str, interpret: bool,
+                              alpha: float, method: str,
                               transport: str, group_size: int):
     """`fedadp_aggregate(engine="flat")` on a (client x model) mesh."""
     spec_leaves = jax.tree.leaves(delta_pspecs,
@@ -735,7 +721,7 @@ def _fedadp_aggregate_flat_2d(mesh: Mesh, delta_pspecs: PyTree, *,
                 "engine='tree'")
         round_op = make_round_ops_2d(
             mesh, deltas, pspecs, alpha=alpha, method=method,
-            interpret=interpret, transport=transport,
+            transport=transport,
             group_size=group_size)
         psi_avg = weighting.fedavg_weights(data_sizes)
         _, _, _, _, delta_tree, theta, theta_sm, w = round_op(

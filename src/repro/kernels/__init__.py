@@ -1,3 +1,15 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Fused Pallas kernels for the flat round engine, with pure-jnp oracles
+in `ref.py`."""
+from __future__ import annotations
+
+import jax
+
+
+def interpret_mode(interpret: bool | None) -> bool:
+    """Resolve a kernel wrapper's `interpret` flag.
+
+    None (every wrapper's default) runs the Pallas interpreter on any
+    backend but a TPU, and compiles the kernel with Mosaic on a TPU. An
+    explicit bool wins: compile-only tests pass False to lower for a
+    described chip from a CPU process."""
+    return jax.default_backend() != "tpu" if interpret is None else interpret

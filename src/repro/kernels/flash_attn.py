@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -62,7 +64,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, blk_k: int, scale: float,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, blk_q: int = 128, blk_k: int = 128,
-                    interpret: bool = True):
+                    interpret=None):
     """q/k/v: (BH, T, d) (heads pre-flattened; GQA callers repeat kv).
 
     Returns (BH, T, d) in q's dtype. T must divide by blk_q and blk_k.
@@ -91,7 +93,7 @@ def _flash_fwd_impl(q, k, v, causal, blk_q, blk_k, interpret):
         ],
         out_specs=pl.BlockSpec((1, blk_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, d), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)
 
 
@@ -122,7 +124,7 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
 def gqa_flash(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
-              interpret: bool = True, blk_q: int = 128, blk_k: int = 128):
+              interpret=None, blk_q: int = 128, blk_k: int = 128):
     """GQA convenience wrapper: q (B,T,H,hd), k/v (B,T,G,hd) -> (B,T,H,hd)."""
     B, T, H, hd = q.shape
     G = k.shape[2]

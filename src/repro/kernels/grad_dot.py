@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 LANE = 128
 ROWS = 512  # 512*128*4 B = 256 KiB per input block
 
@@ -37,11 +39,10 @@ def _kernel(a_ref, b_ref, dot_ref, na_ref, nb_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def grad_dot_stats(a: jax.Array, b: jax.Array, *, interpret: bool = True):
+def grad_dot_stats(a: jax.Array, b: jax.Array, *, interpret=None):
     """(<a,b>, ||a||^2, ||b||^2) for equally-shaped arrays, f32 accumulate.
 
-    interpret=True runs the kernel body on CPU (container has no TPU);
-    on real hardware pass interpret=False.
+    `interpret` resolves through `repro.kernels.interpret_mode`.
     """
     assert a.shape == b.shape
     af = a.reshape(-1)
@@ -67,6 +68,6 @@ def grad_dot_stats(a: jax.Array, b: jax.Array, *, interpret: bool = True):
         ],
         out_specs=(scalar_spec, scalar_spec, scalar_spec),
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(a2, b2)
     return dot[0, 0], na[0, 0], nb[0, 0]

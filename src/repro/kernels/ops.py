@@ -1,8 +1,7 @@
 """Jitted tree-level wrappers over the Pallas kernels.
 
 These mirror `repro.core.treemath` but stream through the fused kernels —
-used by the FL aggregation layer when `use_pallas=True` (TPU) and by the
-kernel benchmarks. Trees are flattened leaf-by-leaf and the per-leaf
+used by the kernel benchmarks. Trees are flattened leaf-by-leaf and the per-leaf
 partial statistics are combined, so no concatenated copy of the parameter
 vector is ever materialized.
 """
@@ -18,7 +17,7 @@ from repro.kernels import grad_dot, weighted_agg
 PyTree = Any
 
 
-def tree_dot_and_norms(a: PyTree, b: PyTree, *, interpret: bool = True):
+def tree_dot_and_norms(a: PyTree, b: PyTree, *, interpret=None):
     dots, nas, nbs = [], [], []
     for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
         d, na, nb = grad_dot.grad_dot_stats(x, y, interpret=interpret)
@@ -32,7 +31,7 @@ def tree_dot_and_norms(a: PyTree, b: PyTree, *, interpret: bool = True):
     )
 
 
-def tree_weighted_sum(stacked: PyTree, w: jax.Array, *, interpret: bool = True):
+def tree_weighted_sum(stacked: PyTree, w: jax.Array, *, interpret=None):
     """sum_k w[k] * tree[k] for leaves with leading K axis."""
 
     def leaf(x):
@@ -43,7 +42,7 @@ def tree_weighted_sum(stacked: PyTree, w: jax.Array, *, interpret: bool = True):
     return jax.tree.map(leaf, stacked)
 
 
-def tree_vdot_batched(stacked: PyTree, single: PyTree, *, interpret: bool = True):
+def tree_vdot_batched(stacked: PyTree, single: PyTree, *, interpret=None):
     parts = []
     for x, g in zip(jax.tree.leaves(stacked), jax.tree.leaves(single)):
         parts.append(

@@ -1,35 +1,44 @@
-"""Pure-jnp oracles for the Pallas kernels (tests assert_allclose vs these)."""
+"""Oracles for the Pallas kernels (tests assert_allclose vs these).
+
+The reductions run in float64 NumPy over the f32 inputs, so an oracle's
+own rounding stays far below the kernels' f32 accumulation error, and
+does not depend on the host's reduction order."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+
+def _f64(a) -> np.ndarray:
+    return np.asarray(jnp.asarray(a, jnp.float32), np.float64)
+
+
+def _f32(a) -> jax.Array:
+    return jnp.asarray(np.asarray(a, np.float32))
 
 
 def grad_dot_stats(a: jax.Array, b: jax.Array):
-    af = a.reshape(-1).astype(jnp.float32)
-    bf = b.reshape(-1).astype(jnp.float32)
-    return jnp.dot(af, bf), jnp.dot(af, af), jnp.dot(bf, bf)
+    af, bf = _f64(a).reshape(-1), _f64(b).reshape(-1)
+    return _f32(af @ bf), _f32(af @ af), _f32(bf @ bf)
 
 
 def weighted_agg(w: jax.Array, x: jax.Array):
-    return jnp.sum(
-        w.astype(jnp.float32)[:, None] * x.astype(jnp.float32), axis=0
-    ).astype(x.dtype)
+    return _f32(_f64(w) @ _f64(x)).astype(x.dtype)
 
 
 def batched_dot(x: jax.Array, g: jax.Array):
-    return x.astype(jnp.float32) @ g.astype(jnp.float32)
+    return _f32(_f64(x) @ _f64(g))
 
 
 def round_stats(x: jax.Array, g: jax.Array, mask: jax.Array | None = None):
     """(dots (K,), sqnorms (K,), sqg ()) over x (K, N), g (N,)."""
-    xf = x.astype(jnp.float32)
-    gf = g.astype(jnp.float32)
+    xf, gf = _f64(x), _f64(g)
     if mask is not None:
-        mf = mask.astype(jnp.float32)
+        mf = _f64(mask)
         xf = xf * mf[None]
         gf = gf * mf
-    return xf @ gf, jnp.sum(xf * xf, axis=1), jnp.dot(gf, gf)
+    return _f32(xf @ gf), _f32(np.sum(xf * xf, axis=1)), _f32(gf @ gf)
 
 
 def _dequant(values: jax.Array, scales: jax.Array) -> jax.Array:
@@ -44,8 +53,7 @@ def _dequant(values: jax.Array, scales: jax.Array) -> jax.Array:
 
 def weighted_agg_q(w: jax.Array, values: jax.Array, scales: jax.Array):
     """Dequantize-then-f32 oracle for the fused weighted_agg_q kernel."""
-    x = _dequant(values, scales)
-    return jnp.sum(w.astype(jnp.float32)[:, None] * x, axis=0)
+    return weighted_agg(w, _dequant(values, scales))
 
 
 def round_stats_q(values: jax.Array, scales: jax.Array, g: jax.Array,
@@ -67,8 +75,7 @@ def _dequant4(values: jax.Array, scales: jax.Array, n: int,
 def weighted_agg_q4(w: jax.Array, values: jax.Array, scales: jax.Array, *,
                     n: int, group_size: int):
     """Dequantize-then-f32 oracle for the fused weighted_agg_q4 kernel."""
-    x = _dequant4(values, scales, n, group_size)
-    return jnp.sum(w.astype(jnp.float32)[:, None] * x, axis=0)
+    return weighted_agg(w, _dequant4(values, scales, n, group_size))
 
 
 def round_stats_q4(values: jax.Array, scales: jax.Array, g: jax.Array,

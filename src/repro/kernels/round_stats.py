@@ -12,9 +12,12 @@ pass over x.
 
 The client axis is chunked like `weighted_agg`: the grid is (client
 chunks, lane tiles) with the lane dimension minor, so each chunk's
-(K_TILE, 1) output blocks accumulate across consecutive lane steps, and
-sqg accumulates only on the first chunk (g is re-streamed per chunk but
-must be counted once). Any K is served; a ragged tail chunk (K % K_TILE
+output blocks accumulate across consecutive lane steps, and sqg
+accumulates only on the first chunk (g is re-streamed per chunk but
+must be counted once). The accumulators are lane-dense — (K_TILE, LANE)
+per-client partial sums and a (1, LANE) sqg row, reduced over the lanes
+after the kernel — since Mosaic stores neither scalars nor (K, 1)
+columns to VMEM efficiently. Any K is served; a ragged tail chunk (K % K_TILE
 != 0) is bounds-masked in-kernel, so the buffer is never copied to a
 zero-padded staging array.
 
@@ -35,7 +38,8 @@ in-register), and the server-side g / mask vectors ride along as even/odd
 (ROWS, LANE) views so every nibble pairs with its own g element without
 ever interleaving the wire buffer — one HBM pass over ~8x fewer bytes.
 
-`interpret=True` runs the identical kernel body on CPU.
+Off a TPU the identical kernel body runs in the Pallas interpreter
+(`repro.kernels.interpret_mode`).
 """
 from __future__ import annotations
 
@@ -47,22 +51,32 @@ from jax.experimental import pallas as pl
 
 # tile geometry and client-chunk size are shared with weighted_agg — the
 # (K_TILE, ROWS, LANE) x-tile here fits the same VMEM envelope.
+from repro.kernels import interpret_mode
 from repro.kernels.weighted_agg import (
     K_TILE,  # noqa: F401  (re-exported: callers size shards against it)
     LANE,
     ROWS,
     _expand_group_scales,
+    _group_scale_operand,
     _k_chunks,
     _mask_tail_rows,
     _pad_lanes,
     _row_block,
+    _tile_major,
     _unpack_nibbles,
     _use_fallback,
 )
 
 
-def _stats_kernel(x_ref, g_ref, dots_ref, sqs_ref, sqg_ref, *, k, tile):
-    kc, i = pl.program_id(0), pl.program_id(1)
+def _accumulate(outs, kc, i, planes):
+    """Fold one tile's (x (KT, R, LANE), g (R, LANE)) f32 plane pairs
+    into the (dots, sqs, sqg) accumulator refs.
+
+    dots/sqs are (KT, LANE) partial sums over the tile's rows; sqg a
+    (1, LANE) row. dots/sqs reset at each client chunk's first lane
+    tile; sqg resets once and sums g only on the first chunk (g repeats
+    per chunk but must be counted once)."""
+    dots_ref, sqs_ref, sqg_ref = outs
 
     @pl.when(i == 0)
     def _init():
@@ -71,47 +85,55 @@ def _stats_kernel(x_ref, g_ref, dots_ref, sqs_ref, sqg_ref, *, k, tile):
 
     @pl.when((kc == 0) & (i == 0))
     def _init_g():
-        sqg_ref[0, 0] = 0.0
+        sqg_ref[...] = jnp.zeros_like(sqg_ref)
 
-    x = _mask_tail_rows(x_ref[...].astype(jnp.float32), kc, k=k, tile=tile)
-    g = g_ref[...].astype(jnp.float32)  # (ROWS, LANE)
-    dots_ref[...] += jnp.sum(x * g[None], axis=(1, 2))[:, None]
-    sqs_ref[...] += jnp.sum(x * x, axis=(1, 2))[:, None]
-
-    @pl.when(kc == 0)  # g repeats per client chunk; count it once
-    def _accum_g():
-        sqg_ref[0, 0] += jnp.sum(g * g)
-
-
-def _stats_kernel_masked(x_ref, g_ref, m_ref, dots_ref, sqs_ref, sqg_ref,
-                         *, k, tile):
-    kc, i = pl.program_id(0), pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        dots_ref[...] = jnp.zeros_like(dots_ref)
-        sqs_ref[...] = jnp.zeros_like(sqs_ref)
-
-    @pl.when((kc == 0) & (i == 0))
-    def _init_g():
-        sqg_ref[0, 0] = 0.0
-
-    m = m_ref[...].astype(jnp.float32)  # (ROWS, LANE) in {0, 1}
-    x = _mask_tail_rows(x_ref[...].astype(jnp.float32) * m[None], kc,
-                        k=k, tile=tile)
-    g = g_ref[...].astype(jnp.float32) * m
-    dots_ref[...] += jnp.sum(x * g[None], axis=(1, 2))[:, None]
-    sqs_ref[...] += jnp.sum(x * x, axis=(1, 2))[:, None]
+    dots_ref[...] += sum(jnp.sum(x * g[None], axis=1) for x, g in planes)
+    sqs_ref[...] += sum(jnp.sum(x * x, axis=1) for x, _ in planes)
 
     @pl.when(kc == 0)
     def _accum_g():
-        sqg_ref[0, 0] += jnp.sum(g * g)
+        sqg_ref[...] += sum(jnp.sum(g * g, axis=0, keepdims=True)
+                            for _, g in planes)
+
+
+def _stats_call(kernel, operands, in_specs, *, k, tile, kp, rows, m,
+                interpret):
+    """Run a stats kernel over the (client chunk, lane tile) grid and
+    reduce its lane-dense accumulators to (dots (K,), sqs (K,), sqg ())."""
+    kvec_spec = pl.BlockSpec((tile, LANE), lambda kc, i: (kc, 0))
+    dots, sqs, sqg = pl.pallas_call(
+        kernel,
+        grid=(kp // tile, m // rows),
+        in_specs=in_specs,
+        out_specs=(kvec_spec, kvec_spec,
+                   pl.BlockSpec((1, LANE), lambda kc, i: (0, 0))),
+        out_shape=(
+            jax.ShapeDtypeStruct((kp, LANE), jnp.float32),
+            jax.ShapeDtypeStruct((kp, LANE), jnp.float32),
+            jax.ShapeDtypeStruct((1, LANE), jnp.float32),
+        ),
+        interpret=interpret_mode(interpret),
+    )(*operands)
+    return (jnp.sum(dots[:k], axis=1), jnp.sum(sqs[:k], axis=1),
+            jnp.sum(sqg))
+
+
+def _stats_kernel(*refs, k, tile, masked):
+    x_ref, g_ref = refs[:2]
+    kc, i = pl.program_id(0), pl.program_id(1)
+    x = x_ref[...].astype(jnp.float32)
+    g = g_ref[...].astype(jnp.float32)  # (R, LANE)
+    if masked:  # (R, LANE) in {0, 1}
+        mk = refs[2][...].astype(jnp.float32)
+        x, g = x * mk[None], g * mk
+    x = _mask_tail_rows(x, kc, k=k, tile=tile)
+    _accumulate(refs[-3:], kc, i, [(x, g)])
 
 
 @functools.partial(jax.jit,
                    static_argnames=("interpret", "min_kernel_elems"))
 def round_stats(x: jax.Array, g: jax.Array, mask: jax.Array | None = None,
-                *, interpret: bool = True, min_kernel_elems=None):
+                *, interpret=None, min_kernel_elems=None):
     """(dots (K,), sqnorms (K,), sqg ()) in one pass over x: (K, N), g: (N,).
 
     mask, if given, is an (N,) 0/1 vector; statistics are computed over the
@@ -129,107 +151,47 @@ def round_stats(x: jax.Array, g: jax.Array, mask: jax.Array | None = None,
             mf = mask.astype(jnp.float32)
             xf = xf * mf[None]
             gf = gf * mf
-        return xf @ gf, jnp.sum(xf * xf, axis=1), jnp.dot(gf, gf)
+        hi = jax.lax.Precision.HIGHEST
+        return (jnp.matmul(xf, gf, precision=hi), jnp.sum(xf * xf, axis=1),
+                jnp.dot(gf, gf, precision=hi))
     tile, kp = _k_chunks(K)
     rows = _row_block(n)
     block = rows * LANE
     x = _pad_lanes(x, block)
     g = _pad_lanes(g, block)
-    if mask is not None:
-        mask = _pad_lanes(mask, block)
     m = x.shape[1] // LANE
-    x3 = x.reshape(K, m, LANE)
-    g2 = g.reshape(m, LANE)
-
     tile_spec = pl.BlockSpec((rows, LANE), lambda kc, i: (i, 0))
     in_specs = [
         pl.BlockSpec((tile, rows, LANE), lambda kc, i: (kc, i, 0)),
         tile_spec,
     ]
-    operands = [x3, g2]
-    kernel = _stats_kernel
+    operands = [x.reshape(K, m, LANE), g.reshape(m, LANE)]
     if mask is not None:
         in_specs.append(tile_spec)
-        operands.append(mask.reshape(m, LANE))
-        kernel = _stats_kernel_masked
-
-    kvec_spec = pl.BlockSpec((tile, 1), lambda kc, i: (kc, 0))
-    dots, sqs, sqg = pl.pallas_call(
-        functools.partial(kernel, k=K, tile=tile),
-        grid=(kp // tile, m // rows),
-        in_specs=in_specs,
-        out_specs=(kvec_spec, kvec_spec,
-                   pl.BlockSpec((1, 1), lambda kc, i: (0, 0))),
-        out_shape=(
-            jax.ShapeDtypeStruct((kp, 1), jnp.float32),
-            jax.ShapeDtypeStruct((kp, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        ),
-        interpret=interpret,
-    )(*operands)
-    return dots[:K, 0], sqs[:K, 0], sqg[0, 0]
+        operands.append(_pad_lanes(mask, block).reshape(m, LANE))
+    kernel = functools.partial(_stats_kernel, k=K, tile=tile,
+                               masked=mask is not None)
+    return _stats_call(kernel, operands, in_specs, k=K, tile=tile, kp=kp,
+                       rows=rows, m=m, interpret=interpret)
 
 
-def _stats_q4_kernel(x_ref, s_ref, ge_ref, go_ref, dots_ref, sqs_ref,
-                     sqg_ref, *, k, tile, gs2):
+def _stats_q4_kernel(*refs, k, tile, gs2, masked):
+    x_ref, s_ref, ge_ref, go_ref = refs[:4]
     kc, i = pl.program_id(0), pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        dots_ref[...] = jnp.zeros_like(dots_ref)
-        sqs_ref[...] = jnp.zeros_like(sqs_ref)
-
-    @pl.when((kc == 0) & (i == 0))
-    def _init_g():
-        sqg_ref[0, 0] = 0.0
-
     lo, hi = _unpack_nibbles(x_ref[...])
     sexp = _expand_group_scales(s_ref[...], gs2)  # (KT, ROWS, LANE)
-    xlo = _mask_tail_rows(lo.astype(jnp.float32) * sexp, kc, k=k, tile=tile)
-    xhi = _mask_tail_rows(hi.astype(jnp.float32) * sexp, kc, k=k, tile=tile)
+    xlo = lo.astype(jnp.float32) * sexp
+    xhi = hi.astype(jnp.float32) * sexp
     ge = ge_ref[...].astype(jnp.float32)  # (ROWS, LANE) — g[0::2]
     go = go_ref[...].astype(jnp.float32)  # (ROWS, LANE) — g[1::2]
-    dots_ref[...] += (jnp.sum(xlo * ge[None], axis=(1, 2))
-                      + jnp.sum(xhi * go[None], axis=(1, 2)))[:, None]
-    sqs_ref[...] += (jnp.sum(xlo * xlo, axis=(1, 2))
-                     + jnp.sum(xhi * xhi, axis=(1, 2)))[:, None]
-
-    @pl.when(kc == 0)  # g repeats per client chunk; count it once
-    def _accum_g():
-        sqg_ref[0, 0] += jnp.sum(ge * ge) + jnp.sum(go * go)
-
-
-def _stats_q4_kernel_masked(x_ref, s_ref, ge_ref, go_ref, me_ref, mo_ref,
-                            dots_ref, sqs_ref, sqg_ref, *, k, tile, gs2):
-    kc, i = pl.program_id(0), pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        dots_ref[...] = jnp.zeros_like(dots_ref)
-        sqs_ref[...] = jnp.zeros_like(sqs_ref)
-
-    @pl.when((kc == 0) & (i == 0))
-    def _init_g():
-        sqg_ref[0, 0] = 0.0
-
-    lo, hi = _unpack_nibbles(x_ref[...])
-    sexp = _expand_group_scales(s_ref[...], gs2)
-    me = me_ref[...].astype(jnp.float32)  # (ROWS, LANE) in {0, 1}
-    mo = mo_ref[...].astype(jnp.float32)
-    xlo = _mask_tail_rows(lo.astype(jnp.float32) * sexp * me[None], kc,
-                          k=k, tile=tile)
-    xhi = _mask_tail_rows(hi.astype(jnp.float32) * sexp * mo[None], kc,
-                          k=k, tile=tile)
-    ge = ge_ref[...].astype(jnp.float32) * me
-    go = go_ref[...].astype(jnp.float32) * mo
-    dots_ref[...] += (jnp.sum(xlo * ge[None], axis=(1, 2))
-                      + jnp.sum(xhi * go[None], axis=(1, 2)))[:, None]
-    sqs_ref[...] += (jnp.sum(xlo * xlo, axis=(1, 2))
-                     + jnp.sum(xhi * xhi, axis=(1, 2)))[:, None]
-
-    @pl.when(kc == 0)
-    def _accum_g():
-        sqg_ref[0, 0] += jnp.sum(ge * ge) + jnp.sum(go * go)
+    if masked:  # (ROWS, LANE) even/odd views in {0, 1}
+        me = refs[4][...].astype(jnp.float32)
+        mo = refs[5][...].astype(jnp.float32)
+        xlo, xhi = xlo * me[None], xhi * mo[None]
+        ge, go = ge * me, go * mo
+    xlo = _mask_tail_rows(xlo, kc, k=k, tile=tile)
+    xhi = _mask_tail_rows(xhi, kc, k=k, tile=tile)
+    _accumulate(refs[-3:], kc, i, [(xlo, ge), (xhi, go)])
 
 
 def _even_odd_views(vec: jax.Array, cols: int, m: int):
@@ -244,7 +206,7 @@ def _even_odd_views(vec: jax.Array, cols: int, m: int):
 @functools.partial(jax.jit, static_argnames=("group_size", "interpret"))
 def round_stats_q4(values: jax.Array, scales: jax.Array, g: jax.Array,
                    mask: jax.Array | None = None, *, group_size: int,
-                   interpret: bool = True):
+                   interpret=None):
     """`round_stats` over the int4 packed wire buffer, dequant in-register.
 
     values: (K, ceil(n/2)) int8 packed (two int4 params per byte, low
@@ -267,101 +229,46 @@ def round_stats_q4(values: jax.Array, scales: jax.Array, g: jax.Array,
     cols = x.shape[1]
     m = cols // LANE
     gp = cols // gs2
-    gt = (ROWS * LANE) // gs2
     assert scales.shape[0] == K and scales.shape[1] <= gp, (scales.shape, gp)
     sp = jnp.pad(scales.astype(jnp.float32),
                  ((0, 0), (0, gp - scales.shape[1])), constant_values=1.0)
-    x3 = x.reshape(K, m, LANE)
     ge2, go2 = _even_odd_views(g.astype(jnp.float32), cols, m)
 
+    s_op = _group_scale_operand(sp, kp, gs2)
     tile_spec = pl.BlockSpec((ROWS, LANE), lambda kc, i: (i, 0))
     in_specs = [
         pl.BlockSpec((tile, ROWS, LANE), lambda kc, i: (kc, i, 0)),
-        pl.BlockSpec((tile, gt), lambda kc, i: (kc, i)),
+        pl.BlockSpec((None, tile) + s_op.shape[2:],
+                     lambda kc, i: (i, kc) + (0,) * (s_op.ndim - 2)),
         tile_spec,
         tile_spec,
     ]
-    operands = [x3, sp, ge2, go2]
-    kernel = _stats_q4_kernel
+    operands = [x.reshape(K, m, LANE), s_op, ge2, go2]
     if mask is not None:
-        me2, mo2 = _even_odd_views(mask.astype(jnp.float32), cols, m)
         in_specs += [tile_spec, tile_spec]
-        operands += [me2, mo2]
-        kernel = _stats_q4_kernel_masked
-
-    kvec_spec = pl.BlockSpec((tile, 1), lambda kc, i: (kc, 0))
-    dots, sqs, sqg = pl.pallas_call(
-        functools.partial(kernel, k=K, tile=tile, gs2=gs2),
-        grid=(kp // tile, m // ROWS),
-        in_specs=in_specs,
-        out_specs=(kvec_spec, kvec_spec,
-                   pl.BlockSpec((1, 1), lambda kc, i: (0, 0))),
-        out_shape=(
-            jax.ShapeDtypeStruct((kp, 1), jnp.float32),
-            jax.ShapeDtypeStruct((kp, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        ),
-        interpret=interpret,
-    )(*operands)
-    return dots[:K, 0], sqs[:K, 0], sqg[0, 0]
+        operands += list(_even_odd_views(mask.astype(jnp.float32), cols, m))
+    kernel = functools.partial(_stats_q4_kernel, k=K, tile=tile, gs2=gs2,
+                               masked=mask is not None)
+    return _stats_call(kernel, operands, in_specs, k=K, tile=tile, kp=kp,
+                       rows=ROWS, m=m, interpret=interpret)
 
 
-def _stats_q_kernel(x_ref, s_ref, g_ref, dots_ref, sqs_ref, sqg_ref,
-                    *, k, tile):
+def _stats_q_kernel(*refs, k, tile, masked):
+    x_ref, s_ref, g_ref = refs[:3]
     kc, i = pl.program_id(0), pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        dots_ref[...] = jnp.zeros_like(dots_ref)
-        sqs_ref[...] = jnp.zeros_like(sqs_ref)
-
-    @pl.when((kc == 0) & (i == 0))
-    def _init_g():
-        sqg_ref[0, 0] = 0.0
-
     # in-register dequant: one f32 scale per (client, tile)
-    s = s_ref[...]  # (KT, 1)
-    x = _mask_tail_rows(x_ref[...].astype(jnp.float32) * s[:, :, None], kc,
-                        k=k, tile=tile)
+    x = x_ref[...].astype(jnp.float32) * s_ref[...][:, :, None]
     g = g_ref[...].astype(jnp.float32)
-    dots_ref[...] += jnp.sum(x * g[None], axis=(1, 2))[:, None]
-    sqs_ref[...] += jnp.sum(x * x, axis=(1, 2))[:, None]
-
-    @pl.when(kc == 0)
-    def _accum_g():
-        sqg_ref[0, 0] += jnp.sum(g * g)
-
-
-def _stats_q_kernel_masked(x_ref, s_ref, g_ref, m_ref, dots_ref, sqs_ref,
-                           sqg_ref, *, k, tile):
-    kc, i = pl.program_id(0), pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        dots_ref[...] = jnp.zeros_like(dots_ref)
-        sqs_ref[...] = jnp.zeros_like(sqs_ref)
-
-    @pl.when((kc == 0) & (i == 0))
-    def _init_g():
-        sqg_ref[0, 0] = 0.0
-
-    s = s_ref[...]  # (KT, 1)
-    m = m_ref[...].astype(jnp.float32)  # (ROWS, LANE)
-    x = _mask_tail_rows(
-        x_ref[...].astype(jnp.float32) * s[:, :, None] * m[None], kc,
-        k=k, tile=tile)
-    g = g_ref[...].astype(jnp.float32) * m
-    dots_ref[...] += jnp.sum(x * g[None], axis=(1, 2))[:, None]
-    sqs_ref[...] += jnp.sum(x * x, axis=(1, 2))[:, None]
-
-    @pl.when(kc == 0)
-    def _accum_g():
-        sqg_ref[0, 0] += jnp.sum(g * g)
+    if masked:  # (ROWS, LANE) in {0, 1}
+        mk = refs[3][...].astype(jnp.float32)
+        x, g = x * mk[None], g * mk
+    x = _mask_tail_rows(x, kc, k=k, tile=tile)
+    _accumulate(refs[-3:], kc, i, [(x, g)])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def round_stats_q(values: jax.Array, scales: jax.Array, g: jax.Array,
-                  mask: jax.Array | None = None, *, interpret: bool = True):
+                  mask: jax.Array | None = None, *, interpret=None):
     """`round_stats` over the int8 wire buffer, dequant fused in-register.
 
     values: (K, N) int8; scales: (K, ceil(N / (ROWS*LANE))) f32 — the
@@ -370,46 +277,30 @@ def round_stats_q(values: jax.Array, scales: jax.Array, g: jax.Array,
     round_stats(dequantize(values, scales), g, mask) to f32 accumulation
     order. Lane-tail zero padding needs no scale handling (int8 zeros
     dequantize to zero); the ragged tail client chunk is bounds-masked, so
-    out-of-range scale reads are select-zeroed with the rows they scale.
+    the zero-padded scale rows are select-zeroed with the rows they scale.
     """
     K, n = values.shape
     tile, kp = _k_chunks(K)
     block = ROWS * LANE
     x = _pad_lanes(values, block)
     g = _pad_lanes(g, block)
-    if mask is not None:
-        mask = _pad_lanes(mask, block)
     m = x.shape[1] // LANE
     c = m // ROWS
     assert scales.shape == (K, c), (scales.shape, (K, c))
-    x3 = x.reshape(K, m, LANE)
-    g2 = g.reshape(m, LANE)
 
     tile_spec = pl.BlockSpec((ROWS, LANE), lambda kc, i: (i, 0))
     in_specs = [
         pl.BlockSpec((tile, ROWS, LANE), lambda kc, i: (kc, i, 0)),
-        pl.BlockSpec((tile, 1), lambda kc, i: (kc, i)),
+        pl.BlockSpec((None, tile, 1), lambda kc, i: (i, kc, 0)),
         tile_spec,
     ]
-    operands = [x3, scales.astype(jnp.float32), g2]
-    kernel = _stats_q_kernel
+    operands = [x.reshape(K, m, LANE),
+                _tile_major(scales.astype(jnp.float32), kp, 1),
+                g.reshape(m, LANE)]
     if mask is not None:
         in_specs.append(tile_spec)
-        operands.append(mask.reshape(m, LANE))
-        kernel = _stats_q_kernel_masked
-
-    kvec_spec = pl.BlockSpec((tile, 1), lambda kc, i: (kc, 0))
-    dots, sqs, sqg = pl.pallas_call(
-        functools.partial(kernel, k=K, tile=tile),
-        grid=(kp // tile, m // ROWS),
-        in_specs=in_specs,
-        out_specs=(kvec_spec, kvec_spec,
-                   pl.BlockSpec((1, 1), lambda kc, i: (0, 0))),
-        out_shape=(
-            jax.ShapeDtypeStruct((kp, 1), jnp.float32),
-            jax.ShapeDtypeStruct((kp, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        ),
-        interpret=interpret,
-    )(*operands)
-    return dots[:K, 0], sqs[:K, 0], sqg[0, 0]
+        operands.append(_pad_lanes(mask, block).reshape(m, LANE))
+    kernel = functools.partial(_stats_q_kernel, k=K, tile=tile,
+                               masked=mask is not None)
+    return _stats_call(kernel, operands, in_specs, k=K, tile=tile, kp=kp,
+                       rows=ROWS, m=m, interpret=interpret)

@@ -42,6 +42,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 LANE = 128
 ROWS = 128  # per-client block: 128*128*4 B = 64 KiB
 # Client-axis chunk: 32*128*128*4 B = 2 MiB per x tile — small enough to
@@ -100,6 +102,18 @@ def _pad_axis0(x: jax.Array, kp: int) -> jax.Array:
     return jnp.concatenate([x, jnp.zeros((kp - k,) + x.shape[1:], x.dtype)])
 
 
+def _tile_major(s: jax.Array, kp: int, per_tile: int) -> jax.Array:
+    """(K, C*per_tile) per-(client, chunk) scales -> (C, kp, per_tile).
+
+    Mosaic needs the last two block dims to be (8, 128)-divisible or
+    whole: a (K_TILE, 1) block of a (K, C) array is neither. With the
+    chunk axis leading and squeezed out of the block, each grid step
+    reads a (tile, per_tile) block whose trailing dims are a client
+    chunk (whole kp, or K_TILE = 32) by the whole per-tile width."""
+    s = _pad_axis0(s, kp)
+    return s.reshape(kp, -1, per_tile).transpose(1, 0, 2)
+
+
 def _pad_lanes(x: jax.Array, block: int) -> jax.Array:
     """Zero-pad the last axis to a multiple of `block`."""
     pad = (-x.shape[-1]) % block
@@ -121,12 +135,37 @@ def _unpack_nibbles(b: jax.Array) -> tuple[jax.Array, jax.Array]:
     return lo, hi
 
 
-def _expand_group_scales(s: jax.Array, gs2: int) -> jax.Array:
-    """(KT, Gt) per-group scales -> (KT, ROWS, LANE) per-byte multipliers.
+def _group_scale_operand(sp: jax.Array, kp: int, gs2: int) -> jax.Array:
+    """(K, C*Gt) int4 group scales -> the kernels' tile-major operand.
 
-    gs2 = group_size // 2 bytes per group; Gt * gs2 == ROWS * LANE, so the
-    repeat+reshape is a static in-register broadcast, no gather."""
-    return jnp.repeat(s, gs2, axis=1).reshape(s.shape[0], ROWS, LANE)
+    gs2 = group_size // 2 bytes per group and Gt = ROWS*LANE // gs2
+    groups per tile. A group that is one byte or covers whole 128-lane
+    rows ships as (C, kp, Gt); with q = LANE // gs2 > 1 groups per row it
+    ships as (C, kp, ROWS, q), one row of scales per byte row. The block
+    is the operand's shape with the chunk axis squeezed and kp cut to the
+    client tile."""
+    s = _tile_major(sp, kp, (ROWS * LANE) // gs2)
+    if 1 < gs2 < LANE:
+        s = s.reshape(s.shape[0], kp, ROWS, LANE // gs2)
+    return s
+
+
+def _expand_group_scales(s: jax.Array, gs2: int) -> jax.Array:
+    """A client tile's group scales -> (KT, ROWS, LANE) per-byte multipliers.
+
+    (KT, Gt) blocks expand by a static repeat+reshape (Gt * gs2 ==
+    ROWS * LANE). Mosaic cannot lower that repeat below a whole lane row,
+    so (KT, ROWS, q) blocks expand along the lanes by a 0/1 matmul at
+    HIGHEST precision — exact, since each output is one scale times 1.0."""
+    if s.ndim == 2:
+        return jnp.repeat(s, gs2, axis=1).reshape(s.shape[0], ROWS, LANE)
+    kt, rows, q = s.shape
+    grp = jax.lax.broadcasted_iota(jnp.int32, (q, LANE), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (q, LANE), 1)
+    expand = (lane // gs2 == grp).astype(jnp.float32)
+    return jnp.dot(s.reshape(kt * rows, q), expand,
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32).reshape(kt, rows, LANE)
 
 
 def _mask_tail_rows(x: jax.Array, kc, *, k: int, tile: int) -> jax.Array:
@@ -159,7 +198,7 @@ def _agg_kernel(w_ref, x_ref, y_ref, *, k, tile):
 @functools.partial(jax.jit,
                    static_argnames=("interpret", "out_dtype",
                                     "min_kernel_elems"))
-def weighted_agg(w: jax.Array, x: jax.Array, *, interpret: bool = True,
+def weighted_agg(w: jax.Array, x: jax.Array, *, interpret=None,
                  out_dtype=None, min_kernel_elems=None):
     """y[n] = sum_k w[k] x[k, n]. x: (K, N) any float dtype; f32 accumulate.
 
@@ -173,7 +212,8 @@ def weighted_agg(w: jax.Array, x: jax.Array, *, interpret: bool = True,
     K, n = x.shape
     if _use_fallback(K, n, min_kernel_elems):
         y = jnp.tensordot(w.reshape(K).astype(jnp.float32),
-                          x.astype(jnp.float32), axes=1)
+                          x.astype(jnp.float32), axes=1,
+                          precision=jax.lax.Precision.HIGHEST)
         return y.astype(out_dtype or x.dtype)
     tile, kp = _k_chunks(K)
     rows = _row_block(n)
@@ -193,7 +233,7 @@ def weighted_agg(w: jax.Array, x: jax.Array, *, interpret: bool = True,
         ],
         out_specs=pl.BlockSpec((rows, LANE), lambda i, kc: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, LANE), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(w2, x3)
     return y.reshape(-1)[:n].astype(out_dtype or x.dtype)
 
@@ -213,7 +253,7 @@ def _agg_q_kernel(ws_ref, x_ref, y_ref, *, k, tile):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def weighted_agg_q(w: jax.Array, values: jax.Array, scales: jax.Array, *,
-                   interpret: bool = True):
+                   interpret=None):
     """y[n] = sum_k w[k] * scale[k, n // CHUNK] * values[k, n], f32 out.
 
     values: (K, N) int8 wire buffer; scales: (K, ceil(N / (ROWS*LANE)))
@@ -230,19 +270,20 @@ def weighted_agg_q(w: jax.Array, values: jax.Array, scales: jax.Array, *,
     c = m // ROWS
     assert scales.shape == (K, c), (scales.shape, (K, c))
     x3 = x.reshape(K, m, LANE)
-    ws = _pad_axis0(
-        w.reshape(K, 1).astype(jnp.float32) * scales.astype(jnp.float32), kp)
+    ws = _tile_major(
+        w.reshape(K, 1).astype(jnp.float32) * scales.astype(jnp.float32),
+        kp, 1)
 
     y = pl.pallas_call(
         functools.partial(_agg_q_kernel, k=K, tile=tile),
         grid=(m // ROWS, kp // tile),
         in_specs=[
-            pl.BlockSpec((tile, 1), lambda i, kc: (kc, i)),
+            pl.BlockSpec((None, tile, 1), lambda i, kc: (i, kc, 0)),
             pl.BlockSpec((tile, ROWS, LANE), lambda i, kc: (kc, i, 0)),
         ],
         out_specs=pl.BlockSpec((ROWS, LANE), lambda i, kc: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, LANE), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(ws, x3)
     return y.reshape(-1)[:n]
 
@@ -256,7 +297,7 @@ def _agg_q4_kernel(ws_ref, x_ref, ye_ref, yo_ref, *, k, tile, gs2):
         yo_ref[...] = jnp.zeros_like(yo_ref)
 
     lo, hi = _unpack_nibbles(x_ref[...])
-    # (KT, Gt) weight x per-group dequant scales -> per-byte multipliers
+    # weight x per-group dequant scales -> per-byte multipliers
     sexp = _expand_group_scales(ws_ref[...], gs2)
     xlo = _mask_tail_rows(lo.astype(jnp.float32) * sexp, kc, k=k, tile=tile)
     xhi = _mask_tail_rows(hi.astype(jnp.float32) * sexp, kc, k=k, tile=tile)
@@ -266,14 +307,14 @@ def _agg_q4_kernel(ws_ref, x_ref, ye_ref, yo_ref, *, k, tile, gs2):
 
 @functools.partial(jax.jit, static_argnames=("n", "group_size", "interpret"))
 def weighted_agg_q4(w: jax.Array, values: jax.Array, scales: jax.Array, *,
-                    n: int, group_size: int, interpret: bool = True):
+                    n: int, group_size: int, interpret=None):
     """y[m] = sum_k w[k] * scale[k, m // group_size] * x4[k, m], f32 out.
 
     values: (K, ceil(n/2)) int8 PACKED wire buffer (two int4 params per
     byte, low nibble first); scales: (K, ceil(n/group_size)) f32 grouped
     dequant multipliers (repro.transport int4 layout); `n` the logical
     element count. The weight folds into the per-group scale on the host
-    (one (K_TILE, Gt) VMEM operand per step); nibbles unpack in-register
+    (one small VMEM operand per step, `_group_scale_operand`); nibbles unpack in-register
     and accumulate into separate even/odd f32 planes, interleaved back to
     logical order after the kernel. group_size must be even and divide
     CHUNK = ROWS*LANE (transport.validate_group_size), so a tile covers
@@ -287,26 +328,27 @@ def weighted_agg_q4(w: jax.Array, values: jax.Array, scales: jax.Array, *,
     x = _pad_lanes(values, ROWS * LANE)
     m = x.shape[1] // LANE
     gp = x.shape[1] // gs2  # padded group columns (gs2 | ROWS*LANE | cols)
-    gt = (ROWS * LANE) // gs2  # groups per tile
     assert scales.shape[0] == K and scales.shape[1] <= gp, (scales.shape, gp)
     x3 = x.reshape(K, m, LANE)
     # padding scales with 1.0 keeps padded zero bytes at exactly zero
     sp = jnp.pad(scales.astype(jnp.float32),
                  ((0, 0), (0, gp - scales.shape[1])), constant_values=1.0)
-    ws = _pad_axis0(w.reshape(K, 1).astype(jnp.float32) * sp, kp)
+    ws = _group_scale_operand(w.reshape(K, 1).astype(jnp.float32) * sp, kp,
+                              gs2)
 
     ye, yo = pl.pallas_call(
         functools.partial(_agg_q4_kernel, k=K, tile=tile, gs2=gs2),
         grid=(m // ROWS, kp // tile),
         in_specs=[
-            pl.BlockSpec((tile, gt), lambda i, kc: (kc, i)),
+            pl.BlockSpec((None, tile) + ws.shape[2:],
+                         lambda i, kc: (i, kc) + (0,) * (ws.ndim - 2)),
             pl.BlockSpec((tile, ROWS, LANE), lambda i, kc: (kc, i, 0)),
         ],
         out_specs=(pl.BlockSpec((ROWS, LANE), lambda i, kc: (i, 0)),
                    pl.BlockSpec((ROWS, LANE), lambda i, kc: (i, 0))),
         out_shape=(jax.ShapeDtypeStruct((m, LANE), jnp.float32),
                    jax.ShapeDtypeStruct((m, LANE), jnp.float32)),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(ws, x3)
     # interleave the nibble planes back to logical order: y[2j] = ye[j],
     # y[2j+1] = yo[j] — an O(N) shuffle of the f32 OUTPUT, not the wire.
@@ -323,19 +365,21 @@ def _bdot_kernel(x_ref, g_ref, out_ref, *, k, tile):
 
     x = _mask_tail_rows(x_ref[...].astype(jnp.float32), kc, k=k, tile=tile)
     g = g_ref[...].astype(jnp.float32)  # (ROWS, LANE)
-    out_ref[...] += jnp.sum(x * g[None], axis=(1, 2))[:, None]
+    out_ref[...] += jnp.sum(x * g[None], axis=1)  # (KT, LANE) partials
 
 
 @functools.partial(jax.jit,
                    static_argnames=("interpret", "min_kernel_elems"))
-def batched_dot(x: jax.Array, g: jax.Array, *, interpret: bool = True,
+def batched_dot(x: jax.Array, g: jax.Array, *, interpret=None,
                 min_kernel_elems=None):
     """u[k] = <x[k], g>. x: (K, N), g: (N,). Buffers below
     `min_kernel_elems` elements (default SMALL_ELEMS; 0 forces Pallas)
-    compute as one XLA matvec."""
+    compute as one XLA matvec. The kernel keeps lane-dense (K, LANE)
+    partial sums; the lanes reduce after it."""
     K, n = x.shape
     if _use_fallback(K, n, min_kernel_elems):
-        return x.astype(jnp.float32) @ g.astype(jnp.float32)
+        return jnp.matmul(x.astype(jnp.float32), g.astype(jnp.float32),
+                          precision=jax.lax.Precision.HIGHEST)
     tile, kp = _k_chunks(K)
     rows = _row_block(n)
     x = _pad_lanes(x, rows * LANE)
@@ -351,8 +395,8 @@ def batched_dot(x: jax.Array, g: jax.Array, *, interpret: bool = True,
             pl.BlockSpec((tile, rows, LANE), lambda kc, i: (kc, i, 0)),
             pl.BlockSpec((rows, LANE), lambda kc, i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((tile, 1), lambda kc, i: (kc, 0)),
-        out_shape=jax.ShapeDtypeStruct((kp, 1), jnp.float32),
-        interpret=interpret,
+        out_specs=pl.BlockSpec((tile, LANE), lambda kc, i: (kc, 0)),
+        out_shape=jax.ShapeDtypeStruct((kp, LANE), jnp.float32),
+        interpret=interpret_mode(interpret),
     )(x3, g2)
-    return out[:K, 0]
+    return jnp.sum(out[:K], axis=1)

@@ -72,6 +72,8 @@ def main() -> None:
     from repro.launch.mesh import make_host_mesh, make_production_mesh
     from repro.models import transformer
 
+    repro.use_compile_cache()
+
     name = args.arch + ("-smoke" if args.smoke else "")
     cfg = registry.get(name)
     mesh = make_host_mesh() if args.host_mesh else make_production_mesh(

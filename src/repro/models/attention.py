@@ -95,8 +95,7 @@ def attn_forward(p: dict, cfg, x: jax.Array, cos, sin, *, causal: bool = True,
         from repro.kernels import flash_attn
 
         blk = min(128, T)
-        out = flash_attn.gqa_flash(q, k, v, causal=True, blk_q=blk, blk_k=blk,
-                                   interpret=jax.default_backend() != "tpu")
+        out = flash_attn.gqa_flash(q, k, v, causal=True, blk_q=blk, blk_k=blk)
         y = out.reshape(B, T, cfg.num_heads * hd).astype(x.dtype) @ p["wo"]
     elif q_chunk and T > q_chunk and T % q_chunk == 0:
         out = _chunked_attention(q, k, v, scale, causal, window, q_chunk)

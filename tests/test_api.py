@@ -12,10 +12,13 @@ allocated or traced.
 """
 import inspect
 import warnings
+from pathlib import Path
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 import repro
 from repro.core import fl
@@ -36,11 +39,13 @@ def test_facade_all_is_pinned():
         "SpanTimer",
         "fixed_arrival_schedule",
         "init_round_state",
+        "make_mesh",
         "make_round_fn",
         "run_manifest",
         "state_from_tree",
         "state_to_tree",
         "telemetry",
+        "use_compile_cache",
     ]
     for name in repro.__all__:
         assert getattr(repro, name) is not None
@@ -50,6 +55,34 @@ def test_facade_reexports_are_the_real_objects():
     assert repro.FLConfig is fl.FLConfig
     assert repro.RoundState is fl.RoundState
     assert repro.make_round_fn is fl.make_round_fn
+
+
+def test_make_mesh_axes_are_auto():
+    """Explicit axes make with_sharding_constraint(P("data")) refuse the
+    flat engines' row sharding; every repo mesh is built Auto."""
+    mesh = repro.make_mesh((1, 1), ("data", "model"))
+    assert mesh.axis_names == ("data", "model")
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+
+
+def test_use_compile_cache_keeps_env_dir(monkeypatch, tmp_path):
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert repro.use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_use_compile_cache_defaults_to_checkout_dir(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = repro.use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+        assert repro.use_compile_cache() == path  # fixed: same each call
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    checkout = Path(repro.__file__).resolve().parents[2]
+    assert path == str(checkout / ".jax_cache")
 
 
 # ------------------------------------------------------ run entrypoint

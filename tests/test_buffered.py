@@ -39,6 +39,7 @@ import repro
 from repro.core import buffer as buffer_mod
 from repro.core import driver, fl, weighting
 from repro.data import synthetic
+from repro.launch.mesh import make_mesh
 
 GOLDEN = os.path.join(os.path.dirname(__file__), "golden",
                       "convergence.json")
@@ -168,7 +169,7 @@ def test_buffered_full_cohort_matches_sync(engine, uplink, downlink, ef,
     whole cohort every tick — the sync round, engine by engine. Subset
     selection (8-client population, explicit 3-cohort) exercises the
     masked Eq. 9 scatter and the EF rows under buffering."""
-    mesh = jax.make_mesh((1,), ("data",)) if engine == "flat_sharded" \
+    mesh = make_mesh((1,), ("data",)) if engine == "flat_sharded" \
         else None
     base = dict(num_clients=8, clients_per_round=3, local_steps=3,
                 method="fedadp", base_lr=0.05, engine=engine,
@@ -443,6 +444,7 @@ def test_golden_buffered_sharded_subprocess_quantized():
 import json, os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
+from repro.launch.mesh import make_mesh
 import numpy as np
 import repro
 from benchmarks.common import node_spec, run_fl
@@ -453,7 +455,7 @@ for tk, k in s["stragglers"]:
     delays[tk, k] = s["delay"]
 for tk, k in s["drops"]:
     drops[tk, k] = True
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 hist, _ = run_fl(
     "fedadp", node_spec(5, 5, 1), rounds={t["max_rounds"]},
     target={t["target"]}, engine="flat_sharded", transport="int4",
@@ -490,6 +492,7 @@ def test_buffered_flush_2d_mesh_matches_sync_and_tree_subprocess():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import fl
         K, d, h, tau, B = 3, 12, 8, 2, 4
         NC = 8
@@ -507,7 +510,7 @@ def test_buffered_flush_2d_mesh_matches_sync_and_tree_subprocess():
             return jnp.mean((pred - y) ** 2)
         sel = jnp.asarray([1, 4, 6], jnp.int32)
         sizes = jnp.asarray([10.0, 25.0, 40.0], np.float32)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         def run(engine, m, tr, buffered):
             cfg = fl.FLConfig(num_clients=NC, clients_per_round=K,
                               local_steps=tau, method="fedadp", engine=engine,

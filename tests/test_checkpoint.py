@@ -462,6 +462,7 @@ def test_kill_resume_flat_sharded_8device_subprocess(tmp_path):
         import os, sys, tempfile
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.checkpoint import io as ckpt_io
         from repro.core import fl
         from repro.core.server import FedServer
@@ -471,7 +472,7 @@ def test_kill_resume_flat_sharded_8device_subprocess(tmp_path):
         nodes = synthetic.make_federated(
             train, [("iid", None)] * 4 + [("xclass", 1)] * 4,
             samples_per_node=200, seed=1)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         cfg = fl.FLConfig(num_clients=8, clients_per_round=8, local_steps=4,
                           method="fedadp", engine="flat_sharded",
                           transport="int8", error_feedback=True,

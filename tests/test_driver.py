@@ -25,6 +25,7 @@ from repro.core import driver, fl
 from repro.core.server import FedServer, _epoch_batcher
 from repro.data import synthetic
 from repro.data.synthetic import Dataset
+from repro.launch.mesh import make_mesh
 
 GOLDEN = os.path.join(os.path.dirname(__file__), "golden",
                       "convergence.json")
@@ -193,7 +194,7 @@ def test_partial_participation_pinned_across_engines():
         x, y = b
         return jnp.mean((x @ p["w"] + p["b"] - y) ** 2)
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sel = jnp.asarray([1, 4, 6], jnp.int32)
     sizes = jnp.asarray([10.0, 20.0, 30.0])
     outs = {}
@@ -280,7 +281,7 @@ def test_downlink_delta_engines_agree():
         x, y = b
         return jnp.mean((x @ p["w"] + p["b"] - y) ** 2)
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sel = jnp.arange(K, dtype=jnp.int32)
     sizes = jnp.asarray([10.0, 20.0, 30.0, 40.0])
     outs = {}
@@ -383,6 +384,7 @@ def test_scanned_flat_sharded_8device_subprocess():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import fl
         from repro.core.server import FedServer
         from repro.data import synthetic
@@ -391,7 +393,7 @@ def test_scanned_flat_sharded_8device_subprocess():
         nodes = synthetic.make_federated(
             train, [("iid", None)] * 4 + [("xclass", 1)] * 4,
             samples_per_node=200, seed=1)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         cfg = fl.FLConfig(num_clients=8, clients_per_round=8, local_steps=4,
                           method="fedadp", engine="flat_sharded",
                           transport="int8", base_lr=0.05)

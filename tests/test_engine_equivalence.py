@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 
 from repro.core import fl
+from repro.launch.mesh import make_mesh
 
 K = 4
 
@@ -200,6 +201,7 @@ def test_flat_sharded_nondivisible_k_matches_tree_subprocess():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import fl
         K, d, tau, B = 13, 12, 2, 4
         rng = np.random.default_rng(0)
@@ -211,7 +213,7 @@ def test_flat_sharded_nondivisible_k_matches_tree_subprocess():
         def loss_fn(p, batch):
             x, y = batch
             return jnp.mean((x @ p["w"] + p["b"] - y) ** 2)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         sel = jnp.arange(K, dtype=jnp.int32)
         sizes = jnp.asarray(np.linspace(10.0, 40.0, K, dtype=np.float32))
         for tr, dl in (("f32", "f32"), ("int8", "f32"), ("int4", "int8")):
@@ -248,7 +250,7 @@ def test_flat_sharded_single_device_matches_flat():
     """On a 1-way client mesh the sharded flat engine is the flat engine
     plus no-op psums; trajectories must agree to 1e-5."""
     params, loss_fn, batches = _toy_problem()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     outs = {}
     for engine in ("flat", "flat_sharded"):
         cfg = fl.FLConfig(num_clients=K, clients_per_round=K, local_steps=3,
@@ -276,6 +278,7 @@ def test_flat_sharded_matches_tree_8way_subprocess():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import fl
         K, d, tau, B = 16, 12, 3, 8
         rng = np.random.default_rng(0)
@@ -287,7 +290,7 @@ def test_flat_sharded_matches_tree_8way_subprocess():
         def loss_fn(p, batch):
             x, y = batch
             return jnp.mean((x @ p["w"] + p["b"] - y) ** 2)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         sel = jnp.arange(K, dtype=jnp.int32)
         sizes = jnp.asarray(np.linspace(10.0, 40.0, K, dtype=np.float32))
         outs = {}
@@ -369,6 +372,7 @@ def test_flat_sharded_2d_mesh_matches_tree_subprocess():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import fl
         K, d, h, tau, B = 6, 12, 8, 2, 4
         rng = np.random.default_rng(0)
@@ -403,7 +407,7 @@ def test_flat_sharded_2d_mesh_matches_tree_subprocess():
                     st, m = rf(st, (X, Y), sel, sizes)
             return st, m
         for shape in ((2, 4), (4, 2)):
-            mesh = jax.make_mesh(shape, ("data", "model"))
+            mesh = make_mesh(shape, ("data", "model"))
             for tr in ("f32", "bf16", "int8", "int4"):
                 st_t, m_t = run("tree", mesh, tr)
                 st_f, m_f = run("flat_sharded", mesh, tr)
@@ -446,6 +450,7 @@ def test_flat_sharded_2d_keeps_sharded_leaves_sharded():
         import os, re
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core import fl_shard_map
         from repro.models import sharding as msharding
@@ -455,7 +460,7 @@ def test_flat_sharded_2d_keeps_sharded_leaves_sharded():
                   "b": jnp.zeros((7,), jnp.float32)}
         deltas = {"wq": jnp.asarray(rng.normal(size=(K, d, h)).astype(np.float32)),
                   "b": jnp.asarray(rng.normal(size=(K, 7)).astype(np.float32))}
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         pspecs = msharding.param_pspecs(params, mesh)
         assert "model" in str(pspecs["wq"]), pspecs
         stacked = jax.tree.map(lambda s: P("data", *tuple(s)), pspecs,

@@ -169,8 +169,9 @@ def test_golden_sharded_subprocess_quantized_both_directions():
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
+from repro.launch.mesh import make_mesh
 from benchmarks.common import node_spec, run_fl
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 hist, _ = run_fl(
     "fedadp", node_spec(5, 5, 1), rounds={task["max_rounds"]},
     target={task["target"]}, engine="flat_sharded", transport="int4",

@@ -18,6 +18,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.core import fl_shard_map, treemath, weighting
+from repro.launch.mesh import make_mesh
 
 
 def _reference(deltas, sizes, sm_prev, cnt_prev, alpha=5.0):
@@ -35,7 +36,7 @@ def _reference(deltas, sizes, sm_prev, cnt_prev, alpha=5.0):
 
 
 def test_single_device_mesh_matches_reference():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     K = 4
     deltas = {
         "a": jax.random.normal(jax.random.key(0), (K, 8, 6)),
@@ -61,7 +62,7 @@ def test_single_device_mesh_matches_reference():
 def test_single_device_flat_engine_matches_reference():
     """engine="flat" on a 1x1 mesh: the kernel path with no-op psums must
     reproduce the treemath reference."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     K = 4
     deltas = {
         "a": jax.random.normal(jax.random.key(0), (K, 8, 6)),
@@ -88,14 +89,14 @@ def test_single_device_flat_engine_matches_reference():
 def test_flat_engine_rejects_model_sharded_specs():
     """Model-axis-sharded leaves cannot ravel into contiguous client rows;
     the flat engine must refuse them at build time."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     pspecs = {"a": P("data", None, "model")}
     with pytest.raises(ValueError, match="client-only"):
         fl_shard_map.fedadp_aggregate(mesh, pspecs, alpha=5.0, engine="flat")
 
 
 def test_unknown_shard_map_engine_rejected():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with pytest.raises(ValueError, match="engine"):
         fl_shard_map.fedadp_aggregate(mesh, {"a": P("data", None)},
                                       alpha=5.0, engine="nope")
@@ -106,9 +107,10 @@ def test_multi_device_mesh_matches_reference_subprocess():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
         from repro.core import fl_shard_map, treemath, weighting
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         K = 4
         deltas = {"a": jax.random.normal(jax.random.key(0), (K, 8, 6)),
                   "b": jax.random.normal(jax.random.key(1), (K, 16))}

@@ -148,6 +148,7 @@ def test_flat_sharded_8device_stream_matches_stepwise():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         import repro
         from repro.core import fl
         from repro.data import synthetic
@@ -157,7 +158,7 @@ def test_flat_sharded_8device_stream_matches_stepwise():
         nodes = synthetic.make_federated(
             train, [("iid", None)] * 4 + [("xclass", 1)] * 4,
             samples_per_node=100, seed=1)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         cfg = fl.FLConfig(num_clients=8, clients_per_round=8, local_steps=2,
                           method="fedadp", engine="flat_sharded",
                           base_lr=0.05, telemetry="node")

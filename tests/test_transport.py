@@ -18,6 +18,7 @@ import pytest
 from repro import transport
 from repro.core import fl, fl_shard_map, treemath
 from repro.kernels import ref, round_stats, weighted_agg
+from repro.launch.mesh import make_mesh
 from repro.transport.quantize import CHUNK
 
 # K values straddling the K_TILE=32 client-chunk boundary (degenerate
@@ -396,7 +397,7 @@ def test_engines_agree_per_wire_pair(uplink, downlink):
     EVERY (uplink, downlink) transport pair, multi-round. int4 runs a
     sub-row scale group (32) so the grouped-dequant path is exercised."""
     gs = 32 if uplink == "int4" else 512
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     p_t, s_t, m_t, _, _ = _run("tree", uplink, downlink=downlink,
                                group_size=gs)
     p_f, s_f, m_f, _, _ = _run("flat", uplink, downlink=downlink,
@@ -417,7 +418,7 @@ def test_engines_agree_per_wire_pair(uplink, downlink):
 def test_quantized_engines_agree_fedavg(transport_name):
     """fedavg's psi-weighted aggregate reuses the stats aggregate in the
     single-region sharded round — pin it per quantized wire too."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     p_t, s_t, m_t, _, _ = _run("tree", transport_name, "fedavg")
     p_f, s_f, m_f, _, _ = _run("flat", transport_name, "fedavg")
     p_s, s_s, m_s, _, _ = _run("flat_sharded", transport_name, "fedavg",
@@ -615,7 +616,7 @@ def test_downlink_ef_engines_agree():
     """The EF broadcast is computed upstream of the engine branch: tree ==
     flat == flat_sharded to 1e-5 under downlink EF + quantized uplink."""
     params = _nonzero_params()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     outs = {
         eng: _run(eng, "int4", rounds=3, downlink="int8",
                   downlink_error_feedback=True, params=params,
@@ -696,7 +697,7 @@ def test_sequential_mode_rejects_quantized_downlink():
 def test_shard_map_tree_engine_rejects_quantized_transport():
     """The ROADMAP contract: the tree engine never reads quantized buffers;
     fedadp_aggregate must refuse rather than silently dequantize."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     from jax.sharding import PartitionSpec as P
     with pytest.raises(ValueError, match="tree"):
         fl_shard_map.fedadp_aggregate(mesh, {"a": P("data")}, alpha=5.0,
@@ -708,7 +709,7 @@ def test_shard_map_flat_engine_quantized_matches_f32_loosely():
     mesh: runs end-to-end and stays near the f32 wire (int4's bound scales
     with its 16x coarser step)."""
     from jax.sharding import PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     Kk = 4
     deltas = {
         "a": jax.random.normal(jax.random.key(0), (Kk, 8, 6)) * 0.1,
