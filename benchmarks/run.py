@@ -126,18 +126,19 @@ def method_ablation(full: bool = False) -> None:
         )
 
 
-def kernel_micro(full: bool = False) -> None:
+def kernel_micro(full: bool = False, tiny: bool = False) -> None:
     """Pallas kernels (interpret mode) vs XLA reference on identical inputs.
 
     Interpret-mode timing is NOT TPU performance — the roofline analysis in
     EXPERIMENTS.md covers the TPU projection; this records correctness-path
-    cost and the ref/XLA baseline."""
+    cost and the ref/XLA baseline. `tiny` uses the smallest buffer that
+    still takes the kernel path (K * N = SMALL_ELEMS)."""
     import jax
     import jax.numpy as jnp
 
     from repro.kernels import grad_dot, ref, round_stats, weighted_agg
 
-    n = 1 << 22 if full else 1 << 20
+    n = 1 << 17 if tiny else (1 << 22 if full else 1 << 20)
     a = jax.random.normal(jax.random.key(0), (n,), jnp.float32)
     b = jax.random.normal(jax.random.key(1), (n,), jnp.float32)
     x = jax.random.normal(jax.random.key(2), (8, n // 8), jnp.float32)
@@ -779,7 +780,7 @@ def main() -> None:
     print("name,us_per_call,derived")
     for name in names:
         kwargs = {"full": args.full}
-        if name in ("engine", "transport", "driver", "telemetry"):
+        if name in ("kernels", "engine", "transport", "driver", "telemetry"):
             kwargs["tiny"] = args.tiny
         BENCHES[name](**kwargs)
 

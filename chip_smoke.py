@@ -16,7 +16,10 @@ fallback there.
 `--chips 4` runs only the client-sharded engine: phase A's CNN with
 f32/f32 and int8/int8 as engine="flat_sharded" on a (4,) "data" mesh
 (K=10 over 4 shards, so the client axis is padded) and on a (2, 2)
-"data" x "model" mesh, each against engine="tree" on the same mesh.
+"data" x "model" mesh, each against engine="tree" on the same mesh. On
+the (4,) mesh it also feeds one fixed delta buffer at the CNN's width
+through flat_sharded's aggregation region and holds it to a float64
+reference, so aggregation is checked with local training taken out.
 
 The script fails (non-zero exit, no "ok" line) unless every loss is
 finite, flat matches tree and scanned matches stepwise after round 1,
@@ -46,13 +49,14 @@ import numpy as np  # noqa: E402
 # tests hold for every wire pair (tests/test_transport.py).
 TOL = 1e-5
 # On the (4,) mesh, where K=10 clients do not divide over 4 shards,
-# flat_sharded's row sharding propagates into local training and its
+# flat_sharded's row sharding propagates into local training, and its
 # round-1 losses already differ from tree's (1.4e-6 relative on v5e)
-# before any aggregation; FedAdp's angles amplify that delta noise. The
-# (2, 2) mesh splits the clients evenly and matches at 1e-5. Measured on
-# the (4,) mesh on v5e: params within 2.8x and per-client stats within
-# 71x the 1e-5 bound. An aggregation fault moves a weight by O(1), far
-# past these bounds.
+# before any aggregation; FedAdp's angles amplify that delta noise. So
+# end to end on that mesh the comparison allows the measured spread
+# (params within 2.8x and per-client stats within 71x the 1e-5 bound on
+# v5e), while the aggregation itself is held to TOL on fixed deltas
+# (`Smoke.aggregation`). The (2, 2) mesh splits the clients evenly and
+# matches at TOL end to end.
 TOL_PARTITIONED = {"params": 1e-4, "stats": 3e-3}
 # Test accuracy after round 4 over 3,000 images: 1e-5 agreement of the
 # trajectories leaves at most a few borderline images flipped.
@@ -167,6 +171,63 @@ class Smoke:
             f"{tol['stats']} (<= 1 passes)")
         self.check(dp <= 1 and ds <= 1, f"{what} after round 1")
 
+    def aggregation(self, what, mesh, up, n, k=10, alpha=5.0):
+        """flat_sharded's aggregation with local training taken out.
+
+        One fixed (k, n) delta buffer on `up`'s wire goes through the
+        region the round runs (`make_round_ops`, client rows zero-padded
+        to the mesh as `core.fl` pads them) and through a float64 NumPy
+        evaluation of Eqs. 1, 8, 9 and 11 on the same dequantized wire.
+        Round 1: no smoothing history, equal data sizes."""
+        from repro import transport
+        from repro.core import fl_shard_map
+
+        kb, kx = jax.random.split(jax.random.key(7))
+        spread = jnp.linspace(0.5, 3.0, k)[:, None]  # a range of angles
+        x = 1e-3 * (jax.random.normal(kb, (n,))
+                    + spread * jax.random.normal(kx, (k, n)))
+        if up == "f32":
+            wire, ref = (x,), x
+        else:
+            q = transport.quantize(x, up)
+            wire, ref = (q.values, q.scales), transport.dequantize(q)
+        caxis = fl_shard_map.client_axis_size(mesh)
+        kp = -(-k // caxis) * caxis
+
+        def pad(a, fill=0.0):
+            return jnp.pad(a, [(0, kp - k)] + [(0, 0)] * (a.ndim - 1),
+                           constant_values=fill)
+
+        rows = fl_shard_map.flat_client_sharding(mesh)
+        wire = tuple(jax.device_put(pad(a, fill), rows)
+                     for a, fill in zip(wire, (0.0, 1.0)))
+        sizes = jnp.full((k,), 600.0, jnp.float32)
+        ops = fl_shard_map.make_round_ops(mesh, alpha=alpha, transport=up)
+        g, dots, sqs, sqg, delta, theta, _, w = jax.jit(ops)(
+            *wire, pad(sizes / jnp.sum(sizes)), jnp.ones((n,), jnp.float32),
+            jnp.zeros((kp,), jnp.float32), jnp.zeros((kp,), jnp.int32),
+            pad(sizes))
+
+        xr = np.asarray(ref, np.float64)
+        s = np.asarray(sizes, np.float64)
+        g64 = (s / s.sum()) @ xr
+        dots64, sqs64, sqg64 = xr @ g64, np.sum(xr * xr, axis=1), g64 @ g64
+        theta64 = np.arccos(dots64 / np.sqrt(sqs64 * sqg64))
+        logits = alpha * (1.0 - np.exp(-np.exp(-alpha * (theta64 - 1.0))))
+        e = np.exp(logits + np.log(s) - np.max(logits + np.log(s)))
+        w64 = e / e.sum()
+        got = {"global_delta": g, "dots": dots[:k], "sqnorms": sqs[:k],
+               "sqg": sqg, "theta": theta[:k], "weights": w[:k],
+               "round_delta": delta}
+        want = {"global_delta": g64, "dots": dots64, "sqnorms": sqs64,
+                "sqg": sqg64, "theta": theta64, "weights": w64,
+                "round_delta": w64 @ xr}
+        diffs = {key: max_rel_diff(got[key], want[key], TOL) for key in got}
+        log(f"  {what} (fixed deltas, rows {k} -> {kp}) vs float64: "
+            + ", ".join(f"{key} {d:.3g}" for key, d in diffs.items())
+            + f" at {TOL} (<= 1 passes)")
+        self.check(max(diffs.values()) <= 1, f"{what} vs float64")
+
     def accuracies(self, what, accs):
         spread = max(accs) - min(accs)
         log(f"  {what}: acc@{ROUNDS} {accs} spread {spread:.4f}")
@@ -224,6 +285,10 @@ def four_chips(sm: Smoke) -> None:
                     runs["tree"],
                     tol=TOL_PARTITIONED if len(shape) == 1 else None)
             sm.accuracies(f"{tag} (flat_sharded/tree)", accs)
+            if len(shape) == 1:
+                n = sum(p.size for p in jax.tree.leaves(runs["tree"][0]))
+                sm.aggregation(f"{tag}: flat_sharded aggregation", mesh, up,
+                               n)
 
 
 def main() -> int:

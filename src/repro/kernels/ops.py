@@ -41,13 +41,3 @@ def tree_weighted_sum(stacked: PyTree, w: jax.Array, *, interpret=None):
 
     return jax.tree.map(leaf, stacked)
 
-
-def tree_vdot_batched(stacked: PyTree, single: PyTree, *, interpret=None):
-    parts = []
-    for x, g in zip(jax.tree.leaves(stacked), jax.tree.leaves(single)):
-        parts.append(
-            weighted_agg.batched_dot(
-                x.reshape(x.shape[0], -1), g.reshape(-1), interpret=interpret
-            )
-        )
-    return jnp.sum(jnp.stack(parts), axis=0)

@@ -1,88 +1,41 @@
-"""Oracles for the Pallas kernels (tests assert_allclose vs these).
+"""Pure-jnp references for the Pallas kernels.
 
-The reductions run in float64 NumPy over the f32 inputs, so an oracle's
-own rounding stays far below the kernels' f32 accumulation error, and
-does not depend on the host's reduction order."""
+`benchmarks.run --only kernels` times these under `jax.jit` as the XLA
+baseline, and `flash_attention` is the attention kernel's test oracle.
+Dots run at HIGHEST precision, so on a TPU a reference is not itself a
+reduced-precision MXU pass. The reduction kernels' parity tests compare
+against float64 oracles instead (`tests/_oracle.py`)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-
-def _f64(a) -> np.ndarray:
-    return np.asarray(jnp.asarray(a, jnp.float32), np.float64)
-
-
-def _f32(a) -> jax.Array:
-    return jnp.asarray(np.asarray(a, np.float32))
+_HI = jax.lax.Precision.HIGHEST
 
 
 def grad_dot_stats(a: jax.Array, b: jax.Array):
-    af, bf = _f64(a).reshape(-1), _f64(b).reshape(-1)
-    return _f32(af @ bf), _f32(af @ af), _f32(bf @ bf)
+    af = a.reshape(-1).astype(jnp.float32)
+    bf = b.reshape(-1).astype(jnp.float32)
+    return (jnp.dot(af, bf, precision=_HI), jnp.dot(af, af, precision=_HI),
+            jnp.dot(bf, bf, precision=_HI))
 
 
 def weighted_agg(w: jax.Array, x: jax.Array):
-    return _f32(_f64(w) @ _f64(x)).astype(x.dtype)
-
-
-def batched_dot(x: jax.Array, g: jax.Array):
-    return _f32(_f64(x) @ _f64(g))
+    return jnp.sum(
+        w.astype(jnp.float32)[:, None] * x.astype(jnp.float32), axis=0
+    ).astype(x.dtype)
 
 
 def round_stats(x: jax.Array, g: jax.Array, mask: jax.Array | None = None):
     """(dots (K,), sqnorms (K,), sqg ()) over x (K, N), g (N,)."""
-    xf, gf = _f64(x), _f64(g)
+    xf = x.astype(jnp.float32)
+    gf = g.astype(jnp.float32)
     if mask is not None:
-        mf = _f64(mask)
+        mf = mask.astype(jnp.float32)
         xf = xf * mf[None]
         gf = gf * mf
-    return _f32(xf @ gf), _f32(np.sum(xf * xf, axis=1)), _f32(gf @ gf)
-
-
-def _dequant(values: jax.Array, scales: jax.Array) -> jax.Array:
-    """(K, N) f32 from int8 wire values + per-chunk scales — delegates to
-    the transport layer's own dequantize so the oracle always verifies the
-    fused kernels against the ACTUAL wire semantics (a local re-derivation
-    could drift if the chunk layout ever changes)."""
-    from repro.transport.quantize import QuantizedDelta, dequantize
-
-    return dequantize(QuantizedDelta(values, scales))
-
-
-def weighted_agg_q(w: jax.Array, values: jax.Array, scales: jax.Array):
-    """Dequantize-then-f32 oracle for the fused weighted_agg_q kernel."""
-    return weighted_agg(w, _dequant(values, scales))
-
-
-def round_stats_q(values: jax.Array, scales: jax.Array, g: jax.Array,
-                  mask: jax.Array | None = None):
-    """Dequantize-then-f32 oracle for the fused round_stats_q kernel."""
-    return round_stats(_dequant(values, scales), g, mask)
-
-
-def _dequant4(values: jax.Array, scales: jax.Array, n: int,
-              group_size: int) -> jax.Array:
-    """(K, n) f32 from the int4 packed wire (nibble pairs + grouped
-    scales) — delegates to the transport layer's own dequantize, like
-    `_dequant`, so the oracle tracks the ACTUAL wire semantics."""
-    from repro.transport.quantize import QuantizedDelta, dequantize
-
-    return dequantize(QuantizedDelta(values, scales, "int4", n, group_size))
-
-
-def weighted_agg_q4(w: jax.Array, values: jax.Array, scales: jax.Array, *,
-                    n: int, group_size: int):
-    """Dequantize-then-f32 oracle for the fused weighted_agg_q4 kernel."""
-    return weighted_agg(w, _dequant4(values, scales, n, group_size))
-
-
-def round_stats_q4(values: jax.Array, scales: jax.Array, g: jax.Array,
-                   mask: jax.Array | None = None, *, group_size: int):
-    """Dequantize-then-f32 oracle for the fused round_stats_q4 kernel."""
-    return round_stats(_dequant4(values, scales, g.shape[0], group_size),
-                       g, mask)
+    return (jnp.dot(xf, gf, precision=_HI), jnp.sum(xf * xf, axis=1),
+            jnp.dot(gf, gf, precision=_HI))
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,

@@ -5,7 +5,7 @@ FedAdp's contribution measurement (paper Eqs. 8-11) needs, per round:
   sqs[k]  = ||x_k||^2   — K client squared norms
   sqg     = ||g||^2     — the global-gradient squared norm
 over the flat (K, N) client-delta buffer x and the (N,) global delta g.
-Computed separately (`batched_dot` + K sqnorm reductions + one sqnorm)
+Computed separately (one matvec + K sqnorm reductions + one sqnorm)
 that is three HBM passes over x; this kernel streams each (K_TILE, ROWS,
 128) tile through VMEM once and emits all 2K+1 statistics — a single HBM
 pass over x.

@@ -24,15 +24,12 @@ pass that moves ~4x fewer bytes (see repro.transport).
 (ROWS, LANE) byte tile holds TWO logical value chunks (low/high nibbles
 of consecutive element pairs), and the per-(client, group) scales are no
 longer 1:1 with tiles — a tile covers 2*CHUNK/group_size groups, expanded
-in-register by a static repeat. Both nibbles unpack in-register (shift /
+in-register (`_expand_group_scales`). Both nibbles unpack in-register (shift /
 mask / sign-extend on the int32 upcast), so aggregation over the int4
 uplink is a single HBM pass over ~8x fewer bytes than f32. The kernel
 emits separate even/odd accumulators (one per nibble plane) that the
 wrapper interleaves back to logical order — an O(N) f32 shuffle on the
 OUTPUT, never a second pass over the wire buffer.
-
-Also provides `batched_dot`: u_k = <x_k, g> for all K clients in one pass
-(the per-client angle numerators), sharing the same tiling.
 """
 from __future__ import annotations
 
@@ -355,48 +352,3 @@ def weighted_agg_q4(w: jax.Array, values: jax.Array, scales: jax.Array, *,
     y = jnp.stack([ye.reshape(-1), yo.reshape(-1)], axis=-1).reshape(-1)
     return y[:n]
 
-
-def _bdot_kernel(x_ref, g_ref, out_ref, *, k, tile):
-    kc = pl.program_id(0)
-
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    x = _mask_tail_rows(x_ref[...].astype(jnp.float32), kc, k=k, tile=tile)
-    g = g_ref[...].astype(jnp.float32)  # (ROWS, LANE)
-    out_ref[...] += jnp.sum(x * g[None], axis=1)  # (KT, LANE) partials
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("interpret", "min_kernel_elems"))
-def batched_dot(x: jax.Array, g: jax.Array, *, interpret=None,
-                min_kernel_elems=None):
-    """u[k] = <x[k], g>. x: (K, N), g: (N,). Buffers below
-    `min_kernel_elems` elements (default SMALL_ELEMS; 0 forces Pallas)
-    compute as one XLA matvec. The kernel keeps lane-dense (K, LANE)
-    partial sums; the lanes reduce after it."""
-    K, n = x.shape
-    if _use_fallback(K, n, min_kernel_elems):
-        return jnp.matmul(x.astype(jnp.float32), g.astype(jnp.float32),
-                          precision=jax.lax.Precision.HIGHEST)
-    tile, kp = _k_chunks(K)
-    rows = _row_block(n)
-    x = _pad_lanes(x, rows * LANE)
-    g = _pad_lanes(g, rows * LANE)
-    m = x.shape[1] // LANE
-    x3 = x.reshape(K, m, LANE)
-    g2 = g.reshape(m, LANE)
-
-    out = pl.pallas_call(
-        functools.partial(_bdot_kernel, k=K, tile=tile),
-        grid=(kp // tile, m // rows),
-        in_specs=[
-            pl.BlockSpec((tile, rows, LANE), lambda kc, i: (kc, i, 0)),
-            pl.BlockSpec((rows, LANE), lambda kc, i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((tile, LANE), lambda kc, i: (kc, 0)),
-        out_shape=jax.ShapeDtypeStruct((kp, LANE), jnp.float32),
-        interpret=interpret_mode(interpret),
-    )(x3, g2)
-    return jnp.sum(out[:K], axis=1)

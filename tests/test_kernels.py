@@ -1,11 +1,12 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret mode)."""
+"""Pallas kernels vs float64 oracles: shape/dtype sweeps (interpret mode)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import treemath
-from repro.kernels import grad_dot, ops, ref, round_stats, weighted_agg
+import _oracle as oracle
+from repro.kernels import grad_dot, ops, round_stats, weighted_agg
 
 SHAPES = [(7,), (128,), (65536,), (1000, 333), (3, 17, 129)]
 DTYPES = [jnp.float32, jnp.bfloat16]
@@ -19,7 +20,7 @@ def test_grad_dot_stats(shape, dtype):
     a = jax.random.normal(jax.random.key(0), shape, dtype)
     b = jax.random.normal(jax.random.key(1), shape, dtype)
     got = grad_dot.grad_dot_stats(a, b)
-    want = ref.grad_dot_stats(a, b)
+    want = oracle.grad_dot_stats(a, b)
     rtol = 1e-3 if dtype == jnp.float32 else 2e-2
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=rtol)
@@ -32,23 +33,10 @@ def test_weighted_agg(k, n, dtype):
     x = jax.random.normal(jax.random.key(0), (k, n), dtype)
     w = jax.random.uniform(jax.random.key(1), (k,), jnp.float32)
     got = weighted_agg.weighted_agg(w, x, min_kernel_elems=0)
-    want = ref.weighted_agg(w, x)
+    want = oracle.weighted_agg(w, x)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
         rtol=2e-2, atol=1e-2,
-    )
-
-
-@pytest.mark.parametrize("k", [1, 8, 32])
-@pytest.mark.parametrize("n", [128, 16385, 50000])
-@pytest.mark.parametrize("dtype", DTYPES)
-def test_batched_dot(k, n, dtype):
-    x = jax.random.normal(jax.random.key(0), (k, n), dtype)
-    g = jax.random.normal(jax.random.key(1), (n,), dtype)
-    rtol = 1e-3 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(
-        np.asarray(weighted_agg.batched_dot(x, g, min_kernel_elems=0)),
-        np.asarray(ref.batched_dot(x, g)), rtol=rtol, atol=1e-2,
     )
 
 
@@ -59,7 +47,7 @@ def test_round_stats(k, n, dtype):
     x = jax.random.normal(jax.random.key(0), (k, n), dtype)
     g = jax.random.normal(jax.random.key(1), (n,), dtype)
     got = round_stats.round_stats(x, g, min_kernel_elems=0)
-    want = ref.round_stats(x, g)
+    want = oracle.round_stats(x, g)
     rtol = 1e-3 if dtype == jnp.float32 else 2e-2
     for gg, ww, name in zip(got, want, ("dots", "sqnorms", "sqg")):
         np.testing.assert_allclose(np.asarray(gg), np.asarray(ww), rtol=rtol,
@@ -73,7 +61,7 @@ def test_round_stats_masked(n):
     mask = (jax.random.uniform(jax.random.key(2), (n,)) > 0.5).astype(
         jnp.float32)
     got = round_stats.round_stats(x, g, mask, min_kernel_elems=0)
-    want = ref.round_stats(x, g, mask)
+    want = oracle.round_stats(x, g, mask)
     for gg, ww, name in zip(got, want, ("dots", "sqnorms", "sqg")):
         np.testing.assert_allclose(np.asarray(gg), np.asarray(ww), rtol=1e-3,
                                    err_msg=name)
@@ -96,7 +84,7 @@ def test_chunked_round_stats(k, n, dtype):
     x = jax.random.normal(jax.random.key(0), (k, n), dtype)
     g = jax.random.normal(jax.random.key(1), (n,), dtype)
     got = round_stats.round_stats(x, g, min_kernel_elems=0)
-    want = ref.round_stats(x, g)
+    want = oracle.round_stats(x, g)
     rtol = 1e-3 if dtype == jnp.float32 else 2e-2
     for gg, ww, name in zip(got, want, ("dots", "sqnorms", "sqg")):
         np.testing.assert_allclose(np.asarray(gg), np.asarray(ww), rtol=rtol,
@@ -105,18 +93,14 @@ def test_chunked_round_stats(k, n, dtype):
 
 @pytest.mark.parametrize("k", CHUNK_KS)
 @pytest.mark.parametrize("dtype", DTYPES)
-def test_chunked_weighted_agg_and_batched_dot(k, dtype):
+def test_chunked_weighted_agg(k, dtype):
     n = 16385  # one lane-block plus a ragged tail
     x = jax.random.normal(jax.random.key(0), (k, n), dtype)
-    g = jax.random.normal(jax.random.key(1), (n,), dtype)
     w = jax.random.uniform(jax.random.key(2), (k,), jnp.float32)
     np.testing.assert_allclose(
         np.asarray(weighted_agg.weighted_agg(w, x, min_kernel_elems=0),
                    np.float32),
-        np.asarray(ref.weighted_agg(w, x), np.float32), rtol=2e-2, atol=1e-2)
-    np.testing.assert_allclose(
-        np.asarray(weighted_agg.batched_dot(x, g, min_kernel_elems=0)),
-        np.asarray(ref.batched_dot(x, g)), rtol=2e-2, atol=1e-1)
+        np.asarray(oracle.weighted_agg(w, x), np.float32), rtol=2e-2, atol=1e-2)
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
@@ -130,7 +114,7 @@ def test_chunked_round_stats_masked_across_chunk_boundary(dtype):
     # as segment_mask produces for a dropped leaf
     mask = jnp.ones((n,), jnp.float32).at[16000:17000].set(0.0)
     got = round_stats.round_stats(x, g, mask, min_kernel_elems=0)
-    want = ref.round_stats(x, g, mask)
+    want = oracle.round_stats(x, g, mask)
     rtol = 1e-3 if dtype == jnp.float32 else 2e-2
     for gg, ww, name in zip(got, want, ("dots", "sqnorms", "sqg")):
         np.testing.assert_allclose(np.asarray(gg), np.asarray(ww), rtol=rtol,
@@ -162,7 +146,7 @@ def test_round_stats_q4_kernel_chunk_and_group_boundaries(k, gs):
     values, scales = _int4_wire(jax.random.key(0), k, n, gs)
     g = jax.random.normal(jax.random.key(1), (n,), jnp.float32)
     got = round_stats.round_stats_q4(values, scales, g, group_size=gs)
-    want = ref.round_stats_q4(values, scales, g, group_size=gs)
+    want = oracle.round_stats_q4(values, scales, g, group_size=gs)
     for gg, ww, name in zip(got, want, ("dots", "sqnorms", "sqg")):
         np.testing.assert_allclose(np.asarray(gg), np.asarray(ww), rtol=2e-3,
                                    atol=1e-2, err_msg=name)
@@ -175,7 +159,7 @@ def test_weighted_agg_q4_kernel_chunk_and_group_boundaries(k, gs):
     values, scales = _int4_wire(jax.random.key(2), k, n, gs)
     w = jax.random.uniform(jax.random.key(3), (k,), jnp.float32)
     got = weighted_agg.weighted_agg_q4(w, values, scales, n=n, group_size=gs)
-    want = ref.weighted_agg_q4(w, values, scales, n=n, group_size=gs)
+    want = oracle.weighted_agg_q4(w, values, scales, n=n, group_size=gs)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3,
                                atol=1e-3)
 
@@ -226,10 +210,6 @@ def test_small_shape_fallback_matches_kernel(k, n):
         np.asarray(weighted_agg.weighted_agg(w, x)),
         np.asarray(weighted_agg.weighted_agg(w, x, min_kernel_elems=0)),
         rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(
-        np.asarray(weighted_agg.batched_dot(x, g)),
-        np.asarray(weighted_agg.batched_dot(x, g, min_kernel_elems=0)),
-        rtol=1e-5, atol=1e-3)
     for a, b, name in zip(
             round_stats.round_stats(x, g),
             round_stats.round_stats(x, g, min_kernel_elems=0),
@@ -246,7 +226,6 @@ def test_small_shape_fallback_trace_time_dispatch():
     g = jnp.ones((1024,), jnp.float32)
     w = jnp.ones((8,), jnp.float32)
     assert not _has_pallas_call(weighted_agg.weighted_agg, w, x)
-    assert not _has_pallas_call(weighted_agg.batched_dot, x, g)
     assert not _has_pallas_call(round_stats.round_stats, x, g)
     assert _has_pallas_call(weighted_agg.weighted_agg, w, x,
                             min_kernel_elems=0)
@@ -304,12 +283,39 @@ def test_ops_tree_weighted_sum_matches_treemath():
     )
 
 
-def test_ops_tree_vdot_batched_matches_treemath():
-    trees = jax.tree.map(
-        lambda *xs: jnp.stack(xs), *[_tree(jax.random.key(i)) for i in range(3)]
-    )
-    single = _tree(jax.random.key(9))
-    np.testing.assert_allclose(
-        np.asarray(ops.tree_vdot_batched(trees, single)),
-        np.asarray(treemath.tree_vdot_batched(trees, single)), rtol=1e-3,
-    )
+
+@pytest.mark.parametrize("name", ["grad_dot_stats", "weighted_agg",
+                                  "round_stats"])
+def test_xla_reference_jits_and_matches_oracle(name):
+    """`repro.kernels.ref` is the benchmarks' jitted XLA baseline: it must
+    trace under jax.jit and compute what the float64 oracle computes."""
+    from repro.kernels import ref
+
+    x = jax.random.normal(jax.random.key(0), (4, 3000), jnp.float32)
+    g = jax.random.normal(jax.random.key(1), (3000,), jnp.float32)
+    w = jax.random.uniform(jax.random.key(2), (4,), jnp.float32)
+    args = {"grad_dot_stats": (x[0], g), "weighted_agg": (w, x),
+            "round_stats": (x, g)}[name]
+    got = jax.jit(getattr(ref, name))(*args)
+    want = getattr(oracle, name)(*args)
+    for gg, ww in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(gg), np.asarray(ww),
+                                   rtol=1e-5, atol=1e-4)
+
+
+def test_kernel_micro_bench_runs_tiny(capsys):
+    """`python -m benchmarks.run --only kernels --tiny` end to end: every
+    kernel and its jitted XLA reference emit a finite time."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    from benchmarks import run
+
+    run.kernel_micro(tiny=True)
+    lines = capsys.readouterr().out.strip().splitlines()
+    names = [ln.split(",")[0] for ln in lines]
+    assert names == [f"kernel/{k}/{v}"
+                     for k in ("grad_dot", "weighted_agg", "round_stats")
+                     for v in ("pallas_interp", "xla_ref")], names
+    assert all(np.isfinite(float(ln.split(",")[1])) for ln in lines)

@@ -6,9 +6,6 @@ tests lower each kernel of the flat engine for a described (not
 attached) v5e chip at the paper CNN's width, N = 1,663,370, for one
 client chunk (K=10) and a ragged second chunk (K=33), and check that the
 compiled program holds the Mosaic kernel (`tpu_custom_call`).
-
-`batched_dot` is left out: a Mosaic abort there takes the whole process
-down, and with it a pytest-xdist worker.
 """
 import os
 

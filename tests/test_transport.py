@@ -17,7 +17,8 @@ import pytest
 
 from repro import transport
 from repro.core import fl, fl_shard_map, treemath
-from repro.kernels import ref, round_stats, weighted_agg
+import _oracle as oracle
+from repro.kernels import round_stats, weighted_agg
 from repro.launch.mesh import make_mesh
 from repro.transport.quantize import CHUNK
 
@@ -195,7 +196,7 @@ def test_round_stats_q_matches_dequant_oracle(k, n):
     q = transport.quantize(_chunky(jax.random.key(0), k, n), "int8")
     g = jax.random.normal(jax.random.key(1), (n,), jnp.float32)
     got = round_stats.round_stats_q(q.values, q.scales, g)
-    want = ref.round_stats_q(q.values, q.scales, g)
+    want = oracle.round_stats_q(q.values, q.scales, g)
     for gg, ww, name in zip(got, want, ("dots", "sqnorms", "sqg")):
         np.testing.assert_allclose(np.asarray(gg), np.asarray(ww), rtol=1e-3,
                                    atol=1e-2, err_msg=name)
@@ -207,7 +208,7 @@ def test_weighted_agg_q_matches_dequant_oracle(k, n):
     q = transport.quantize(_chunky(jax.random.key(2), k, n), "int8")
     w = jax.random.uniform(jax.random.key(3), (k,), jnp.float32)
     got = weighted_agg.weighted_agg_q(w, q.values, q.scales)
-    want = ref.weighted_agg_q(w, q.values, q.scales)
+    want = oracle.weighted_agg_q(w, q.values, q.scales)
     assert got.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-3,
                                atol=1e-4)
@@ -222,7 +223,7 @@ def test_round_stats_q_masked_across_chunk_boundary():
     g = jax.random.normal(jax.random.key(5), (n,), jnp.float32)
     mask = jnp.ones((n,), jnp.float32).at[CHUNK - 500:CHUNK + 500].set(0.0)
     got = round_stats.round_stats_q(q.values, q.scales, g, mask)
-    want = ref.round_stats_q(q.values, q.scales, g, mask)
+    want = oracle.round_stats_q(q.values, q.scales, g, mask)
     for gg, ww, name in zip(got, want, ("dots", "sqnorms", "sqg")):
         np.testing.assert_allclose(np.asarray(gg), np.asarray(ww), rtol=1e-3,
                                    err_msg=name)
@@ -243,7 +244,7 @@ def test_round_stats_q4_matches_dequant_oracle(k, n, gs):
                            "int4", group_size=gs)
     g = jax.random.normal(jax.random.key(11), (n,), jnp.float32)
     got = round_stats.round_stats_q4(q.values, q.scales, g, group_size=gs)
-    want = ref.round_stats_q4(q.values, q.scales, g, group_size=gs)
+    want = oracle.round_stats_q4(q.values, q.scales, g, group_size=gs)
     for gg, ww, name in zip(got, want, ("dots", "sqnorms", "sqg")):
         np.testing.assert_allclose(np.asarray(gg), np.asarray(ww), rtol=2e-3,
                                    atol=1e-2, err_msg=name)
@@ -258,7 +259,7 @@ def test_weighted_agg_q4_matches_dequant_oracle(k, n, gs):
     w = jax.random.uniform(jax.random.key(13), (k,), jnp.float32)
     got = weighted_agg.weighted_agg_q4(w, q.values, q.scales, n=n,
                                        group_size=gs)
-    want = ref.weighted_agg_q4(w, q.values, q.scales, n=n, group_size=gs)
+    want = oracle.weighted_agg_q4(w, q.values, q.scales, n=n, group_size=gs)
     assert got.dtype == jnp.float32 and got.shape == (n,)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3,
                                atol=1e-3)
@@ -277,7 +278,7 @@ def test_round_stats_q4_masked_across_boundaries():
     mask = jnp.ones((n,), jnp.float32).at[gs - 101:CHUNK + 501].set(0.0)
     got = round_stats.round_stats_q4(q.values, q.scales, g, mask,
                                      group_size=gs)
-    want = ref.round_stats_q4(q.values, q.scales, g, mask, group_size=gs)
+    want = oracle.round_stats_q4(q.values, q.scales, g, mask, group_size=gs)
     for gg, ww, name in zip(got, want, ("dots", "sqnorms", "sqg")):
         np.testing.assert_allclose(np.asarray(gg), np.asarray(ww), rtol=2e-3,
                                    atol=1e-2, err_msg=name)
@@ -294,13 +295,13 @@ def test_q4_kernels_odd_n_tail_nibble():
     g = jax.random.normal(jax.random.key(17), (n,), jnp.float32)
     w = jax.random.uniform(jax.random.key(18), (k,), jnp.float32)
     got = round_stats.round_stats_q4(q.values, q.scales, g, group_size=gs)
-    want = ref.round_stats_q4(q.values, q.scales, g, group_size=gs)
+    want = oracle.round_stats_q4(q.values, q.scales, g, group_size=gs)
     for gg, ww in zip(got, want):
         np.testing.assert_allclose(np.asarray(gg), np.asarray(ww), rtol=2e-3,
                                    atol=1e-2)
     ya = weighted_agg.weighted_agg_q4(w, q.values, q.scales, n=n,
                                       group_size=gs)
-    yw = ref.weighted_agg_q4(w, q.values, q.scales, n=n, group_size=gs)
+    yw = oracle.weighted_agg_q4(w, q.values, q.scales, n=n, group_size=gs)
     np.testing.assert_allclose(np.asarray(ya), np.asarray(yw), rtol=2e-3,
                                atol=1e-3)
 
@@ -320,7 +321,7 @@ def test_q4_fuzz_parity_seeded():
         g = jnp.asarray(rng.normal(size=(n,)).astype(np.float32))
         got = round_stats.round_stats_q4(q.values, q.scales, g,
                                          group_size=gs)
-        want = ref.round_stats_q4(q.values, q.scales, g, group_size=gs)
+        want = oracle.round_stats_q4(q.values, q.scales, g, group_size=gs)
         for gg, ww, name in zip(got, want, ("dots", "sqnorms", "sqg")):
             np.testing.assert_allclose(
                 np.asarray(gg), np.asarray(ww), rtol=2e-3, atol=1e-2,
@@ -337,7 +338,7 @@ def test_bf16_wire_through_plain_kernels(k):
     w = jax.random.uniform(jax.random.key(7), (k,), jnp.float32)
     got = weighted_agg.weighted_agg(w, wire, out_dtype=jnp.float32)
     assert got.dtype == jnp.float32
-    want = ref.weighted_agg(w, wire.astype(jnp.float32))
+    want = oracle.weighted_agg(w, wire.astype(jnp.float32))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-3,
                                atol=1e-4)
 
