@@ -226,28 +226,36 @@ def make_step_fn(loss_fn: Callable, fl: fl_mod.FLConfig, data: ClientData,
     `state.buf`), and `arrival_fn` (an explicit per-tick delay/dropout
     schedule, e.g. `core.server.fixed_arrival_schedule`) flows through
     to the round builder. Both are inert for sync configs.
+
+    Selection and batching run under the `jax.named_scope` ``fl.select``
+    and the eval under ``fl.eval``; the round's own phases carry the
+    ``fl.*`` scopes `fl.make_round_fn` lists.
     """
     buffered = fl.aggregation == "buffered"
     round_fn = fl_mod.make_round_fn(loss_fn, fl, angle_pred=angle_pred,
                                     mesh=mesh, arrival_fn=arrival_fn)
 
     def step(state: fl_mod.RoundState, eval_every):
-        rng, k_sel, k_bat = jax.random.split(state.rng, 3)
-        if buffered and fl.clients_per_round < fl.num_clients:
-            busy = buffer_mod.population_busy(state.buf, fl.num_clients)
-            sel = select_clients_avoiding(k_sel, fl.num_clients,
-                                          fl.clients_per_round, busy)
-        else:
-            sel = select_clients(k_sel, fl.num_clients, fl.clients_per_round)
-        batches = epoch_batches(k_bat, data, sel)
-        sizes = data.sizes[sel].astype(jnp.float32)
+        with jax.named_scope("fl.select"):
+            rng, k_sel, k_bat = jax.random.split(state.rng, 3)
+            if buffered and fl.clients_per_round < fl.num_clients:
+                busy = buffer_mod.population_busy(state.buf,
+                                                  fl.num_clients)
+                sel = select_clients_avoiding(k_sel, fl.num_clients,
+                                              fl.clients_per_round, busy)
+            else:
+                sel = select_clients(k_sel, fl.num_clients,
+                                     fl.clients_per_round)
+            batches = epoch_batches(k_bat, data, sel)
+            sizes = data.sizes[sel].astype(jnp.float32)
         state, metrics = round_fn(state._replace(rng=rng), batches, sel,
                                   sizes)
         if eval_fn is not None:
-            do_eval = (eval_every > 0) & (state.round % eval_every == 0)
-            acc = jax.lax.cond(do_eval, eval_fn,
-                               lambda p: jnp.float32(EVAL_SENTINEL),
-                               state.params)
+            with jax.named_scope("fl.eval"):
+                do_eval = (eval_every > 0) & (state.round % eval_every == 0)
+                acc = jax.lax.cond(do_eval, eval_fn,
+                                   lambda p: jnp.float32(EVAL_SENTINEL),
+                                   state.params)
             metrics = dict(metrics, accuracy=acc)
         return state, metrics
 
@@ -310,19 +318,22 @@ def run_rounds(run_block: Callable, state: fl_mod.RoundState, rounds: int,
     ambiguity reaches the stream) plus per-node rows when the config's
     `telemetry="node"` metrics are present; `telemetry_every` subsamples
     the emitted rounds. `spans` (a `telemetry.spans.SpanTimer`; one is
-    created over `sink` when omitted) bounds each block dispatch +
-    device_get as a ``scan_block`` span, checkpoint writes as
-    ``checkpoint``, and event emission as ``sink_emit`` — the
-    wall-clock-per-round numbers flstat reports come from these.
+    created over `sink` when omitted) bounds the read of the starting
+    round counter as ``read_round``, each block as a ``scan_block`` span
+    holding its ``dispatch`` and the ``fetch`` (device_get) of its
+    metrics, checkpoint writes as ``checkpoint``, and event emission as
+    ``sink_emit`` — the wall-clock-per-round numbers flstat reports come
+    from these.
 
     Returns (state, metrics, rounds_to_target, rounds_run) where metrics
     holds per-round host arrays stacked over every round run THIS call
     (`rounds_run` counts the same; rounds_to_target is absolute).
     """
-    base = int(jax.device_get(state.round))
-    saved_at = None
     if spans is None:
         spans = tel_spans.SpanTimer(sink)
+    with spans.span("read_round"):
+        base = int(jax.device_get(state.round))
+    saved_at = None
 
     def checkpoint(round_now):
         nonlocal saved_at
@@ -339,9 +350,11 @@ def run_rounds(run_block: Callable, state: fl_mod.RoundState, rounds: int,
     while done < rounds and rounds_to_target is None:
         length = min(block, rounds - done)
         with spans.span("scan_block", round=base + done):
-            state, ms = run_block(state, jnp.int32(eval_every),
-                                  length=length)
-            ms = jax.device_get(ms)
+            with spans.span("dispatch"):
+                state, ms = run_block(state, jnp.int32(eval_every),
+                                      length=length)
+            with spans.span("fetch"):
+                ms = jax.device_get(ms)
         blocks.append(ms)
         if sink is not None:
             with spans.span("sink_emit", round=base + done):

@@ -736,6 +736,17 @@ def make_round_fn(loss_fn: Callable, fl: FLConfig,
     `arrival_fn(tick) -> (delay (K,) i32, drop (K,) bool)` overrides the
     config's random straggler/dropout draw with an explicit schedule
     (`core.server.fixed_arrival_schedule`); sync mode ignores it.
+
+    Every builder runs each phase of the round under one
+    `jax.named_scope`, so a profiler trace names the phase of each
+    device op: ``fl.downlink`` (compress or decode the broadcast),
+    ``fl.local_train`` (the clients' local updates), ``fl.uplink`` (wire
+    compression, flattening to the (K, N) buffer, buffer admission),
+    ``fl.stats`` (the global reference delta, round statistics and the
+    Eq. 9 angle), ``fl.weights`` (FedAvg and Gompertz weights and their
+    normalisation) and ``fl.aggregate`` (the weighted sum and its
+    application to the params); the driver adds ``fl.select`` and
+    ``fl.eval``. Metrics bookkeeping stays outside them.
     """
     fl.validate()
     if angle_pred is None and fl.angle_filter == "dense_only":
@@ -906,95 +917,100 @@ def _make_parallel_round(loss_fn, fl: FLConfig, delta_constraint, angle_pred=Non
         params_srv = params
         new_dl, new_bcast = dl_state, state.bcast
         down_split = None
-        if fl.downlink != "f32":
-            pvec, punravel = treemath.tree_ravel(params)
-            if fl.downlink_delta:
-                # delta encoding: compress the model DIFF against the
-                # chain head (the canonical reconstruction) — per-round
-                # diffs are small, so the quant scales track them tightly.
-                pvec = pvec - state.bcast.head
-            if fl.downlink_error_feedback:
-                # EF-SGD mirror: replay the carried broadcast residual,
-                # then carry what this round's compression drops.
-                pvec = pvec + dl_state
-            qd = transport_mod.downlink.compress(pvec, fl.downlink)
-            recon = transport_mod.downlink.decompress(qd)
-            if fl.downlink_error_feedback:
-                new_dl = pvec - recon
-            if fl.downlink_delta:
-                # publish version v = head_ver + 1 into the ring and
-                # advance the chain head (recon is this version's delta
-                # reconstruction D_v); every selected client pulls the
-                # new head (delta-decoded or resynced), so its
-                # last-pulled version moves to v.
-                new_bcast = transport_mod.downlink.advance_broadcast(
-                    state.bcast, recon)
-                recon = new_bcast.head
-                v = new_bcast.head_ver
-                if fl.telemetry:
-                    down_split = _down_byte_split(
-                        fl, pvec.shape[0], state.bcast.ver[sel_idx], v)
-                new_bcast = new_bcast._replace(
-                    ver=new_bcast.ver.at[sel_idx].set(v))
-            params = punravel(recon)
+        with jax.named_scope("fl.downlink"):
+            if fl.downlink != "f32":
+                pvec, punravel = treemath.tree_ravel(params)
+                if fl.downlink_delta:
+                    # delta encoding: compress the model DIFF against the
+                    # chain head (the canonical reconstruction) — per-round
+                    # diffs are small, so the quant scales track them tightly.
+                    pvec = pvec - state.bcast.head
+                if fl.downlink_error_feedback:
+                    # EF-SGD mirror: replay the carried broadcast residual,
+                    # then carry what this round's compression drops.
+                    pvec = pvec + dl_state
+                qd = transport_mod.downlink.compress(pvec, fl.downlink)
+                recon = transport_mod.downlink.decompress(qd)
+                if fl.downlink_error_feedback:
+                    new_dl = pvec - recon
+                if fl.downlink_delta:
+                    # publish version v = head_ver + 1 into the ring and
+                    # advance the chain head (recon is this version's delta
+                    # reconstruction D_v); every selected client pulls the
+                    # new head (delta-decoded or resynced), so its
+                    # last-pulled version moves to v.
+                    new_bcast = transport_mod.downlink.advance_broadcast(
+                        state.bcast, recon)
+                    recon = new_bcast.head
+                    v = new_bcast.head_ver
+                    if fl.telemetry:
+                        down_split = _down_byte_split(
+                            fl, pvec.shape[0], state.bcast.ver[sel_idx], v)
+                    new_bcast = new_bcast._replace(
+                        ver=new_bcast.ver.at[sel_idx].set(v))
+                params = punravel(recon)
 
-        deltas, losses = jax.vmap(
-            lambda b: local_update(loss_fn, params, b, lr, fl.prox_mu,
-                                   grad_constraint)
-        )(batches)
-        if delta_constraint is not None:
-            deltas = delta_constraint(deltas)
+        with jax.named_scope("fl.local_train"):
+            deltas, losses = jax.vmap(
+                lambda b: local_update(loss_fn, params, b, lr, fl.prox_mu,
+                                       grad_constraint)
+            )(batches)
+            if delta_constraint is not None:
+                deltas = delta_constraint(deltas)
 
-        psi_avg = weighting.fedavg_weights(data_sizes)
+        with jax.named_scope("fl.weights"):
+            psi_avg = weighting.fedavg_weights(data_sizes)
         new_ef = ef_state
 
         # ---- client uplink: compress the stacked deltas to the wire ----
-        if fl.transport != "f32" and wire_2d:
-            # 2D (client x model) mesh: the wire is quantized per-shard in
-            # blocked order (see fl_shard_map.make_round_ops_2d). The
-            # flat_sharded engine quantizes INSIDE its region; the tree
-            # engine consumes the identical reconstruction through the
-            # blocked roundtrip region here (per-leaf reference reductions
-            # then run on the dequantized tree, so "tree never reads the
-            # wire buffer" still holds).
-            if fl.engine == "tree":
-                k = data_sizes.shape[0]
-                kp = -(-k // csize) * csize
-                deltas_p = jax.tree.map(lambda d: _pad_rows(d, kp), deltas)
-                rt = fl_shard_map.make_blocked_roundtrip(
-                    mesh, deltas_p, _derive_param_pspecs(params, mesh),
-                    transport=fl.transport, group_size=fl.group_size)
-                deltas = jax.tree.map(lambda d: d[:k], rt(deltas_p))
-        elif fl.transport != "f32":
-            flat0, unravel0 = treemath.tree_ravel_stacked(deltas)
-            if fl.error_feedback:
-                # EF-SGD: replay the carried residual into this round's
-                # signal, then carry what quantization drops this round.
-                flat0 = flat0 + ef_state[sel_idx]
-            q = transport_mod.quantize(flat0, fl.transport,
-                                       group_size=fl.group_size)
-            if fl.error_feedback:
-                new_ef = ef_state.at[sel_idx].set(
-                    flat0 - transport_mod.dequantize(q))
-            if fl.engine == "tree":
-                # reference contract: the tree engine never reads the wire
-                # buffer — dequantize back to the stacked tree and run the
-                # per-leaf reference reductions on the reconstruction.
-                # f32 leaves: rounding the dequantized values to a bf16
-                # leaf dtype would add a second loss the flat engines
-                # (which stream the wire directly) never incur.
-                deltas = treemath.tree_unravel_stacked(
-                    deltas, transport_mod.dequantize(q), jnp.float32)
+        with jax.named_scope("fl.uplink"):
+            if fl.transport != "f32" and wire_2d:
+                # 2D (client x model) mesh: the wire is quantized per-shard in
+                # blocked order (see fl_shard_map.make_round_ops_2d). The
+                # flat_sharded engine quantizes INSIDE its region; the tree
+                # engine consumes the identical reconstruction through the
+                # blocked roundtrip region here (per-leaf reference reductions
+                # then run on the dequantized tree, so "tree never reads the
+                # wire buffer" still holds).
+                if fl.engine == "tree":
+                    k = data_sizes.shape[0]
+                    kp = -(-k // csize) * csize
+                    deltas_p = jax.tree.map(lambda d: _pad_rows(d, kp), deltas)
+                    rt = fl_shard_map.make_blocked_roundtrip(
+                        mesh, deltas_p, _derive_param_pspecs(params, mesh),
+                        transport=fl.transport, group_size=fl.group_size)
+                    deltas = jax.tree.map(lambda d: d[:k], rt(deltas_p))
+            elif fl.transport != "f32":
+                flat0, unravel0 = treemath.tree_ravel_stacked(deltas)
+                if fl.error_feedback:
+                    # EF-SGD: replay the carried residual into this round's
+                    # signal, then carry what quantization drops this round.
+                    flat0 = flat0 + ef_state[sel_idx]
+                q = transport_mod.quantize(flat0, fl.transport,
+                                           group_size=fl.group_size)
+                if fl.error_feedback:
+                    new_ef = ef_state.at[sel_idx].set(
+                        flat0 - transport_mod.dequantize(q))
+                if fl.engine == "tree":
+                    # reference contract: the tree engine never reads the wire
+                    # buffer — dequantize back to the stacked tree and run the
+                    # per-leaf reference reductions on the reconstruction.
+                    # f32 leaves: rounding the dequantized values to a bf16
+                    # leaf dtype would add a second loss the flat engines
+                    # (which stream the wire directly) never incur.
+                    deltas = treemath.tree_unravel_stacked(
+                        deltas, transport_mod.dequantize(q), jnp.float32)
 
         # (N,) 0/1 segment mask over the ravel order — ONE copy shared by
         # both flat engines (the tree engine masks per-leaf views instead),
         # so the angle_filter semantics cannot fork between them. The 2D
         # engine bakes the same per-leaf keep flags into its shard-local
         # blocked mask instead (treemath.blocked_segment_mask).
-        maskv = None
-        if fl.engine != "tree" and angle_pred and not wire_2d:
-            maskv = treemath.segment_mask(params,
-                                          angle_keep_list(params, angle_pred))
+        with jax.named_scope("fl.stats"):
+            maskv = None
+            if fl.engine != "tree" and angle_pred and not wire_2d:
+                maskv = treemath.segment_mask(params,
+                                              angle_keep_list(params, angle_pred))
 
         if fl.engine == "flat_sharded" and wire_2d:
             # one shard_map region over the (client x model) tile grid:
@@ -1004,7 +1020,8 @@ def _make_parallel_round(loss_fn, fl: FLConfig, delta_constraint, angle_pred=Non
             # back still sharded (no full-N gather anywhere).
             k = data_sizes.shape[0]
             kp = -(-k // csize) * csize
-            deltas_p = jax.tree.map(lambda d: _pad_rows(d, kp), deltas)
+            with jax.named_scope("fl.uplink"):
+                deltas_p = jax.tree.map(lambda d: _pad_rows(d, kp), deltas)
             keep = (angle_keep_list(params, angle_pred)
                     if angle_pred else None)
             round_ops_2d = fl_shard_map.make_round_ops_2d(
@@ -1023,30 +1040,33 @@ def _make_parallel_round(loss_fn, fl: FLConfig, delta_constraint, angle_pred=Non
             theta, w = theta[:k], w[:k]
             # f32 in-region accumulate, ONE cast to the param leaf dtype —
             # the same rounding schedule as the 1D engines' unravel.
-            delta = jax.tree.map(lambda d, p: d.astype(p.dtype), delta,
-                                 params)
+            with jax.named_scope("fl.aggregate"):
+                delta = jax.tree.map(lambda d, p: d.astype(p.dtype), delta,
+                                     params)
         elif fl.engine == "flat_sharded":
             # the WHOLE round is one shard_map call (stats psums ->
             # replicated Eq.9 + Gompertz weighting -> aggregate psum):
             # rows sharded over ("pod","data"), per-shard fused kernels.
-            if fl.transport == "f32":
-                flat, unravel = treemath.tree_ravel_stacked(deltas)
-                values, scales = flat, None
-                n_logical = flat.shape[1]
-            else:
-                values, scales, unravel = q.values, q.scales, unravel0
-                # int4 packs two params per byte: the wire buffer width is
-                # NOT the logical width the mask/g vectors live in.
-                n_logical = flat0.shape[1]
-            k = values.shape[0]
-            kp = -(-k // csize) * csize  # pad the client axis to the mesh
-            values = jax.lax.with_sharding_constraint(
-                _pad_rows(values, kp), row_sharding)
+            with jax.named_scope("fl.uplink"):
+                if fl.transport == "f32":
+                    flat, unravel = treemath.tree_ravel_stacked(deltas)
+                    values, scales = flat, None
+                    n_logical = flat.shape[1]
+                else:
+                    values, scales, unravel = q.values, q.scales, unravel0
+                    # int4 packs two params per byte: the wire buffer
+                    # width is NOT the logical width the mask/g vectors
+                    # live in.
+                    n_logical = flat0.shape[1]
+                k = values.shape[0]
+                kp = -(-k // csize) * csize  # pad the client axis
+                values = jax.lax.with_sharding_constraint(
+                    _pad_rows(values, kp), row_sharding)
+                wire = (values,) if scales is None else (
+                    values, jax.lax.with_sharding_constraint(
+                        _pad_rows(scales, kp, 1.0), row_sharding))
             mvec = (maskv if maskv is not None
                     else jnp.ones((n_logical,), jnp.float32))
-            wire = (values,) if scales is None else (
-                values, jax.lax.with_sharding_constraint(
-                    _pad_rows(scales, kp, 1.0), row_sharding))
             # padded rows: zero deltas, zero data size -> -inf softmax
             # logit -> exactly zero weight and zero stats contribution.
             g_flat, dots, sqs, sqg, delta_flat, theta, _, w = round_ops(
@@ -1056,8 +1076,10 @@ def _make_parallel_round(loss_fn, fl: FLConfig, delta_constraint, angle_pred=Non
                 _pad_rows(data_sizes, kp))
             dots, sqs = dots[:k], sqs[:k]
             theta, w = theta[:k], w[:k]
-            g_avg = unravel(g_flat, jnp.float32)
-            delta = unravel(delta_flat)
+            with jax.named_scope("fl.stats"):
+                g_avg = unravel(g_flat, jnp.float32)
+            with jax.named_scope("fl.aggregate"):
+                delta = unravel(delta_flat)
         elif fl.engine == "flat":
             # single (K, N) ravel; stats + both aggregations are fused
             # single-HBM-pass kernels over the contiguous buffer
@@ -1065,7 +1087,8 @@ def _make_parallel_round(loss_fn, fl: FLConfig, delta_constraint, angle_pred=Non
             # envelope). Quantized wire buffers flow through the
             # fused-dequant kernel variants untouched.
             if fl.transport == "f32":
-                flat, unravel = treemath.tree_ravel_stacked(deltas)
+                with jax.named_scope("fl.uplink"):
+                    flat, unravel = treemath.tree_ravel_stacked(deltas)
                 wire_x, wire_s = flat, None
             else:
                 unravel = unravel0
@@ -1082,61 +1105,72 @@ def _make_parallel_round(loss_fn, fl: FLConfig, delta_constraint, angle_pred=Non
                 return weighted_agg_mod.weighted_agg_q(
                     wvec, wire_x, wire_s)
 
-            g_flat = agg_wire(psi_avg)
-            if wire_s is None:
-                dots, sqs, sqg = round_stats_mod.round_stats(
-                    wire_x, g_flat, maskv)
-            elif fl.transport == "int4":
-                dots, sqs, sqg = round_stats_mod.round_stats_q4(
-                    wire_x, wire_s, g_flat, maskv,
-                    group_size=fl.group_size)
-            else:
-                dots, sqs, sqg = round_stats_mod.round_stats_q(
-                    wire_x, wire_s, g_flat, maskv)
-            g_avg = unravel(g_flat, jnp.float32)
-            theta = weighting.instantaneous_angle(dots, sqs, sqg)
+            with jax.named_scope("fl.stats"):
+                g_flat = agg_wire(psi_avg)
+                if wire_s is None:
+                    dots, sqs, sqg = round_stats_mod.round_stats(
+                        wire_x, g_flat, maskv)
+                elif fl.transport == "int4":
+                    dots, sqs, sqg = round_stats_mod.round_stats_q4(
+                        wire_x, wire_s, g_flat, maskv,
+                        group_size=fl.group_size)
+                else:
+                    dots, sqs, sqg = round_stats_mod.round_stats_q(
+                        wire_x, wire_s, g_flat, maskv)
+                g_avg = unravel(g_flat, jnp.float32)
+                theta = weighting.instantaneous_angle(dots, sqs, sqg)
         else:
-            angle_mask = (build_angle_mask(params, angle_pred)
-                          if angle_pred else None)
-            # f32: rounding g to the (possibly bf16) leaf dtype before
-            # the stats would lose the angle signal and diverge from the
-            # flat engine; also matches init_prev_delta's f32 threading.
-            g_avg = treemath.tree_weighted_sum(deltas, psi_avg,
-                                               jnp.float32)
-            d_view = angle_mask(deltas) if angle_mask else deltas
-            g_view = angle_mask(g_avg) if angle_mask else g_avg
-            dots = treemath.tree_vdot_batched(d_view, g_view)
-            sqs = treemath.tree_sqnorm_batched(d_view)
-            sqg = treemath.tree_sqnorm(g_view)
-            theta = weighting.instantaneous_angle(dots, sqs, sqg)
+            with jax.named_scope("fl.stats"):
+                angle_mask = (build_angle_mask(params, angle_pred)
+                              if angle_pred else None)
+                # f32: rounding g to the (possibly bf16) leaf dtype before
+                # the stats would lose the angle signal and diverge from
+                # the flat engine; also matches init_prev_delta's f32
+                # threading.
+                g_avg = treemath.tree_weighted_sum(deltas, psi_avg,
+                                                   jnp.float32)
+                d_view = angle_mask(deltas) if angle_mask else deltas
+                g_view = angle_mask(g_avg) if angle_mask else g_avg
+                dots = treemath.tree_vdot_batched(d_view, g_view)
+                sqs = treemath.tree_sqnorm_batched(d_view)
+                sqg = treemath.tree_sqnorm(g_view)
+                theta = weighting.instantaneous_angle(dots, sqs, sqg)
 
         # Eq. 9 scatter — ONE copy for all engines (flat_sharded computed
         # the same float ops in-region for its weighting; this scatter is
         # its state bookkeeping and must stay op-identical).
-        new_state = _scatter_angles(angle_state, sel_idx, theta)
-        theta_sm = new_state.smoothed[sel_idx]
+        with jax.named_scope("fl.stats"):
+            new_state = _scatter_angles(angle_state, sel_idx, theta)
+            theta_sm = new_state.smoothed[sel_idx]
         if fl.engine != "flat_sharded":
-            if fl.method == "fedadp":
-                w = weighting.fedadp_weights(theta_sm, data_sizes, fl.alpha)
-            else:  # fedavg / fedprox aggregate by data size
-                w = psi_avg
-            if fl.engine == "flat":
-                # fedavg/fedprox aggregate with w == psi_avg: reuse g_flat
-                # rather than re-streaming the buffer (no Pallas CSE)
-                delta_flat = g_flat if fl.method != "fedadp" else agg_wire(w)
-                delta = unravel(delta_flat)
-            else:
-                # f32 accumulate, ONE cast to the param leaf dtype — same
-                # rounding schedule as the flat engines' unravel, and it
-                # keeps params at their dtype when the transport path
-                # reconstructed the deltas as f32 leaves.
-                delta = jax.tree.map(
-                    lambda d, p: d.astype(p.dtype),
-                    treemath.tree_weighted_sum(deltas, w, jnp.float32),
-                    params)
+            with jax.named_scope("fl.weights"):
+                if fl.method == "fedadp":
+                    w = weighting.fedadp_weights(theta_sm, data_sizes,
+                                                 fl.alpha)
+                else:  # fedavg / fedprox aggregate by data size
+                    w = psi_avg
+            with jax.named_scope("fl.aggregate"):
+                if fl.engine == "flat":
+                    # fedavg/fedprox aggregate with w == psi_avg: reuse
+                    # g_flat rather than re-streaming the buffer (no
+                    # Pallas CSE)
+                    delta_flat = (g_flat if fl.method != "fedadp"
+                                  else agg_wire(w))
+                    delta = unravel(delta_flat)
+                else:
+                    # f32 accumulate, ONE cast to the param leaf dtype —
+                    # same rounding schedule as the flat engines'
+                    # unravel, and it keeps params at their dtype when the
+                    # transport path reconstructed the deltas as f32
+                    # leaves.
+                    delta = jax.tree.map(
+                        lambda d, p: d.astype(p.dtype),
+                        treemath.tree_weighted_sum(deltas, w, jnp.float32),
+                        params)
         # the delta lands on the server's uncompressed master params — the
         # downlink reconstruction is what the CLIENTS trained from.
-        new_params = treemath.tree_add(params_srv, delta)
+        with jax.named_scope("fl.aggregate"):
+            new_params = treemath.tree_add(params_srv, delta)
 
         # Fig.7 divergence: (1/K) sum_i ||dF - dF_i|| with dF ~ -delta/lr
         div = jnp.mean(jnp.sqrt(jnp.maximum(sqs - 2 * dots + sqg, 0.0))) / lr
@@ -1241,19 +1275,20 @@ def _make_buffered_round(loss_fn, fl: FLConfig, delta_constraint,
         # actually stochastic, so the deterministic case threads
         # state.rng untouched exactly like the sync round (this is part
         # of the bit-exact sync-equivalence contract).
-        new_rng = state.rng
-        if arrival_fn is not None:
-            delay, drop = arrival_fn(state.round)
-            delay = jnp.asarray(delay, jnp.int32)
-            drop = jnp.asarray(drop, bool)
-        elif stochastic:
-            new_rng, k_arr = jax.random.split(state.rng)
-            delay, drop = buffer_mod.draw_arrivals(
-                k_arr, k, fl.straggle_prob, fl.straggle_max,
-                fl.dropout_prob)
-        else:
-            delay = jnp.zeros((k,), jnp.int32)
-            drop = jnp.zeros((k,), bool)
+        with jax.named_scope("fl.uplink"):
+            new_rng = state.rng
+            if arrival_fn is not None:
+                delay, drop = arrival_fn(state.round)
+                delay = jnp.asarray(delay, jnp.int32)
+                drop = jnp.asarray(drop, bool)
+            elif stochastic:
+                new_rng, k_arr = jax.random.split(state.rng)
+                delay, drop = buffer_mod.draw_arrivals(
+                    k_arr, k, fl.straggle_prob, fl.straggle_max,
+                    fl.dropout_prob)
+            else:
+                delay = jnp.zeros((k,), jnp.int32)
+                drop = jnp.zeros((k,), bool)
 
         # ---- server -> client downlink (identical to the sync round:
         # candidates pull the CURRENT broadcast every tick, so the
@@ -1265,90 +1300,97 @@ def _make_buffered_round(loss_fn, fl: FLConfig, delta_constraint,
         params_srv = params
         new_dl, new_bcast = dl_state, state.bcast
         bcast_v, n_ravel = None, 0
-        if fl.downlink != "f32":
-            pvec, punravel = treemath.tree_ravel(params)
-            n_ravel = pvec.shape[0]
-            if fl.downlink_delta:
-                pvec = pvec - state.bcast.head
-            if fl.downlink_error_feedback:
-                pvec = pvec + dl_state
-            qd = transport_mod.downlink.compress(pvec, fl.downlink)
-            recon = transport_mod.downlink.decompress(qd)
-            if fl.downlink_error_feedback:
-                new_dl = pvec - recon
-            if fl.downlink_delta:
-                new_bcast = transport_mod.downlink.advance_broadcast(
-                    state.bcast, recon)
-                recon = new_bcast.head
-                bcast_v = new_bcast.head_ver
-            params = punravel(recon)
+        with jax.named_scope("fl.downlink"):
+            if fl.downlink != "f32":
+                pvec, punravel = treemath.tree_ravel(params)
+                n_ravel = pvec.shape[0]
+                if fl.downlink_delta:
+                    pvec = pvec - state.bcast.head
+                if fl.downlink_error_feedback:
+                    pvec = pvec + dl_state
+                qd = transport_mod.downlink.compress(pvec, fl.downlink)
+                recon = transport_mod.downlink.decompress(qd)
+                if fl.downlink_error_feedback:
+                    new_dl = pvec - recon
+                if fl.downlink_delta:
+                    new_bcast = transport_mod.downlink.advance_broadcast(
+                        state.bcast, recon)
+                    recon = new_bcast.head
+                    bcast_v = new_bcast.head_ver
+                params = punravel(recon)
 
         # ---- candidate local updates (all K slots compute; admission
         # masks decide whose report actually enters the buffer) ----
-        deltas, losses = jax.vmap(
-            lambda b: local_update(loss_fn, params, b, lr, fl.prox_mu,
-                                   grad_constraint)
-        )(batches)
-        if delta_constraint is not None:
-            deltas = delta_constraint(deltas)
+        with jax.named_scope("fl.local_train"):
+            deltas, losses = jax.vmap(
+                lambda b: local_update(loss_fn, params, b, lr, fl.prox_mu,
+                                       grad_constraint)
+            )(batches)
+            if delta_constraint is not None:
+                deltas = delta_constraint(deltas)
 
         # a free slot admits its candidate unless the client already has
         # a report in flight (full participation re-offers everyone) or
         # the report drops in transit (the slot stays free — liveness
         # never waits on a timeout).
-        busy = buffer_mod.population_busy(state.buf, fl.num_clients)
-        admit = state.buf.free & ~busy[sel_idx] & ~drop
+        with jax.named_scope("fl.uplink"):
+            busy = buffer_mod.population_busy(state.buf, fl.num_clients)
+            admit = state.buf.free & ~busy[sel_idx] & ~drop
 
         # admitted candidates actually pulled this tick's broadcast:
         # their decode base — and so their last-pulled version — is
         # fixed at admission time; busy/dropped candidates never pulled
         # and are neither version-advanced nor charged downlink bytes.
         down_split = None
-        if fl.downlink_delta:
-            if fl.telemetry:
-                down_split = _down_byte_split(
-                    fl, n_ravel, state.bcast.ver[sel_idx], bcast_v,
-                    pulled=admit)
-            new_bcast = new_bcast._replace(
-                ver=new_bcast.ver.at[sel_idx].set(
-                    jnp.where(admit, bcast_v, new_bcast.ver[sel_idx])))
+        with jax.named_scope("fl.downlink"):
+            if fl.downlink_delta:
+                if fl.telemetry:
+                    down_split = _down_byte_split(
+                        fl, n_ravel, state.bcast.ver[sel_idx], bcast_v,
+                        pulled=admit)
+                new_bcast = new_bcast._replace(
+                    ver=new_bcast.ver.at[sel_idx].set(
+                        jnp.where(admit, bcast_v, new_bcast.ver[sel_idx])))
 
         # ---- client uplink: compress to the wire, buffer the f32
         # reconstruction (the tree engine never reads the wire, and rows
         # must survive across ticks independent of the transport) ----
-        flat0, unravel0 = treemath.tree_ravel_stacked(deltas)
-        new_ef = ef_state
-        if fl.transport == "f32":
-            rows = flat0
-        else:
-            if fl.error_feedback:
-                flat0 = flat0 + ef_state[sel_idx]
-            q = transport_mod.quantize(flat0, fl.transport,
-                                       group_size=fl.group_size)
-            rows = transport_mod.dequantize(q)
-            if fl.error_feedback:
-                # the residual of a non-admitted report stays carried —
-                # that report never shipped, so nothing was dropped yet.
-                new_ef = ef_state.at[sel_idx].set(
-                    jnp.where(admit[:, None], flat0 - rows,
-                              ef_state[sel_idx]))
-        buf = buffer_mod.admit(state.buf, admit, rows, sel_idx,
-                               data_sizes, delay)
+        with jax.named_scope("fl.uplink"):
+            flat0, unravel0 = treemath.tree_ravel_stacked(deltas)
+            new_ef = ef_state
+            if fl.transport == "f32":
+                rows = flat0
+            else:
+                if fl.error_feedback:
+                    flat0 = flat0 + ef_state[sel_idx]
+                q = transport_mod.quantize(flat0, fl.transport,
+                                           group_size=fl.group_size)
+                rows = transport_mod.dequantize(q)
+                if fl.error_feedback:
+                    # the residual of a non-admitted report stays carried —
+                    # that report never shipped, so nothing was dropped yet.
+                    new_ef = ef_state.at[sel_idx].set(
+                        jnp.where(admit[:, None], flat0 - rows,
+                                  ef_state[sel_idx]))
+            buf = buffer_mod.admit(state.buf, admit, rows, sel_idx,
+                                   data_sizes, delay)
 
-        landed = buffer_mod.landed_mask(buf)
-        num_landed = jnp.sum(landed.astype(jnp.int32))
-        do_flush = num_landed >= m_flush
+        with jax.named_scope("fl.weights"):
+            landed = buffer_mod.landed_mask(buf)
+            num_landed = jnp.sum(landed.astype(jnp.int32))
+            do_flush = num_landed >= m_flush
 
-        # staleness-discounted FedAvg weights over the landed rows — the
-        # angle-reference global delta g, exactly psi_avg when every row
-        # landed at age 0.
-        psi_b = weighting.buffered_fedavg_weights(
-            buf.sizes, buf.age, landed, fl.staleness_beta)
+            # staleness-discounted FedAvg weights over the landed rows — the
+            # angle-reference global delta g, exactly psi_avg when every row
+            # landed at age 0.
+            psi_b = weighting.buffered_fedavg_weights(
+                buf.sizes, buf.age, landed, fl.staleness_beta)
 
-        maskv = None
-        if fl.engine != "tree" and angle_pred:
-            maskv = treemath.segment_mask(params,
-                                          angle_keep_list(params, angle_pred))
+        with jax.named_scope("fl.stats"):
+            maskv = None
+            if fl.engine != "tree" and angle_pred:
+                maskv = treemath.segment_mask(params,
+                                              angle_keep_list(params, angle_pred))
 
         if fl.engine == "flat_sharded":
             # same single-region schedule as the sync round, over the f32
@@ -1356,15 +1398,18 @@ def _make_buffered_round(loss_fn, fl: FLConfig, delta_constraint,
             kp = -(-k // csize) * csize
             n = buf.data.shape[1]
             npad = -(-n // msize) * msize
-            values = _pad_rows(buf.data, kp)
             mvec = (maskv if maskv is not None
                     else jnp.ones((n,), jnp.float32))
-            if npad != n:
-                # zero columns: zero in both rows and aggregate, so every
-                # stat contribution is exactly zero; sliced off below.
-                values = jnp.pad(values, ((0, 0), (0, npad - n)))
-                mvec = jnp.pad(mvec, (0, npad - n))
-            values = jax.lax.with_sharding_constraint(values, row_sharding)
+            with jax.named_scope("fl.uplink"):
+                values = _pad_rows(buf.data, kp)
+                if npad != n:
+                    # zero columns: zero in both rows and aggregate, so
+                    # every stat contribution is exactly zero; sliced off
+                    # below.
+                    values = jnp.pad(values, ((0, 0), (0, npad - n)))
+                    mvec = jnp.pad(mvec, (0, npad - n))
+                values = jax.lax.with_sharding_constraint(values,
+                                                          row_sharding)
             g_flat, dots, sqs, sqg, delta_flat, theta, _, w = flush_ops(
                 values, _pad_rows(psi_b, kp), mvec,
                 _pad_rows(angle_state.smoothed[buf.slot], kp),
@@ -1376,61 +1421,70 @@ def _make_buffered_round(loss_fn, fl: FLConfig, delta_constraint,
             if npad != n:
                 g_flat = g_flat[:n]
                 delta_flat = delta_flat[:n]
-            g_avg = unravel0(g_flat, jnp.float32)
-            delta = unravel0(delta_flat)
+            with jax.named_scope("fl.stats"):
+                g_avg = unravel0(g_flat, jnp.float32)
+            with jax.named_scope("fl.aggregate"):
+                delta = unravel0(delta_flat)
         elif fl.engine == "flat":
-            g_flat = weighted_agg_mod.weighted_agg(
-                psi_b, buf.data, out_dtype=jnp.float32)
-            dots, sqs, sqg = round_stats_mod.round_stats(
-                buf.data, g_flat, maskv)
-            g_avg = unravel0(g_flat, jnp.float32)
-            theta = weighting.instantaneous_angle(dots, sqs, sqg)
+            with jax.named_scope("fl.stats"):
+                g_flat = weighted_agg_mod.weighted_agg(
+                    psi_b, buf.data, out_dtype=jnp.float32)
+                dots, sqs, sqg = round_stats_mod.round_stats(
+                    buf.data, g_flat, maskv)
+                g_avg = unravel0(g_flat, jnp.float32)
+                theta = weighting.instantaneous_angle(dots, sqs, sqg)
         else:
-            deltas_b = treemath.tree_unravel_stacked(deltas, buf.data,
-                                                     jnp.float32)
-            angle_mask = (build_angle_mask(params, angle_pred)
-                          if angle_pred else None)
-            g_avg = treemath.tree_weighted_sum(deltas_b, psi_b, jnp.float32)
-            d_view = angle_mask(deltas_b) if angle_mask else deltas_b
-            g_view = angle_mask(g_avg) if angle_mask else g_avg
-            dots = treemath.tree_vdot_batched(d_view, g_view)
-            sqs = treemath.tree_sqnorm_batched(d_view)
-            sqg = treemath.tree_sqnorm(g_view)
-            theta = weighting.instantaneous_angle(dots, sqs, sqg)
+            with jax.named_scope("fl.stats"):
+                deltas_b = treemath.tree_unravel_stacked(deltas, buf.data,
+                                                         jnp.float32)
+                angle_mask = (build_angle_mask(params, angle_pred)
+                              if angle_pred else None)
+                g_avg = treemath.tree_weighted_sum(deltas_b, psi_b,
+                                                   jnp.float32)
+                d_view = angle_mask(deltas_b) if angle_mask else deltas_b
+                g_view = angle_mask(g_avg) if angle_mask else g_avg
+                dots = treemath.tree_vdot_batched(d_view, g_view)
+                sqs = treemath.tree_sqnorm_batched(d_view)
+                sqg = treemath.tree_sqnorm(g_view)
+                theta = weighting.instantaneous_angle(dots, sqs, sqg)
 
         # Eq. 9 over the LANDED reports only, applied only on flush ticks
         # (both masks reduce to the sync scatter when everything landed).
-        ang_flushed = _scatter_angles_masked(angle_state, buf.slot, theta,
-                                             landed)
-        new_angle = jax.tree.map(lambda a, b: jnp.where(do_flush, a, b),
-                                 ang_flushed, angle_state)
-        theta_sm = new_angle.smoothed[buf.slot]
+        with jax.named_scope("fl.stats"):
+            ang_flushed = _scatter_angles_masked(angle_state, buf.slot,
+                                                 theta, landed)
+            new_angle = jax.tree.map(lambda a, b: jnp.where(do_flush, a, b),
+                                     ang_flushed, angle_state)
+            theta_sm = new_angle.smoothed[buf.slot]
         if fl.engine != "flat_sharded":
-            if fl.method == "fedadp":
-                w = weighting.buffered_fedadp_weights(
-                    theta_sm, buf.sizes, buf.age, landed, fl.alpha,
-                    fl.staleness_beta)
-            else:
-                w = psi_b
-            if fl.engine == "flat":
-                delta_flat = (g_flat if fl.method != "fedadp" else
-                              weighted_agg_mod.weighted_agg(
-                                  w, buf.data, out_dtype=jnp.float32))
-                delta = unravel0(delta_flat)
-            else:
-                delta = jax.tree.map(
-                    lambda d, p: d.astype(p.dtype),
-                    treemath.tree_weighted_sum(deltas_b, w, jnp.float32),
-                    params)
+            with jax.named_scope("fl.weights"):
+                if fl.method == "fedadp":
+                    w = weighting.buffered_fedadp_weights(
+                        theta_sm, buf.sizes, buf.age, landed, fl.alpha,
+                        fl.staleness_beta)
+                else:
+                    w = psi_b
+            with jax.named_scope("fl.aggregate"):
+                if fl.engine == "flat":
+                    delta_flat = (g_flat if fl.method != "fedadp" else
+                                  weighted_agg_mod.weighted_agg(
+                                      w, buf.data, out_dtype=jnp.float32))
+                    delta = unravel0(delta_flat)
+                else:
+                    delta = jax.tree.map(
+                        lambda d, p: d.astype(p.dtype),
+                        treemath.tree_weighted_sum(deltas_b, w, jnp.float32),
+                        params)
 
         # flush: apply the aggregated delta to the master params — or, on
         # a non-flush tick, carry everything unchanged (where no-ops).
-        new_params = jax.tree.map(
-            lambda a, b: jnp.where(do_flush, a, b),
-            treemath.tree_add(params_srv, delta), params_srv)
-        new_prev = jax.tree.map(lambda a, b: jnp.where(do_flush, a, b),
-                                g_avg, state.prev_delta)
-        final_buf = buffer_mod.advance(buf, landed, do_flush)
+        with jax.named_scope("fl.aggregate"):
+            new_params = jax.tree.map(
+                lambda a, b: jnp.where(do_flush, a, b),
+                treemath.tree_add(params_srv, delta), params_srv)
+            new_prev = jax.tree.map(lambda a, b: jnp.where(do_flush, a, b),
+                                    g_avg, state.prev_delta)
+            final_buf = buffer_mod.advance(buf, landed, do_flush)
 
         nl_f = jnp.maximum(num_landed.astype(jnp.float32), 1.0)
         div = jnp.sum(jnp.where(
@@ -1476,11 +1530,14 @@ def _make_sequential_round(loss_fn, fl: FLConfig, angle_pred=None,
         # one stats implementation across modes: pass-2 statistics stream
         # through the round_stats kernel as a single-row (1, N) buffer per
         # scan step, with the MoE angle filter as a flat segment mask.
-        maskv = (
-            treemath.segment_mask(params, angle_keep_list(params, angle_pred))
-            if angle_pred else None
-        )
-        psi_avg = data_sizes / jnp.sum(data_sizes)
+        with jax.named_scope("fl.stats"):
+            maskv = (
+                treemath.segment_mask(params,
+                                      angle_keep_list(params, angle_pred))
+                if angle_pred else None
+            )
+        with jax.named_scope("fl.weights"):
+            psi_avg = data_sizes / jnp.sum(data_sizes)
         zeros32 = jax.tree.map(
             lambda p: jnp.zeros(p.shape, jnp.float32), params
         )
@@ -1489,9 +1546,11 @@ def _make_sequential_round(loss_fn, fl: FLConfig, angle_pred=None,
             # ---- pass 1: global (FedAvg-weighted) delta ----
             def p1(acc, xs):
                 b_i, psi_i = xs
-                d_i, loss = local_update(loss_fn, params, b_i, lr, fl.prox_mu,
-                                         grad_constraint)
-                return treemath.tree_axpy(psi_i, d_i, acc), loss
+                with jax.named_scope("fl.local_train"):
+                    d_i, loss = local_update(loss_fn, params, b_i, lr,
+                                             fl.prox_mu, grad_constraint)
+                with jax.named_scope("fl.stats"):
+                    return treemath.tree_axpy(psi_i, d_i, acc), loss
 
             g_avg, losses = jax.lax.scan(p1, zeros32, (batches, psi_avg))
             g_ref = g_avg
@@ -1499,27 +1558,34 @@ def _make_sequential_round(loss_fn, fl: FLConfig, angle_pred=None,
             g_ref = prev_delta
             losses = None
 
-        g_flat, _ = treemath.tree_ravel(g_ref)
+        with jax.named_scope("fl.stats"):
+            g_flat, _ = treemath.tree_ravel(g_ref)
 
         # ---- pass 2 (or single stale pass): stats + online weighted sum ----
         def p2(carry, xs):
             num, den, g_acc = carry
             b_i, psi_i, D_i, idx_i = xs
-            d_i, loss = local_update(loss_fn, params, b_i, lr, fl.prox_mu,
-                                     grad_constraint)
-            d_flat, _ = treemath.tree_ravel(d_i)
-            dots_i, sqs_i, sqg_i = round_stats_mod.round_stats(
-                d_flat[None], g_flat, maskv)
-            dot, sq = dots_i[0], sqs_i[0]
-            theta_i = weighting.instantaneous_angle(dot, sq, sqg_i)
-            cnt = angle_state.count[idx_i].astype(jnp.float32) + 1.0
-            sm = ((cnt - 1.0) * angle_state.smoothed[idx_i] + theta_i) / cnt
-            if fl.method == "fedadp":
-                w_i = D_i * jnp.exp(weighting.gompertz(sm, fl.alpha))
-            else:
-                w_i = D_i
-            num = treemath.tree_axpy(w_i, d_i, num)
-            g_acc = treemath.tree_axpy(psi_i, d_i, g_acc)
+            with jax.named_scope("fl.local_train"):
+                d_i, loss = local_update(loss_fn, params, b_i, lr,
+                                         fl.prox_mu, grad_constraint)
+            with jax.named_scope("fl.uplink"):
+                d_flat, _ = treemath.tree_ravel(d_i)
+            with jax.named_scope("fl.stats"):
+                dots_i, sqs_i, sqg_i = round_stats_mod.round_stats(
+                    d_flat[None], g_flat, maskv)
+                dot, sq = dots_i[0], sqs_i[0]
+                theta_i = weighting.instantaneous_angle(dot, sq, sqg_i)
+                cnt = angle_state.count[idx_i].astype(jnp.float32) + 1.0
+                sm = ((cnt - 1.0) * angle_state.smoothed[idx_i]
+                      + theta_i) / cnt
+            with jax.named_scope("fl.weights"):
+                if fl.method == "fedadp":
+                    w_i = D_i * jnp.exp(weighting.gompertz(sm, fl.alpha))
+                else:
+                    w_i = D_i
+            with jax.named_scope("fl.aggregate"):
+                num = treemath.tree_axpy(w_i, d_i, num)
+                g_acc = treemath.tree_axpy(psi_i, d_i, g_acc)
             return (num, den + w_i, g_acc), (theta_i, sm, dot, sq, sqg_i, loss)
 
         (num, den, g_acc), ys = jax.lax.scan(
@@ -1527,16 +1593,20 @@ def _make_sequential_round(loss_fn, fl: FLConfig, angle_pred=None,
             (batches, psi_avg, data_sizes.astype(jnp.float32), sel_idx),
         )
         theta, theta_sm, dots, sqs, sqgs, losses2 = ys
-        delta = treemath.tree_scale(num, 1.0 / jnp.maximum(den, 1e-12))
-        new_params = jax.tree.map(
-            lambda p, d: (p.astype(jnp.float32) + d).astype(p.dtype), params, delta
-        )
-        new_state = _scatter_angles(angle_state, sel_idx, theta)
-        w = (
-            weighting.fedadp_weights(theta_sm, data_sizes, fl.alpha)
-            if fl.method == "fedadp"
-            else psi_avg
-        )
+        with jax.named_scope("fl.aggregate"):
+            delta = treemath.tree_scale(num, 1.0 / jnp.maximum(den, 1e-12))
+            new_params = jax.tree.map(
+                lambda p, d: (p.astype(jnp.float32) + d).astype(p.dtype),
+                params, delta
+            )
+        with jax.named_scope("fl.stats"):
+            new_state = _scatter_angles(angle_state, sel_idx, theta)
+        with jax.named_scope("fl.weights"):
+            w = (
+                weighting.fedadp_weights(theta_sm, data_sizes, fl.alpha)
+                if fl.method == "fedadp"
+                else psi_avg
+            )
         div = jnp.mean(jnp.sqrt(jnp.maximum(sqs - 2 * dots + sqgs, 0.0))) / lr
         metrics = {
             "loss": jnp.mean(losses if losses is not None else losses2),

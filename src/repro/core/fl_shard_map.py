@@ -195,24 +195,27 @@ def make_round_ops(mesh: Mesh, *, alpha: float, method: str = "fedadp",
         my = _shard_slots(values, caxis)
         n = mask.shape[0]  # logical width (!= packed width for int4)
         kw = dict(transport=transport, group_size=group_size)
-        g_flat = jax.lax.psum(
-            _shard_agg(psi[my], values, scales, n=n, **kw),
-            caxis)
-        d_loc, s_loc, sqg = _shard_stats(values, scales, g_flat, mask,
-                                         **kw)
-        k = psi.shape[0]
-        dots = jax.lax.psum(
-            jnp.zeros((k,), jnp.float32).at[my].set(d_loc), caxis)
-        sqs = jax.lax.psum(
-            jnp.zeros((k,), jnp.float32).at[my].set(s_loc), caxis)
-        theta = weighting.instantaneous_angle(dots, sqs, sqg)
-        cnt = count_sel.astype(jnp.float32) + 1.0
-        theta_sm = ((cnt - 1.0) * smoothed_sel + theta) / cnt  # Eq. 9
-        if method == "fedadp":
-            w = weighting.fedadp_weights(theta_sm, data_sizes, alpha)
-            delta_flat = jax.lax.psum(
-                _shard_agg(w[my], values, scales, n=n, **kw),
+        with jax.named_scope("fl.stats"):
+            g_flat = jax.lax.psum(
+                _shard_agg(psi[my], values, scales, n=n, **kw),
                 caxis)
+            d_loc, s_loc, sqg = _shard_stats(values, scales, g_flat, mask,
+                                             **kw)
+            k = psi.shape[0]
+            dots = jax.lax.psum(
+                jnp.zeros((k,), jnp.float32).at[my].set(d_loc), caxis)
+            sqs = jax.lax.psum(
+                jnp.zeros((k,), jnp.float32).at[my].set(s_loc), caxis)
+            theta = weighting.instantaneous_angle(dots, sqs, sqg)
+            cnt = count_sel.astype(jnp.float32) + 1.0
+            theta_sm = ((cnt - 1.0) * smoothed_sel + theta) / cnt  # Eq. 9
+        if method == "fedadp":
+            with jax.named_scope("fl.weights"):
+                w = weighting.fedadp_weights(theta_sm, data_sizes, alpha)
+            with jax.named_scope("fl.aggregate"):
+                delta_flat = jax.lax.psum(
+                    _shard_agg(w[my], values, scales, n=n, **kw),
+                    caxis)
         else:  # w == psi: the stats' aggregate IS the round delta
             w = psi
             delta_flat = g_flat
@@ -327,46 +330,54 @@ def make_round_ops_2d(mesh: Mesh, template_stacked: PyTree, pspecs: PyTree,
 
     def _body(deltas, psi, smoothed_sel, count_sel, data_sizes):
         j = jax.lax.axis_index(MODEL_AXIS)
-        x = treemath.blocked_ravel_local(jax.tree.leaves(deltas), layout, j)
-        if transport == "f32":
-            values, scales = x, None
-        else:
-            from repro import transport as transport_mod
+        with jax.named_scope("fl.uplink"):
+            x = treemath.blocked_ravel_local(jax.tree.leaves(deltas),
+                                             layout, j)
+            if transport == "f32":
+                values, scales = x, None
+            else:
+                from repro import transport as transport_mod
 
-            q = transport_mod.quantize(
-                x, transport,
-                group_size=group_size or transport_mod.GROUP_SIZE)
-            values, scales = q.values, q.scales
+                q = transport_mod.quantize(
+                    x, transport,
+                    group_size=group_size or transport_mod.GROUP_SIZE)
+                values, scales = q.values, q.scales
         my = _shard_slots(x, caxis)
-        g_loc = jax.lax.psum(
-            _shard_agg(psi[my], values, scales, n=n_loc, **kw),
-            caxis)
-        d_loc, s_loc, sqg_loc = _shard_stats(values, scales, g_loc,
-                                             mask_const, **kw)
-        kp = psi.shape[0]
-        both = caxes + (MODEL_AXIS,)
-        dots = jax.lax.psum(
-            jnp.zeros((kp,), jnp.float32).at[my].set(d_loc), both)
-        sqs = jax.lax.psum(
-            jnp.zeros((kp,), jnp.float32).at[my].set(s_loc), both)
-        sqg = jax.lax.psum(sqg_loc, MODEL_AXIS)
-        theta = weighting.instantaneous_angle(dots, sqs, sqg)
-        cnt = count_sel.astype(jnp.float32) + 1.0
-        theta_sm = ((cnt - 1.0) * smoothed_sel + theta) / cnt  # Eq. 9
-        if method == "fedadp":
-            w = weighting.fedadp_weights(theta_sm, data_sizes, alpha)
-            delta_loc = jax.lax.psum(
-                _shard_agg(w[my], values, scales, n=n_loc, **kw),
+        with jax.named_scope("fl.stats"):
+            g_loc = jax.lax.psum(
+                _shard_agg(psi[my], values, scales, n=n_loc, **kw),
                 caxis)
+            d_loc, s_loc, sqg_loc = _shard_stats(values, scales, g_loc,
+                                                 mask_const, **kw)
+            kp = psi.shape[0]
+            both = caxes + (MODEL_AXIS,)
+            dots = jax.lax.psum(
+                jnp.zeros((kp,), jnp.float32).at[my].set(d_loc), both)
+            sqs = jax.lax.psum(
+                jnp.zeros((kp,), jnp.float32).at[my].set(s_loc), both)
+            sqg = jax.lax.psum(sqg_loc, MODEL_AXIS)
+            theta = weighting.instantaneous_angle(dots, sqs, sqg)
+            cnt = count_sel.astype(jnp.float32) + 1.0
+            theta_sm = ((cnt - 1.0) * smoothed_sel + theta) / cnt  # Eq. 9
+        if method == "fedadp":
+            with jax.named_scope("fl.weights"):
+                w = weighting.fedadp_weights(theta_sm, data_sizes, alpha)
+            with jax.named_scope("fl.aggregate"):
+                delta_loc = jax.lax.psum(
+                    _shard_agg(w[my], values, scales, n=n_loc, **kw),
+                    caxis)
         else:  # w == psi: the stats' aggregate IS the round delta
             w = psi
             delta_loc = g_loc
-        g_tree = jax.tree.unflatten(
-            jax.tree.structure(deltas),
-            _blocked_unstack_local(g_loc, layout, dtypes=jnp.float32))
-        delta_tree = jax.tree.unflatten(
-            jax.tree.structure(deltas),
-            _blocked_unstack_local(delta_loc, layout, dtypes=jnp.float32))
+        with jax.named_scope("fl.stats"):
+            g_tree = jax.tree.unflatten(
+                jax.tree.structure(deltas),
+                _blocked_unstack_local(g_loc, layout, dtypes=jnp.float32))
+        with jax.named_scope("fl.aggregate"):
+            delta_tree = jax.tree.unflatten(
+                jax.tree.structure(deltas),
+                _blocked_unstack_local(delta_loc, layout,
+                                       dtypes=jnp.float32))
         return g_tree, dots, sqs, sqg, delta_tree, theta, theta_sm, w
 
     spec_leaves, tree_of = _spec_tree(pspecs)
@@ -466,31 +477,35 @@ def make_buffered_flush_ops(mesh: Mesh, *, alpha: float,
     def _body(values, psi, mask, smoothed_sel, count_sel, sizes, age,
               landed):
         my = _shard_slots(values, caxis)
-        g_flat = jax.lax.psum(
-            weighted_agg_mod.weighted_agg(
-                psi[my], values, out_dtype=jnp.float32),
-            caxis)
-        d_loc, s_loc, sqg = round_stats_mod.round_stats(
-            values, g_flat, mask)
-        k = psi.shape[0]
-        dots = jax.lax.psum(
-            jnp.zeros((k,), jnp.float32).at[my].set(d_loc), stat_axes)
-        sqs = jax.lax.psum(
-            jnp.zeros((k,), jnp.float32).at[my].set(s_loc), stat_axes)
-        if msize > 1:
-            sqg = jax.lax.psum(sqg, MODEL_AXIS)
-        theta = weighting.instantaneous_angle(dots, sqs, sqg)
-        cnt = count_sel.astype(jnp.float32) + 1.0
-        theta_sm = ((cnt - 1.0) * smoothed_sel + theta) / cnt  # Eq. 9
-        if method == "fedadp":
-            w = weighting.buffered_fedadp_weights(
-                theta_sm, sizes, age, landed, alpha, beta)
-        else:
-            w = weighting.buffered_fedavg_weights(sizes, age, landed, beta)
-        delta_flat = jax.lax.psum(
-            weighted_agg_mod.weighted_agg(
-                w[my], values, out_dtype=jnp.float32),
-            caxis)
+        with jax.named_scope("fl.stats"):
+            g_flat = jax.lax.psum(
+                weighted_agg_mod.weighted_agg(
+                    psi[my], values, out_dtype=jnp.float32),
+                caxis)
+            d_loc, s_loc, sqg = round_stats_mod.round_stats(
+                values, g_flat, mask)
+            k = psi.shape[0]
+            dots = jax.lax.psum(
+                jnp.zeros((k,), jnp.float32).at[my].set(d_loc), stat_axes)
+            sqs = jax.lax.psum(
+                jnp.zeros((k,), jnp.float32).at[my].set(s_loc), stat_axes)
+            if msize > 1:
+                sqg = jax.lax.psum(sqg, MODEL_AXIS)
+            theta = weighting.instantaneous_angle(dots, sqs, sqg)
+            cnt = count_sel.astype(jnp.float32) + 1.0
+            theta_sm = ((cnt - 1.0) * smoothed_sel + theta) / cnt  # Eq. 9
+        with jax.named_scope("fl.weights"):
+            if method == "fedadp":
+                w = weighting.buffered_fedadp_weights(
+                    theta_sm, sizes, age, landed, alpha, beta)
+            else:
+                w = weighting.buffered_fedavg_weights(sizes, age, landed,
+                                                      beta)
+        with jax.named_scope("fl.aggregate"):
+            delta_flat = jax.lax.psum(
+                weighted_agg_mod.weighted_agg(
+                    w[my], values, out_dtype=jnp.float32),
+                caxis)
         return g_flat, dots, sqs, sqg, delta_flat, theta, theta_sm, w
 
     if msize > 1:

@@ -19,6 +19,7 @@ Two execution modes share that step bit-for-bit:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import warnings
@@ -35,6 +36,7 @@ from repro.data.synthetic import Dataset
 from repro.models import small
 from repro.telemetry import schema as tel_schema
 from repro.telemetry import sinks as tel_sinks
+from repro.telemetry import spans as tel_spans
 
 
 def fixed_arrival_schedule(delays, drops):
@@ -129,12 +131,17 @@ class FedServer:
         self._fresh_state = fresh_state
         self._seed = seed
         self.state = fresh_state(seed)
+        # the host spans of every reset, step and run of this server
+        # (`telemetry.spans`); `spans.counts` is the per-name call count
+        self.spans = tel_spans.SpanTimer()
 
     def reset(self, seed: Optional[int] = None) -> None:
         """Reinitialize the RoundState (fresh params, angles, RNG stream)
         WITHOUT re-jitting — e.g. warm the jit cache with a throwaway
         round, then reset before a timed or recorded run."""
-        self.state = self._fresh_state(self._seed if seed is None else seed)
+        with self.spans.span("reset"):
+            self.state = self._fresh_state(
+                self._seed if seed is None else seed)
 
     # RoundState is the single source of truth; these views keep the
     # pre-refactor attribute surface (checkpointing, tests, examples).
@@ -154,9 +161,11 @@ class FedServer:
         """One stepwise round; returns host metrics. eval_every > 0 adds
         metrics["accuracy"] after rounds where (r+1) % eval_every == 0
         (-1.0 on other rounds)."""
-        self.state, metrics = self._step_jit(self.state,
-                                             jnp.int32(eval_every))
-        return jax.device_get(metrics)
+        with self.spans.span("dispatch"):
+            self.state, metrics = self._step_jit(self.state,
+                                                 jnp.int32(eval_every))
+        with self.spans.span("fetch"):
+            return jax.device_get(metrics)
 
     def evaluate(self) -> float:
         """Host-side test accuracy of the current master params."""
@@ -191,53 +200,75 @@ class FedServer:
         modes feed the sink through the same adapter
         (`telemetry.sinks.emit_round_block`), so the streams are
         comparable to 1e-5 — a pinned test, not a hope.
+
+        The run is a ``run`` span of `self.spans` holding a
+        ``read_round`` span for each blocking read of the round counter,
+        ``dispatch`` and ``fetch`` for each step or block, and (scanned)
+        ``history`` for building the History; with a `sink` their
+        ``span`` events join the stream.
         """
         if mode not in ("stepwise", "scanned"):
             raise ValueError(
                 f"unknown mode {mode!r} (expected 'stepwise' or 'scanned')")
         if sink is not None:
             tel_sinks.emit_manifest(sink, self.fl)
-        start = int(self.state.round)
-        if mode == "stepwise":
-            hist = History([], [], [], None, 0.0, [], [])
-            for r in range(rounds):
-                m = self.step(eval_every=eval_every)
-                self._append(hist, m)
-                if sink is not None:
-                    tel_sinks.emit_round_block(sink, m, start + r,
-                                               every=telemetry_every)
-                acc = float(m["accuracy"])
-                if tel_schema.is_real_accuracy(acc):
-                    hist.accuracy.append(acc)
-                    if verbose:
-                        print(f"round {r+1:4d} loss {m['loss']:.4f} "
-                              f"acc {acc:.4f}")
-                    if (target_acc and acc >= target_acc
-                            and hist.rounds_to_target is None):
-                        hist.rounds_to_target = start + r + 1
-                        break
-            hist.final_accuracy = hist.accuracy[-1] if hist.accuracy else 0.0
-        else:
-            self.state, ms, rtt, ran = driver_mod.run_rounds(
-                self._run_block, self.state, rounds, eval_every=eval_every,
-                target_acc=target_acc, block=block, ckpt_dir=ckpt_dir,
-                ckpt_every_blocks=ckpt_every_blocks, ckpt_keep=ckpt_keep,
-                sink=sink, telemetry_every=telemetry_every)
-            hist = History([], [], [], rtt, 0.0, [], [])
-            stop = rtt - start if rtt is not None else ran
-            for r in range(stop):
-                self._append(hist, {k: v[r] for k, v in ms.items()})
-                acc = float(ms["accuracy"][r])
-                if tel_schema.is_real_accuracy(acc):
-                    hist.accuracy.append(acc)
-            hist.final_accuracy = hist.accuracy[-1] if hist.accuracy else 0.0
+        with self._run_span(sink):
+            with self.spans.span("read_round"):
+                start = int(self.state.round)
+            if mode == "stepwise":
+                hist = History([], [], [], None, 0.0, [], [])
+                ran = 0
+                for r in range(rounds):
+                    m = self.step(eval_every=eval_every)
+                    ran += 1
+                    self._append(hist, m)
+                    if sink is not None:
+                        tel_sinks.emit_round_block(sink, m, start + r,
+                                                   every=telemetry_every)
+                    acc = float(m["accuracy"])
+                    if tel_schema.is_real_accuracy(acc):
+                        hist.accuracy.append(acc)
+                        if verbose:
+                            print(f"round {r+1:4d} loss {m['loss']:.4f} "
+                                  f"acc {acc:.4f}")
+                        if (target_acc and acc >= target_acc
+                                and hist.rounds_to_target is None):
+                            hist.rounds_to_target = start + r + 1
+                            break
+            else:
+                self.state, ms, rtt, ran = driver_mod.run_rounds(
+                    self._run_block, self.state, rounds,
+                    eval_every=eval_every, target_acc=target_acc,
+                    block=block, ckpt_dir=ckpt_dir,
+                    ckpt_every_blocks=ckpt_every_blocks,
+                    ckpt_keep=ckpt_keep, sink=sink,
+                    telemetry_every=telemetry_every, spans=self.spans)
+                with self.spans.span("history"):
+                    hist = History([], [], [], rtt, 0.0, [], [])
+                    stop = rtt - start if rtt is not None else ran
+                    for r in range(stop):
+                        self._append(hist, {k: v[r] for k, v in ms.items()})
+                        acc = float(ms["accuracy"][r])
+                        if tel_schema.is_real_accuracy(acc):
+                            hist.accuracy.append(acc)
+        hist.final_accuracy = hist.accuracy[-1] if hist.accuracy else 0.0
         if sink is not None:
             tel_sinks.emit_summary(
-                sink, rounds=int(self.state.round) - start,
+                sink, rounds=ran,
                 final_accuracy=hist.final_accuracy or None,
                 rounds_to_target=hist.rounds_to_target,
                 target_acc=target_acc)
         return hist
+
+    @contextlib.contextmanager
+    def _run_span(self, sink):
+        """The ``run`` span, with `sink` receiving this run's span events."""
+        self.spans.sink = sink
+        try:
+            with self.spans.span("run"):
+                yield
+        finally:
+            self.spans.sink = None
 
     _warned_run_scanned = False
 

@@ -46,7 +46,12 @@ def use_compile_cache() -> str:
     Where `JAX_COMPILATION_CACHE_DIR` is set, JAX already reads it and
     this sets no other path. Otherwise the cache goes to the fixed
     `.jax_cache/` at the checkout root. Called by the entry points'
-    mains, never on import."""
+    mains, never on import.
+
+    The key holds the programs' metadata: the ``fl.*`` named scopes a
+    profiler trace reads live only there, so a program whose scopes moved
+    must not be served the executable of the old ones."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

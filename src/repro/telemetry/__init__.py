@@ -16,8 +16,9 @@ inspectable WITHOUT touching the compiled path when it is off:
   JSONL (manifest-headed, durable), CSV, and in-memory implementations;
   `emit_round_block` adapts stacked scan metrics to schema events at
   block boundaries.
-* **Spans** (`telemetry.spans`) — `SpanTimer`, block_until_ready-bounded
-  host phase timing with optional `jax.profiler` trace annotations.
+* **Spans** (`telemetry.spans`) — `SpanTimer`, host phase timing: each
+  span is a ``fl/<name>`` `jax.profiler` trace annotation, a call count
+  and, with a sink, a ``span`` event.
 * **Schema** (`telemetry.schema`) — the versioned JSONL event contract,
   including the in-scan eval sentinel `EVAL_SENTINEL`.
 * **Manifest** (`telemetry.manifest`) — run provenance (commit, jax

@@ -85,6 +85,20 @@ def test_use_compile_cache_defaults_to_checkout_dir(monkeypatch):
     assert path == str(checkout / ".jax_cache")
 
 
+def test_use_compile_cache_keys_on_scopes(monkeypatch, tmp_path):
+    """The `fl.*` scopes live in the programs' metadata, which the cache
+    key leaves out unless asked: a step whose scopes moved must compile
+    anew."""
+    before = jax.config.jax_compilation_cache_include_metadata_in_key
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    try:
+        repro.use_compile_cache()
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+    finally:
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          before)
+
+
 # ------------------------------------------------------ run entrypoint
 
 

@@ -11,6 +11,7 @@ run's rounds-to-target (the Table-I claim is auditable from telemetry);
 (4) the in-scan eval sentinel is a pinned constant masked by every
 reader.
 """
+import collections
 import os
 import subprocess
 import sys
@@ -327,3 +328,88 @@ def test_csv_sink_writes_per_node_rows(tmp_path):
     assert set(rows[0]) == set(sinks.CSVSink.COLUMNS)
     w = sum(float(r["weight"]) for r in rows if r["round"] == "1")
     assert abs(w - 1.0) < 1e-5
+
+
+# ------------------------------------- host spans and device scopes
+
+# every phase scope of the round step (fl.make_round_fn, driver.make_step_fn)
+SCOPES = {"fl.select", "fl.downlink", "fl.local_train", "fl.uplink",
+          "fl.stats", "fl.weights", "fl.aggregate", "fl.eval"}
+
+
+def test_span_timer_writes_fl_event_to_profiler_trace(tmp_path):
+    """A span is a "fl/<name>" annotation on the host plane of a profiler
+    trace, and one call in `counts`."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    spans = repro.SpanTimer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with spans.span("probe"):
+            jnp.ones(3).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = [e.name for p in ProfileData.from_file(path).planes
+             if p.name.startswith("/host:") for line in p.lines
+             for e in line.events]
+    assert "fl/probe" in names
+    assert spans.counts == {"probe": 1}
+
+
+@pytest.mark.parametrize("mode,rounds,block,want", [
+    ("scanned", 8, 4, {"run": 1, "read_round": 2, "scan_block": 2,
+                       "dispatch": 2, "fetch": 2, "history": 1}),
+    ("scanned", 6, 4, {"run": 1, "read_round": 2, "scan_block": 2,
+                       "dispatch": 2, "fetch": 2, "history": 1}),
+    ("scanned", 4, 8, {"run": 1, "read_round": 2, "scan_block": 1,
+                       "dispatch": 1, "fetch": 1, "history": 1}),
+    ("stepwise", 3, 8, {"run": 1, "read_round": 1, "dispatch": 3,
+                        "fetch": 3}),
+], ids=["scanned-2-blocks", "scanned-ragged", "scanned-1-block",
+        "stepwise"])
+def test_run_counts_its_host_spans(mode, rounds, block, want):
+    """`FedServer.spans.counts` is the program's host-sync counter: one
+    `read_round` per blocking read of the round counter and one `fetch`
+    per block (or stepwise round); a sink gets the same spans as events
+    (and one `sink_emit` per scanned block), after the manifest and
+    before the summary."""
+    s = _server(_cfg(telemetry=None))
+    s.reset(1)
+    assert s.spans.counts == {"reset": 1}
+    s.run(rounds, eval_every=2, mode=mode, block=block)
+    assert {k: v for k, v in s.spans.counts.items() if k != "reset"} == want
+    before = collections.Counter(s.spans.counts)
+    sink = sinks.MemorySink()
+    s.run(rounds, eval_every=2, mode=mode, block=block, sink=sink)
+    schema.validate_events(sink.events)
+    assert sink.events[-1]["event"] == "summary"
+    assert sink.events[-1]["rounds"] == rounds
+    streamed = collections.Counter(e["name"] for e in sink.of_type("span"))
+    assert streamed == collections.Counter(s.spans.counts) - before
+    assert streamed - collections.Counter(want) == collections.Counter(
+        {"sink_emit": want["fetch"]} if mode == "scanned" else {})
+
+
+@pytest.mark.parametrize("engine,extra,want", [
+    ("flat", {}, SCOPES),
+    ("tree", {}, SCOPES),
+    ("flat", {"aggregation": "buffered", "buffer_m": 2}, SCOPES),
+    ("tree", {"mode": "sequential", "transport": "f32", "downlink": "f32"},
+     SCOPES - {"fl.downlink"}),
+], ids=["flat", "tree", "buffered", "sequential"])
+def test_round_phases_are_named_scopes_in_the_hlo(engine, extra, want):
+    """Each phase scope reaches the `op_name` metadata of the lowered step
+    (what a profiler trace names each device op by), with int8 wires so
+    that downlink and uplink run ops."""
+    import re
+
+    cfg = _cfg(telemetry=None, engine=engine,
+               **dict({"transport": "int8", "downlink": "int8"}, **extra))
+    s = _server(cfg)
+    text = jax.jit(s._step_fn).lower(s.state, jnp.int32(1)).as_text(
+        dialect="hlo", debug_info=True)
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    assert {m for n in names for m in re.findall(r"fl\.[a-z_]+", n)} == want
