@@ -2,7 +2,9 @@
 
 Two execution modes (DESIGN.md §6):
 
-* ``parallel`` — the K participating clients are vmapped; on a mesh the
+* ``parallel`` — the K participating clients train at once, vmapped, or
+  through the model's client-batched loss where the loss carries one and
+  no mesh is given (`local_update_path`); on a mesh the
   client axis is sharded over ("pod", "data"). Per-client deltas are
   materialized stacked (K, ...), angles are batched reductions, and the
   weighted aggregation is one collective contraction over the client axis.
@@ -619,6 +621,72 @@ def local_update(loss_fn: Callable, params: PyTree, batches: PyTree, lr,
     return treemath.tree_sub(p_fin, params), jnp.mean(losses)
 
 
+def local_update_clients(clients_loss: Callable, params: PyTree,
+                         batches: PyTree, lr, prox_mu: float = 0.0,
+                         grad_constraint: Optional[Callable] = None):
+    """tau steps of SGD on K clients at once, one `lax.scan` over the
+    steps: `jax.vmap(local_update)` over the clients, computed through a
+    client-batched loss.
+
+    params: leaves (K, ...), each client's starting params; batches:
+    leaves (K, tau, B, ...). `clients_loss(params, batch)` takes the
+    stacked params and one step's batch with leaves (B, K, ...) and
+    returns the K clients' losses (K,). The step differentiates their
+    sum, whose gradient with respect to client k's params is client k's
+    own, so each client takes `local_update`'s step; `prox_mu` and
+    `grad_constraint` act per client as there. Returns (deltas (K, ...),
+    mean losses (K,))."""
+
+    def total(p, b):
+        losses = clients_loss(p, b)
+        if prox_mu > 0.0:
+            prox = treemath.tree_sqnorm_batched(treemath.tree_sub(p, params))
+            losses = losses + 0.5 * prox_mu * prox
+        return jnp.sum(losses), losses
+
+    def step(p, b):
+        (_, losses), g = jax.value_and_grad(total, has_aux=True)(p, b)
+        if grad_constraint is not None:
+            g = jax.vmap(grad_constraint)(g)
+        return treemath.tree_axpy(-lr, g, p), losses
+
+    steps = jax.tree.map(  # (K, tau, B, ...) -> (tau, B, K, ...)
+        lambda a: jnp.moveaxis(a, 0, 2), batches)
+    p_fin, losses = jax.lax.scan(step, params, steps)
+    return treemath.tree_sub(p_fin, params), jnp.mean(losses, axis=0)
+
+
+def local_update_path(loss_fn: Callable, fl: FLConfig, mesh=None) -> str:
+    """The local update a round built from these arguments runs:
+    "batched" (`local_update_clients`) where the loss carries a
+    client-batched form as `loss_fn.clients` and the round is a parallel
+    one built without a mesh (on a client-sharded mesh the batched form
+    would put the sharded client axis into the model's feature axes),
+    "sequential" for the sequential round, else "vmap"
+    (`jax.vmap(local_update)`)."""
+    if fl.mode != "parallel":
+        return "sequential"
+    if mesh is None and getattr(loss_fn, "clients", None) is not None:
+        return "batched"
+    return "vmap"
+
+
+def _local_train(loss_fn, path: str, params, batches, lr, fl: FLConfig,
+                 grad_constraint):
+    """The parallel round's local updates of the K clients, all from
+    `params`, by the path `local_update_path` chose."""
+    if path == "batched":
+        k = jax.tree.leaves(batches)[0].shape[0]
+        stacked = jax.tree.map(
+            lambda p: jnp.broadcast_to(p, (k, *p.shape)), params)
+        return local_update_clients(loss_fn.clients, stacked, batches, lr,
+                                    fl.prox_mu, grad_constraint)
+    return jax.vmap(
+        lambda b: local_update(loss_fn, params, b, lr, fl.prox_mu,
+                               grad_constraint)
+    )(batches)
+
+
 def angle_keep_list(params: PyTree, pred: Callable) -> list:
     """One bool per leaf (flatten order): does `pred(path_keys, leaf)` keep it?"""
     flat = jax.tree_util.tree_flatten_with_path(params)[0]
@@ -864,6 +932,7 @@ def _derive_param_pspecs(params, mesh):
 def _make_parallel_round(loss_fn, fl: FLConfig, delta_constraint, angle_pred=None,
                          grad_constraint=None, mesh=None):
     round_ops = None
+    path = local_update_path(loss_fn, fl, mesh)
     # A mesh with a "model" axis of size > 1 switches the wire to the 2D
     # (client x model) blocked layout: quantization chunks are SHARD-LOCAL
     # (never straddling a model-axis split), model-sharded leaves are
@@ -951,10 +1020,8 @@ def _make_parallel_round(loss_fn, fl: FLConfig, delta_constraint, angle_pred=Non
                 params = punravel(recon)
 
         with jax.named_scope("fl.local_train"):
-            deltas, losses = jax.vmap(
-                lambda b: local_update(loss_fn, params, b, lr, fl.prox_mu,
-                                       grad_constraint)
-            )(batches)
+            deltas, losses = _local_train(loss_fn, path, params, batches,
+                                          lr, fl, grad_constraint)
             if delta_constraint is not None:
                 deltas = delta_constraint(deltas)
 
@@ -1220,6 +1287,7 @@ def _make_buffered_round(loss_fn, fl: FLConfig, delta_constraint,
     """
     stochastic = (arrival_fn is None
                   and (fl.straggle_prob > 0 or fl.dropout_prob > 0))
+    path = local_update_path(loss_fn, fl, mesh)
     m_flush = fl.buffer_m if fl.buffer_m > 0 else fl.clients_per_round
     flush_ops = None
     if fl.engine == "flat_sharded":
@@ -1322,10 +1390,8 @@ def _make_buffered_round(loss_fn, fl: FLConfig, delta_constraint,
         # ---- candidate local updates (all K slots compute; admission
         # masks decide whose report actually enters the buffer) ----
         with jax.named_scope("fl.local_train"):
-            deltas, losses = jax.vmap(
-                lambda b: local_update(loss_fn, params, b, lr, fl.prox_mu,
-                                       grad_constraint)
-            )(batches)
+            deltas, losses = _local_train(loss_fn, path, params, batches,
+                                          lr, fl, grad_constraint)
             if delta_constraint is not None:
                 deltas = delta_constraint(deltas)
 

@@ -110,9 +110,19 @@ class FedServer:
         self.batch_size = batch_size
         init_fn, self.apply_fn = small.MODELS[model]
 
-        def loss_fn(params, batch):
-            x, y = batch
-            return small.classification_loss(self.apply_fn, params, x, y)
+        def loss_of(apply_fn):
+            def loss(params, batch):
+                x, y = batch
+                return small.classification_loss(apply_fn, params, x, y)
+
+            return loss
+
+        loss_fn = loss_of(self.apply_fn)
+        if model in small.CLIENTS_APPLY:  # batch leaves (B, K, ...)
+            loss_fn.clients = loss_of(small.CLIENTS_APPLY[model])
+        # "batched", "vmap" or "sequential": how the step's local updates
+        # run (`fl.local_update_path`); every run's manifest records it
+        self.local_update = fl_mod.local_update_path(loss_fn, fl, mesh)
 
         self.data = driver_mod.stack_nodes(nodes, batch_size)
         eval_fn = driver_mod.make_eval_fn(self.apply_fn, test.x, test.y)
@@ -194,7 +204,8 @@ class FedServer:
         a mid-run state).
 
         `sink` (a `repro.telemetry` TelemetrySink) streams the run as
-        schema events — manifest first, one ``round`` event per round
+        schema events — manifest first (its ``extra`` holds
+        `self.local_update`), one ``round`` event per round
         (subsampled by `telemetry_every`), per-node FedAdp rows when the
         config has `telemetry="node"`, and a ``summary`` last. Both
         modes feed the sink through the same adapter
@@ -211,7 +222,8 @@ class FedServer:
             raise ValueError(
                 f"unknown mode {mode!r} (expected 'stepwise' or 'scanned')")
         if sink is not None:
-            tel_sinks.emit_manifest(sink, self.fl)
+            tel_sinks.emit_manifest(
+                sink, self.fl, extra={"local_update": self.local_update})
         with self._run_span(sink):
             with self.spans.span("read_round"):
                 start = int(self.state.round)

@@ -413,3 +413,23 @@ def test_round_phases_are_named_scopes_in_the_hlo(engine, extra, want):
         dialect="hlo", debug_info=True)
     names = set(re.findall(r'op_name="([^"]*)"', text))
     assert {m for n in names for m in re.findall(r"fl\.[a-z_]+", n)} == want
+
+
+@pytest.mark.parametrize("model,mesh,want", [
+    ("cnn", False, "batched"), ("mlr", False, "vmap"), ("cnn", True, "vmap"),
+], ids=["cnn", "mlr", "cnn-mesh"])
+def test_manifest_names_the_local_update_path(model, mesh, want):
+    """`server.local_update` and the run manifest's `extra` say which
+    local update the step runs: the CNN's client-batched one, except on
+    a mesh, and the vmapped one for MLR."""
+    from repro.launch.mesh import make_mesh
+
+    nodes, test = _task()
+    s = repro.FedServer(model, _cfg(telemetry=None), nodes, test,
+                        batch_size=50,
+                        mesh=make_mesh((1,), ("data",)) if mesh else None)
+    assert s.local_update == want
+    sink = sinks.MemorySink()
+    s.run(0, sink=sink)
+    (manifest,) = sink.of_type("manifest")
+    assert manifest["extra"] == {"local_update": want}

@@ -1,13 +1,17 @@
-"""The main-path Pallas kernels compile with Mosaic for a v5e chip.
+"""The main-path Pallas kernels compile with Mosaic for a v5e chip, and
+the CNN's client-batched local update keeps the clients on the lanes.
 
 Interpret mode accepts block shapes, scalar stores and reshapes that the
 TPU compiler refuses, so the CPU parity tests cannot see them. These
 tests lower each kernel of the flat engine for a described (not
 attached) v5e chip at the paper CNN's width, N = 1,663,370, for one
 client chunk (K=10) and a ragged second chunk (K=33), and check that the
-compiled program holds the Mosaic kernel (`tpu_custom_call`).
+compiled program holds the Mosaic kernel (`tpu_custom_call`). They also
+compile the paper cell's local update (K=10 CNN clients, B=32, 18 steps)
+and read the layouts XLA chose for its 28x28 stage.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -15,7 +19,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import transport
+from repro.core import fl
 from repro.kernels import round_stats, weighted_agg
+from repro.models import small
 
 N = 1_663_370  # models/small.py CNN parameter count
 
@@ -91,3 +97,41 @@ def test_kernel_compiles_for_v5e(one_chip, name, k, group_size):
             for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _f32_layouts(text):
+    """name -> (dims, minor dimension) of every f32 array instruction in a
+    compiled module's text, from its layout's minor-to-major list."""
+    found = {}
+    for name, dims, layout in re.findall(
+            r"%([\w.-]+) = f32\[([\d,]+)\]\{([\d,]+)[:}]", text):
+        dims = [int(d) for d in dims.split(",")]
+        found[name] = (dims, dims[int(layout.split(",")[0])])
+    return found
+
+
+def test_cnn_clients_local_update_keeps_clients_on_lanes(one_chip):
+    """The 28x28 stage keeps the K clients' 32 channels merged on the
+    minor axis: no f32 activation [.., 28, 28, K, 32] or [K, .., 28, 28,
+    32] is left, and the first pool's `reduce` reads its operand with
+    K*32 minor."""
+    k, b, tau = 10, 32, 18
+
+    def shape(s, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: shape((k, *a.shape)),
+                          jax.eval_shape(small.cnn_init, jax.random.key(0)))
+    batches = (shape((k, tau, b, 28, 28, 1)), shape((k, tau, b), jnp.int32))
+
+    def loss(p, bt):
+        return small.classification_loss(small.cnn_apply_clients, p, *bt)
+
+    text = jax.jit(lambda p, bt: fl.local_update_clients(
+        loss, p, bt, 0.02)).lower(params, batches).compile().as_text()
+    assert not re.search(r"f32\[(\d+,)*28,28,10,32\]", text)
+    assert not re.search(r"f32\[10,(\d+,)*28,28,32\]", text)
+    layouts = _f32_layouts(text)
+    pools = [layouts[operand] for operand in re.findall(
+        rf"= f32\[{b},14,14,{k * 32}\]\S* reduce\(%([\w.-]+),", text)]
+    assert pools and all(m == k * 32 for _, m in pools), pools
