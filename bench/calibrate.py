@@ -7,10 +7,11 @@ On the chip, at the cell's own size, in one process:
 * the program: for each of `--seeds` seeds, the first block (or the first
   training to the target) through the window's own call, compared with
   the plain reference exactly as a run's check compares it;
-* the control: the reference in bfloat16 (`reference.CONTROL`) put in the
-  program's place, on `--faulty-seeds` seeds;
-* a planted fault: the reference with each local step's loss taken over
-  half of its minibatch, on the same seeds.
+* the variants of the cell's family (`reference_variants`; for the
+  image family the control, the reference in bfloat16 put in the
+  program's place, and a planted fault, the reference with each local
+  step's loss taken over half of its minibatch), each on `--faulty-seeds`
+  seeds.
 
 A state left unchanged reads 1 for `update` by that number's definition,
 so it needs no run. The readings go to standard output, one JSON line
@@ -20,6 +21,7 @@ smallest faulty one of each number last.
 from __future__ import annotations
 
 import argparse
+import collections
 import json
 import math
 import os
@@ -27,10 +29,10 @@ import sys
 import time
 
 
-def _side_run(reference, s, data, seed, rounds, every, target):
+def _side_run(family, s, data, seed, rounds, every, target):
     """A reference variant's readings shaped like the program's: its
     per-round lists stop at its own rounds-to-target, as a History does."""
-    out = reference.follow(s, data, seed, rounds, every)
+    out = family.follow(s, data, seed, rounds, every)
     rtt = None
     if target is not None:
         hit = [r for r, a in sorted(out["acc"].items()) if a >= target]
@@ -42,12 +44,12 @@ def _side_run(reference, s, data, seed, rounds, every, target):
     return out
 
 
-def _compare_all(args, tr, data, firsts, seeds, rows, reference, check):
+def _compare_all(args, tr, data, firsts, seeds, rows, family, check):
     """Readings of the program and of each variant against the reference."""
     loop = tr["loop"]
     every, target = loop["eval_every"], loop.get("target_acc")
     acc_limit = tr["check"]["limits"].get("acc", 0.0)
-    s_ref = reference.setup_from(tr)
+    s_ref = family.reference_setup(tr)
     refs = {}
 
     def compare(kind, prog, ref):
@@ -66,17 +68,15 @@ def _compare_all(args, tr, data, firsts, seeds, rows, reference, check):
 
     t = time.perf_counter()
     for first in firsts:
-        ref = reference.follow(s_ref, data, first["seed"], first["rounds"],
-                               every)
+        ref = family.follow(s_ref, data, first["seed"], first["rounds"],
+                            every)
         refs[first["seed"]] = (first["rounds"], ref)
         compare("program", first, ref)
     print(f"# program + reference: {time.perf_counter() - t:.1f} s", flush=True)
-    variants = {"control": reference.setup_from(tr, reference.CONTROL),
-                "half_batch": reference.setup_from(tr, half_batch=True)}
-    for kind, s in variants.items():
+    for kind, s in family.reference_variants(tr).items():
         for seed in seeds[: args.faulty_seeds]:
             rounds, ref = refs[seed]
-            out = _side_run(reference, s, data, seed, rounds, every, target)
+            out = _side_run(family, s, data, seed, rounds, every, target)
             out["seed"] = seed
             compare(kind, out, ref)
 
@@ -97,28 +97,27 @@ def main(argv=None) -> int:
 
     sys.path.insert(0, os.path.join(root, "src"))
     import repro
-    from bench import check, reference
-    from bench import data as bench_data
+    from bench import check
 
     cell = bench_run.load_cell(args.workload, root)
+    family = bench_run.load_family(cell.config["family"], root)
     tr = cell.traffic
     loop = tr["loop"]
-    nodes, test = bench_data.make_nodes(tr)
+    nodes, test = family.make_data(tr)
     fl = repro.FLConfig(**tr["fl"]).validate()
     seeds = [int(x) for x in np.random.default_rng(args.first_seed).integers(
         0, 2**31 - 1, size=args.seeds)]
-    server = repro.FedServer(tr["model"], fl, nodes, test,
-                             batch_size=tr["batch_size"], seed=seeds[0])
+    server = family.make_server(repro, fl, tr, nodes, test, seeds[0])
     if loop["kind"] == "to_target":
         server.run(loop["max_rounds"], target_acc=math.inf,
                    eval_every=loop["eval_every"], mode="scanned",
                    block=loop["block"])
     firsts = [bench_run.first_steps(jax, server, tr, s) for s in seeds]
     del server
-    rows = {"program": [], "control": [], "half_batch": []}
+    rows = collections.defaultdict(list)
     with bench_run.reference_device(jax, tr):
-        data = reference.device_data(nodes, test)
-        _compare_all(args, tr, data, firsts, seeds, rows, reference, check)
+        data = family.reference_data(nodes, test)
+        _compare_all(args, tr, data, firsts, seeds, rows, family, check)
     names = ["loss", "loss_first", "update", "update_median", "weights",
              "acc", "acc_first", "rtt"]
     summary = {}

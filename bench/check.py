@@ -13,7 +13,8 @@ compared there.
   worst leaf: | ||program change|| - ||reference change|| | over the
   larger of that leaf's reference norm and the median leaf's. A leaf
   whose reference change is under a thousandth of the median leaf's is
-  left out (it moves by round-off alone).
+  left out (it moves by round-off alone). Leaves of a nested tree are
+  matched by their key paths.
 * `update_median` — the same gap of the median leaf, steady where a
   quantized wire makes one small leaf's gap swing from seed to seed.
 * `loss_first` — the `loss` gap of the first round alone.
@@ -29,20 +30,30 @@ compared there.
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
 
 KEEP_SHARE = 1e-3
 
 
-def leaf_gaps(params0: dict, prog: dict, ref: dict) -> dict:
+def leaves_by_path(tree) -> dict:
+    """{"outer/inner": leaf} of a params tree, in the tree's own leaf
+    order (sorted keys for dicts); a flat dict keeps its keys as names."""
+    return {jax.tree_util.keystr(path, simple=True, separator="/"): leaf
+            for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+def leaf_gaps(params0, prog, ref) -> dict:
     """Per leaf, the gap of the change norms over the larger of the
     leaf's reference norm and the median leaf's; leaves that move by
-    round-off alone are left out."""
-    names = sorted(ref)
-    dp = [np.linalg.norm(np.asarray(prog[k], np.float64)
-                         - np.asarray(params0[k], np.float64)) for k in names]
-    dr = [np.linalg.norm(np.asarray(ref[k], np.float64)
-                         - np.asarray(params0[k], np.float64)) for k in names]
+    round-off alone are left out. The trees may nest; leaves are matched
+    by their key paths."""
+    start, mine, theirs = (leaves_by_path(t) for t in (params0, prog, ref))
+    names = list(theirs)
+    dp = [np.linalg.norm(np.asarray(mine[k], np.float64)
+                         - np.asarray(start[k], np.float64)) for k in names]
+    dr = [np.linalg.norm(np.asarray(theirs[k], np.float64)
+                         - np.asarray(start[k], np.float64)) for k in names]
     med = float(np.median(dr))
     if med == 0.0:  # the reference did not move: any change is a gap
         return {k: (0.0 if p == 0.0 else float("inf"))

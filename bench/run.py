@@ -5,7 +5,11 @@
 from the root of a checkout. The cell is the entry of `BENCHMARK.json`'s
 "workloads" named <name>: its model configuration
 (`bench/configs/<config>.json`) and its traffic (`bench/traffic/<name>.json`),
-which sets the data, the federated configuration and the loop:
+which sets the data, the federated configuration and the loop. The
+configuration names its family, the module `bench/families/<family>.py`
+that makes the cell's data, builds its server and holds its plain
+reference and model FLOP counts (`bench.families.image` says what a
+family gives). The loops:
 
 * "steady": back-to-back `FedServer.run(block, mode="scanned")` calls,
   each one block of rounds, for `--seconds`; `round_s` is the window over
@@ -18,12 +22,14 @@ which sets the data, the federated configuration and the loop:
 Set-up (`setup_s`, process start to the window) makes the data, builds
 the server and drives it through its first block (or its first training)
 with the window's own call, which compiles every program the window runs.
-Those first rounds are what the check compares with the plain reference
-(`bench.reference`, `bench.check`) once the window has closed.
+Those first rounds are what the check compares with the family's plain
+reference (`bench.check`) once the window has closed.
 
 With `--trace 1` the window runs under the JAX profiler for at most the
 traffic's "trace_seconds"; the per-layer metrics named in `BENCHMARK.json`
-for the cell are read from the trace by `bench/metrics/<name>.py`.
+for the cell are read by `bench/metrics/<name>.py` from the trace, with
+the program's scopes and spans in it (`bench.scopes`), and from the
+program's span counts over the window.
 
 The last line of standard output is the result object; earlier lines,
 each prefixed "bench:", carry the compile and cache counts, the peak
@@ -83,13 +89,23 @@ def load_cell(name: str, root: str = ROOT) -> SimpleNamespace:
                            end_to_end=end_to_end, bench=bench)
 
 
-def load_metric(name: str, root: str = ROOT):
-    path = os.path.join(root, "bench", "metrics", name + ".py")
+def _load_file(kind: str, name: str, root: str):
+    path = os.path.join(root, "bench", kind, name + ".py")
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        f"bench_{kind}_" + name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_metric(name: str, root: str = ROOT):
+    """The reader `bench/metrics/<name>.py` of a per-layer metric."""
+    return _load_file("metrics", name, root)
+
+
+def load_family(name: str, root: str = ROOT):
+    """The family module `bench/families/<name>.py`."""
+    return _load_file("families", name, root)
 
 
 class Compiles:
@@ -136,6 +152,9 @@ def use_compile_cache(root: str):
     import jax
 
     jax.config.update("jax_compilation_cache_dir", cache)
+    # a program whose named scopes moved is another program: the scope
+    # readers must not be served an executable of other code
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     # no eviction: a step program that carries its cell's data as
@@ -213,15 +232,14 @@ def main(argv=None, *, require_tpu: bool = True, root: str = ROOT,
 
     sys.path.insert(0, os.path.join(root, "src"))
     import repro
-    from bench import check, counts, reference
-    from bench import data as bench_data
-    from bench import trace as bench_trace
+    from bench import check, counts, scopes
 
+    family = load_family(cell.config["family"], root)
     tr = cell.traffic
     loop = tr["loop"]
     rng = np.random.default_rng(args.seed)
     t = time.perf_counter()
-    nodes, test = bench_data.make_nodes(tr)
+    nodes, test = family.make_data(tr)
     data_s = time.perf_counter() - t
     fl = repro.FLConfig(**tr["fl"]).validate()
     block, every = loop["block"], loop["eval_every"]
@@ -230,8 +248,7 @@ def main(argv=None, *, require_tpu: bool = True, root: str = ROOT,
         return int(rng.integers(0, 2**31 - 1))
 
     seed0 = new_seed()
-    server = repro.FedServer(tr["model"], fl, nodes, test,
-                             batch_size=tr["batch_size"], seed=seed0)
+    server = family.make_server(repro, fl, tr, nodes, test, seed0)
     steady = loop["kind"] == "steady"
     if not steady:
         cap, target = loop["max_rounds"], loop["target_acc"]
@@ -241,6 +258,7 @@ def main(argv=None, *, require_tpu: bool = True, root: str = ROOT,
                    mode="scanned", block=block)
     first = first_steps(jax, server, tr, seed0)
     setup_compiles = compiles.snapshot()
+    setup_spans = dict(server.spans.counts)
 
     tracing = bool(args.trace)
     seconds = min(args.seconds, tr["trace_seconds"]) if tracing else args.seconds
@@ -249,6 +267,7 @@ def main(argv=None, *, require_tpu: bool = True, root: str = ROOT,
                 else lambda name: contextlib.nullcontext())
     rounds = failed = 0
     trainings = []  # (seed, History, final params, round counter)
+    histories = []
     evals = 0
     t_start = time.perf_counter()
     setup_s = t_start - _T0
@@ -262,6 +281,7 @@ def main(argv=None, *, require_tpu: bool = True, root: str = ROOT,
                 with annotate("bench/run"):
                     h = server.run(block, eval_every=every, mode="scanned",
                                    block=block)
+                histories.append(h)
                 evals += len(h.accuracy)
                 rounds += block
                 failed += int(np.sum(~np.isfinite(np.asarray(h.loss))))
@@ -277,6 +297,7 @@ def main(argv=None, *, require_tpu: bool = True, root: str = ROOT,
                 with annotate("bench/run"):
                     h = server.run(cap, target_acc=target, eval_every=every,
                                    mode="scanned", block=block)
+                histories.append(h)
                 # the final params, kept on the device for the check, and
                 # in the traced run the server's round counter
                 trainings.append((s, h, server.state.params, None))
@@ -285,6 +306,8 @@ def main(argv=None, *, require_tpu: bool = True, root: str = ROOT,
                         trainings[-1] = (s, h, server.state.params,
                                          server.round)
     t_end = time.perf_counter()
+    span_counts = {k: v - setup_spans.get(k, 0)
+                   for k, v in server.spans.counts.items()}
     if tracing:
         jax.profiler.stop_trace()
     window_s = t_end - t_start
@@ -344,7 +367,7 @@ def main(argv=None, *, require_tpu: bool = True, root: str = ROOT,
     gc.collect()
 
     t_check = time.perf_counter()
-    found = run_check(jax, cell, nodes, test, [first] + samples, reference,
+    found = run_check(jax, cell, nodes, test, [first] + samples, family,
                       check)
     check_s = time.perf_counter() - t_check
     correct, table = check.judge(found, tr["check"]["limits"])
@@ -355,11 +378,13 @@ def main(argv=None, *, require_tpu: bool = True, root: str = ROOT,
               "count": len(devices), "memory_peak_bytes": peak}
     out = {"correct": bool(correct), "attempted": attempted, "failed": failed}
     if tracing:
-        summary = bench_trace.load(trace_dir)
+        scoped = scopes.load(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
+        summary = scoped.base
         ctx = SimpleNamespace(
             cell=cell, traffic=tr, config=cell.config, trace=summary,
-            window_s=window_s, rounds=rounds, evals=evals,
+            scopes=scoped, span_counts=span_counts, histories=histories,
+            family=family, window_s=window_s, rounds=rounds, evals=evals,
             trainings=ttt_rows, chips=cell.chips, peaks=peaks, clients=k,
             params=n, counts=counts)
         per = {}
@@ -388,7 +413,10 @@ def main(argv=None, *, require_tpu: bool = True, root: str = ROOT,
 
 
 def fl_param_count(params) -> int:
-    return sum(int(a.size) for a in params.values())
+    """Values in a params tree, nested or flat."""
+    import jax
+
+    return sum(int(a.size) for a in jax.tree_util.tree_leaves(params))
 
 
 def reference_device(jax, traffic: dict):
@@ -398,15 +426,16 @@ def reference_device(jax, traffic: dict):
     return jax.default_device(jax.devices(platform)[0] if platform else None)
 
 
-def run_check(jax, cell, nodes, test, runs: list, reference, check) -> dict:
-    """Follow each compared run with the reference; worst readings."""
+def run_check(jax, cell, nodes, test, runs: list, family, check) -> dict:
+    """Follow each compared run with the family's reference; worst
+    readings."""
     tr = cell.traffic
-    s = reference.setup_from(tr)
+    s = family.reference_setup(tr)
     every = tr["loop"]["eval_every"]
     rows, disputed = [], 0
     with reference_device(jax, tr):
-        data = reference.device_data(nodes, test)
-        refs = [reference.follow(s, data, run["seed"], run["rounds"], every)
+        data = family.reference_data(nodes, test)
+        refs = [family.follow(s, data, run["seed"], run["rounds"], every)
                 for run in runs]
     for run, ref in zip(runs, refs):
         rows.append(check.readings(run, ref))

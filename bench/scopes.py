@@ -281,12 +281,10 @@ def main(argv=None, *, require_tpu: bool = True, cell=None) -> int:
     p.add_argument("--seconds", type=float, required=True)
     args = p.parse_args(argv)
 
-    from bench import data as bench_data
     from bench import run
 
     root = run.ROOT
     jax = run.use_compile_cache(root)
-    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     if require_tpu and jax.default_backend() != "tpu":
         print("bench.scopes: needs a TPU chip", file=sys.stderr)
         return 2
@@ -296,6 +294,7 @@ def main(argv=None, *, require_tpu: bool = True, cell=None) -> int:
     import repro
 
     cell = cell or run.load_cell(args.workload, root)
+    family = run.load_family(cell.config["family"], root)
     tr, loop = cell.traffic, cell.traffic["loop"]
     block, every = loop["block"], loop["eval_every"]
     rng = np.random.default_rng(args.seed)
@@ -303,10 +302,9 @@ def main(argv=None, *, require_tpu: bool = True, cell=None) -> int:
     def new_seed() -> int:
         return int(rng.integers(0, 2**31 - 1))
 
-    nodes, test = bench_data.make_nodes(tr)
-    server = repro.FedServer(tr["model"], repro.FLConfig(**tr["fl"]).validate(),
-                             nodes, test, batch_size=tr["batch_size"],
-                             seed=new_seed())
+    nodes, test = family.make_data(tr)
+    server = family.make_server(repro, repro.FLConfig(**tr["fl"]).validate(),
+                                tr, nodes, test, new_seed())
     annotate = jax.profiler.TraceAnnotation
     steady = loop["kind"] == "steady"
 

@@ -1,10 +1,13 @@
 """BENCHMARK.json holds to its contract, and every cell and metric it
-names has the files the harness finds by that name."""
+names has the files the harness finds by that name; each cell's traffic
+keeps its family's rules."""
 import json
 import os
 import re
 
 import pytest
+
+from bench import run
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
@@ -31,6 +34,8 @@ def test_config_file(entry):
         cfg = json.load(f)
     assert cfg["name"] == entry["name"]
     assert cfg["reduced"] == entry["reduced"]
+    assert os.path.isfile(os.path.join(ROOT, "bench", "families",
+                                       cfg["family"] + ".py"))
     assert 1 <= len(entry["why"]) <= 200
 
 
@@ -46,13 +51,8 @@ def test_cell_has_traffic_and_config(cell):
         traffic = json.load(f)
     assert traffic["loop"]["kind"] in ("steady", "to_target")
     assert traffic["check"]["limits"], "a cell's check compares something"
-    fl = traffic["fl"]
-    split = traffic["data"]["split"]
-    samples = split.get("samples") or (
-        traffic["data"]["num_train"]
-        // (split["clients"] * split["shards_per_client"])
-        * split["shards_per_client"])
-    assert fl["local_steps"] == samples // traffic["batch_size"]
+    cfg = run.load_cell(cell["name"], ROOT).config
+    run.load_family(cfg["family"], ROOT).validate_traffic(traffic)
 
 
 @pytest.mark.parametrize(
