@@ -6,9 +6,9 @@ without reaching into `repro.core.*`:
     import repro
 
     cfg = repro.FLConfig(num_clients=10, clients_per_round=10,
-                         local_steps=0, aggregation="buffered",
+                         local_steps=12, aggregation="buffered",
                          buffer_m=7).validate()
-    server = repro.FedServer("mlr", cfg, nodes, test, batch_size=32)
+    server = repro.FedServer("mlr", cfg, nodes, test, batch_size=50)
     hist = server.run(300, target_acc=0.85, mode="scanned")
 
 `__all__` is pinned by tests/test_api.py; grow it deliberately. The
@@ -16,6 +16,7 @@ deeper modules (`repro.core`, `repro.kernels`, `repro.transport`, ...)
 remain importable for tests and internals, but scripts/examples/
 benchmarks go through this facade.
 """
+from repro.core.clients import ClientModel, lm_client  # noqa: F401
 from repro.core.fl import (  # noqa: F401
     FLConfig,
     RoundState,
@@ -41,6 +42,7 @@ from repro.telemetry.spans import SpanTimer  # noqa: F401
 
 __all__ = [
     "CSVSink",
+    "ClientModel",
     "FLConfig",
     "FedServer",
     "History",
@@ -50,6 +52,7 @@ __all__ = [
     "SpanTimer",
     "fixed_arrival_schedule",
     "init_round_state",
+    "lm_client",
     "make_mesh",
     "make_round_fn",
     "run_manifest",

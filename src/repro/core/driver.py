@@ -73,14 +73,29 @@ class ClientData(NamedTuple):
     batch_size: int  # B (static)
 
 
-def stack_nodes(nodes: list, batch_size: int) -> ClientData:
+def stack_nodes(nodes: list, batch_size: int,
+                local_steps: Optional[int] = None) -> ClientData:
     """Stack host node datasets into one device-resident ClientData.
+
+    tau is `local_steps` where given: each round a node trains on the
+    first tau * batch_size rows of a fresh permutation of its data, which
+    may not pass one epoch. Otherwise tau is one epoch, len // batch_size,
+    equal on every node.
 
     Raises ValueError when a node is too small for even one batch
     (tau = len // batch_size = 0 — the old numpy batcher crashed with an
-    opaque reshape error here) or when nodes disagree on tau.
+    opaque reshape error here), when `local_steps` passes a node's epoch,
+    or when nodes disagree on tau.
     """
     taus = [len(ds.y) // batch_size for ds in nodes]
+    if local_steps is not None:
+        for i, (ds, tau) in enumerate(zip(nodes, taus)):
+            if local_steps > tau:
+                raise ValueError(
+                    f"local_steps={local_steps} passes one epoch of node "
+                    f"{i}: {len(ds.y)} samples make {tau} batches of "
+                    f"{batch_size}")
+        taus = [local_steps] * len(nodes)
     for i, (ds, tau) in enumerate(zip(nodes, taus)):
         if tau < 1:
             raise ValueError(
@@ -162,7 +177,8 @@ def epoch_batches(key, data: ClientData, sel: jax.Array):
         idx = jnp.argsort(u)[:count]
         xb = data.x[c][idx].reshape(
             (data.tau, data.batch_size) + data.x.shape[2:])
-        yb = data.y[c][idx].reshape(data.tau, data.batch_size)
+        yb = data.y[c][idx].reshape(
+            (data.tau, data.batch_size) + data.y.shape[2:])
         return xb, yb
 
     return jax.vmap(one)(sel)
@@ -209,7 +225,8 @@ def make_step_fn(loss_fn: Callable, fl: fl_mod.FLConfig, data: ClientData,
     step(state, eval_every) -> (state, metrics): split the state's RNG,
     select this round's cohort, draw each client's epoch batches, run the
     compiled round, and (when `eval_fn` is given) conditionally append
-    `metrics["accuracy"]` — evaluated only after rounds where
+    `metrics["accuracy"]` (or every key of the dict `eval_fn` returns,
+    "accuracy" among them) — evaluated only after rounds where
     round % eval_every == 0 post-increment (i.e. (r+1) % eval_every == 0),
     the named `EVAL_SENTINEL` (-1.0) otherwise, so the eval forward pass
     is skipped via `lax.cond` on non-eval rounds. `eval_every` is a
@@ -253,6 +270,15 @@ def make_step_fn(loss_fn: Callable, fl: fl_mod.FLConfig, data: ClientData,
         if eval_fn is not None:
             with jax.named_scope("fl.eval"):
                 do_eval = (eval_every > 0) & (state.round % eval_every == 0)
+                out = jax.eval_shape(eval_fn, state.params)
+                if isinstance(out, dict):  # accuracy and more readings
+                    res = jax.lax.cond(
+                        do_eval, eval_fn,
+                        lambda p: {k: jnp.full(v.shape, EVAL_SENTINEL,
+                                               v.dtype)
+                                   for k, v in out.items()},
+                        state.params)
+                    return state, dict(metrics, **res)
                 acc = jax.lax.cond(do_eval, eval_fn,
                                    lambda p: jnp.float32(EVAL_SENTINEL),
                                    state.params)

@@ -13,19 +13,21 @@ Two execution modes (DESIGN.md §6):
 * ``sequential`` — one model copy (FSDP-shardable), clients advanced by
   `lax.scan`. FedAdp needs the round's global gradient *before* weighting,
   so the exact variant runs TWO passes (local training recomputed in pass
-  2 — compute x2, memory x1/K). The key identity making two (not three)
-  passes suffice: softmax weights factor as w_i = D_i e^{f(θ̃_i)} with a
-  scalar denominator, so pass 2 can accumulate Σ w_i Δ_i and Σ w_i online.
+  2 — compute x2, memory x1/K; no raveled copy of any delta). The key
+  identity making two (not three) passes suffice: softmax weights factor
+  as w_i = D_i e^{f(θ̃_i)} with a scalar denominator, so pass 2 can
+  accumulate Σ w_i Δ_i and Σ w_i online.
 
   ``stale_angles=True`` is the beyond-paper one-pass variant: angles are
   measured against the *previous* round's aggregated delta (one-round
   staleness), restoring pass-1-only compute. Evaluated in EXPERIMENTS.md.
 
-Both modes compute their angle statistics through ONE implementation —
-the fused `kernels.round_stats` Pallas kernel (client-chunked, any K):
-parallel flat engines feed it the stacked (K, N) buffer (optionally
-client-row-sharded under shard_map), the sequential scan feeds it one
-(1, N) row per client.
+Both modes compute their angle statistics through the Pallas kernels of
+`kernels.round_stats`: parallel flat engines feed `round_stats`
+(client-chunked, any K) the stacked (K, N) buffer (optionally
+client-row-sharded under shard_map); the sequential scan, which builds
+no flat buffer, calls `leaf_stats` on each leaf of a client's trained
+and starting params in the leaf's own layout, so no delta is written.
 
 Angle convention: the paper defines θ_i between ∇F and ∇F_i with
 ∇F_i = -Δ_i/η (Alg. 1 l.9); the -1/η factors cancel in the cosine, so we
@@ -99,9 +101,9 @@ class FLConfig:
     #            agree to 1e-5 per transport. error_feedback is
     #            incompatible with a quantized 2D wire (the residual is
     #            a global tree-ravel-order buffer) and raises.
-    # The sequential mode's pass-2 statistics also stream through the
-    # round_stats kernel (K=1 rows against the raveled global delta), so
-    # all modes share one stats implementation.
+    # The sequential mode's pass-2 statistics stream through the
+    # round_stats module's `leaf_stats` kernel, one call per leaf of each
+    # client's trained and starting params against the global delta.
     engine: str = "tree"  # tree | flat | flat_sharded
     # Delta transport — the client-uplink wire format (repro.transport):
     #   "f32"  — reference wire, deltas ship unmodified.
@@ -602,8 +604,23 @@ def local_update(loss_fn: Callable, params: PyTree, batches: PyTree, lr,
     grad_constraint re-shards per-step gradients (e.g. onto the FSDP param
     spec so GSPMD reduce-scatters batch-partial grads instead of
     all-reducing the full tree — §Perf collective-term optimization).
-    Returns (delta, mean_loss)."""
+    Returns (delta, mean_loss).
 
+    A loss with `loss_fn.has_aux` returns (loss, counters), a dict of
+    scalars (e.g. a model's expert-row count); the update then returns
+    (delta, mean_loss, counters summed over the tau steps)."""
+    if getattr(loss_fn, "has_aux", False):
+        p_fin, losses, counters = _local_final_counted(
+            loss_fn, params, batches, lr, prox_mu, grad_constraint)
+        return (treemath.tree_sub(p_fin, params), jnp.mean(losses),
+                _summed(counters))
+    p_fin, losses = _local_final(loss_fn, params, batches, lr, prox_mu,
+                                 grad_constraint)
+    return treemath.tree_sub(p_fin, params), jnp.mean(losses)
+
+
+def _local_final(loss_fn, params, batches, lr, prox_mu, grad_constraint):
+    """(final params, per-step losses) of `local_update`'s tau steps."""
     if prox_mu > 0.0:
         base = loss_fn
 
@@ -617,8 +634,33 @@ def local_update(loss_fn: Callable, params: PyTree, batches: PyTree, lr,
             g = grad_constraint(g)
         return treemath.tree_axpy(-lr, g, p), loss
 
-    p_fin, losses = jax.lax.scan(step, params, batches)
-    return treemath.tree_sub(p_fin, params), jnp.mean(losses)
+    return jax.lax.scan(step, params, batches)
+
+
+def _local_final_counted(loss_fn, params, batches, lr, prox_mu,
+                         grad_constraint):
+    """(final params, per-step losses, per-step counters) of tau steps of
+    a loss that returns (loss, counters)."""
+
+    def total(p, b):
+        loss, counters = loss_fn(p, b)
+        if prox_mu > 0.0:
+            prox = treemath.tree_sqnorm(treemath.tree_sub(p, params))
+            loss = loss + 0.5 * prox_mu * prox
+        return loss, counters
+
+    def step(p, b):
+        (loss, counters), g = jax.value_and_grad(total, has_aux=True)(p, b)
+        if grad_constraint is not None:
+            g = grad_constraint(g)
+        return treemath.tree_axpy(-lr, g, p), (loss, counters)
+
+    p_fin, (losses, counters) = jax.lax.scan(step, params, batches)
+    return p_fin, losses, counters
+
+
+def _summed(counters):
+    return jax.tree.map(lambda c: jnp.sum(c, axis=0), counters)
 
 
 def local_update_clients(clients_loss: Callable, params: PyTree,
@@ -823,6 +865,10 @@ def make_round_fn(loss_fn: Callable, fl: FLConfig,
         raise ValueError(
             "engine='flat_sharded' shards the (K, N) delta buffer over "
             "the mesh client axis; pass mesh= to make_round_fn")
+    if fl.mode == "parallel" and getattr(loss_fn, "has_aux", False):
+        raise ValueError(
+            "a loss that returns counters (loss_fn.has_aux) trains its "
+            "clients one at a time: use mode='sequential'")
     if fl.mode == "parallel":
         if fl.aggregation == "buffered":
             return _make_buffered_round(loss_fn, fl, delta_constraint,
@@ -1589,57 +1635,91 @@ def _make_buffered_round(loss_fn, fl: FLConfig, delta_constraint,
 
 def _make_sequential_round(loss_fn, fl: FLConfig, angle_pred=None,
                            grad_constraint=None):
+    """The round that trains one client at a time, for a model of which
+    the chip holds one training copy. Besides the params and
+    `prev_delta` it holds three full-size f32 trees: the weighted sum
+    and the training client's params and grads; no client's delta is
+    ever written out. Pass 1 accumulates the FedAvg delta g in what
+    becomes `prev_delta`; pass 2 retrains the same clients on the same
+    batches, streams each client's statistics against g leaf by leaf
+    through `round_stats.leaf_stats` (from its trained and its starting
+    params), and accumulates the Gompertz-weighted sum. A loss with
+    counters (`loss_fn.has_aux`) adds them, summed over both passes, to
+    the round's metrics."""
+    has_aux = getattr(loss_fn, "has_aux", False)
+
+    def train(params, b_i, lr):
+        """(client's final params, mean loss, counters)."""
+        if has_aux:
+            p_fin, losses, counters = _local_final_counted(
+                loss_fn, params, b_i, lr, fl.prox_mu, grad_constraint)
+            return p_fin, jnp.mean(losses), _summed(counters)
+        p_fin, losses = _local_final(loss_fn, params, b_i, lr, fl.prox_mu,
+                                     grad_constraint)
+        return p_fin, jnp.mean(losses), {}
+
+    def axpy_delta(a, p_fin, params, acc):
+        """acc + a (p_fin - params), leaf by leaf, in f32."""
+        return jax.tree.map(
+            lambda f, p, c: c + a * (f.astype(jnp.float32)
+                                     - p.astype(jnp.float32)),
+            p_fin, params, acc)
+
+    def stats(p_fin, params, g, keep):
+        dot = sq = sqg = jnp.zeros((), jnp.float32)
+        leaves = zip(jax.tree.leaves(p_fin), jax.tree.leaves(params),
+                     jax.tree.leaves(g))
+        for i, (f, p, gl) in enumerate(leaves):
+            if keep is None or keep[i]:
+                d_, s_, g_ = round_stats_mod.leaf_stats(f, p, gl)
+                dot, sq, sqg = dot + d_, sq + s_, sqg + g_
+        return dot, sq, sqg
+
+    def add(a, b):
+        return jax.tree.map(jnp.add, a, b)
+
     def round_fn(state: RoundState, batches, sel_idx, data_sizes):
         params, angle_state = state.params, state.angle
-        prev_delta = state.prev_delta
         lr = _lr_at(fl, state.round)
-        # one stats implementation across modes: pass-2 statistics stream
-        # through the round_stats kernel as a single-row (1, N) buffer per
-        # scan step, with the MoE angle filter as a flat segment mask.
-        with jax.named_scope("fl.stats"):
-            maskv = (
-                treemath.segment_mask(params,
-                                      angle_keep_list(params, angle_pred))
-                if angle_pred else None
-            )
+        keep = angle_keep_list(params, angle_pred) if angle_pred else None
         with jax.named_scope("fl.weights"):
             psi_avg = data_sizes / jnp.sum(data_sizes)
         zeros32 = jax.tree.map(
             lambda p: jnp.zeros(p.shape, jnp.float32), params
         )
+        counts0 = {}
+        if has_aux:  # zeros shaped as one step's counters
+            step0 = jax.tree.map(lambda a: a[0, 0], batches)
+            counts0 = jax.tree.map(
+                jnp.zeros_like, jax.eval_shape(loss_fn, params, step0)[1])
 
         if not fl.stale_angles:
             # ---- pass 1: global (FedAvg-weighted) delta ----
-            def p1(acc, xs):
+            def p1(carry, xs):
+                acc, counts = carry
                 b_i, psi_i = xs
                 with jax.named_scope("fl.local_train"):
-                    d_i, loss = local_update(loss_fn, params, b_i, lr,
-                                             fl.prox_mu, grad_constraint)
+                    p_i, loss, c_i = train(params, b_i, lr)
                 with jax.named_scope("fl.stats"):
-                    return treemath.tree_axpy(psi_i, d_i, acc), loss
+                    acc = axpy_delta(psi_i, p_i, params, acc)
+                return (acc, add(counts, c_i)), loss
 
-            g_avg, losses = jax.lax.scan(p1, zeros32, (batches, psi_avg))
-            g_ref = g_avg
+            (g_ref, counts0), losses = jax.lax.scan(
+                p1, (zeros32, counts0), (batches, psi_avg))
+            g_acc0 = None
         else:
-            g_ref = prev_delta
+            g_ref = state.prev_delta
             losses = None
-
-        with jax.named_scope("fl.stats"):
-            g_flat, _ = treemath.tree_ravel(g_ref)
+            g_acc0 = zeros32
 
         # ---- pass 2 (or single stale pass): stats + online weighted sum ----
         def p2(carry, xs):
-            num, den, g_acc = carry
+            num, den, g_acc, counts = carry
             b_i, psi_i, D_i, idx_i = xs
             with jax.named_scope("fl.local_train"):
-                d_i, loss = local_update(loss_fn, params, b_i, lr,
-                                         fl.prox_mu, grad_constraint)
-            with jax.named_scope("fl.uplink"):
-                d_flat, _ = treemath.tree_ravel(d_i)
+                p_i, loss, c_i = train(params, b_i, lr)
             with jax.named_scope("fl.stats"):
-                dots_i, sqs_i, sqg_i = round_stats_mod.round_stats(
-                    d_flat[None], g_flat, maskv)
-                dot, sq = dots_i[0], sqs_i[0]
+                dot, sq, sqg_i = stats(p_i, params, g_ref, keep)
                 theta_i = weighting.instantaneous_angle(dot, sq, sqg_i)
                 cnt = angle_state.count[idx_i].astype(jnp.float32) + 1.0
                 sm = ((cnt - 1.0) * angle_state.smoothed[idx_i]
@@ -1650,12 +1730,14 @@ def _make_sequential_round(loss_fn, fl: FLConfig, angle_pred=None,
                 else:
                     w_i = D_i
             with jax.named_scope("fl.aggregate"):
-                num = treemath.tree_axpy(w_i, d_i, num)
-                g_acc = treemath.tree_axpy(psi_i, d_i, g_acc)
-            return (num, den + w_i, g_acc), (theta_i, sm, dot, sq, sqg_i, loss)
+                num = axpy_delta(w_i, p_i, params, num)
+                if g_acc is not None:  # the stale pass's FedAvg delta
+                    g_acc = axpy_delta(psi_i, p_i, params, g_acc)
+            return ((num, den + w_i, g_acc, add(counts, c_i)),
+                    (theta_i, sm, dot, sq, sqg_i, loss))
 
-        (num, den, g_acc), ys = jax.lax.scan(
-            p2, (zeros32, jnp.zeros((), jnp.float32), zeros32),
+        (num, den, g_acc, counts), ys = jax.lax.scan(
+            p2, (zeros32, jnp.zeros((), jnp.float32), g_acc0, counts0),
             (batches, psi_avg, data_sizes.astype(jnp.float32), sel_idx),
         )
         theta, theta_sm, dots, sqs, sqgs, losses2 = ys
@@ -1679,11 +1761,13 @@ def _make_sequential_round(loss_fn, fl: FLConfig, angle_pred=None,
             "theta": theta, "theta_smoothed": theta_sm, "weights": w,
             "divergence": div, "lr": lr, "cos": jnp.cos(theta),
             "expected_contribution": weighting.expected_contribution(w, jnp.cos(theta)),
+            **counts,
         }
         if fl.telemetry:
             metrics.update(_telemetry_metrics(fl, params, sel_idx, w))
         return state._replace(
-            params=new_params, angle=new_state, prev_delta=g_acc,
+            params=new_params, angle=new_state,
+            prev_delta=g_ref if g_acc is None else g_acc,
             round=state.round + 1,
         ), metrics
 

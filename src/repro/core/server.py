@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import io as ckpt_io
+from repro.core import clients as clients_mod
 from repro.core import driver as driver_mod
 from repro.core import fl as fl_mod
 from repro.data.synthetic import Dataset
@@ -76,6 +77,13 @@ class History:
     final_accuracy: float
     thetas: list  # per-round smoothed angles of the selected clients
     weights: list
+    # a language model's (`clients.lm_client`): per round, the token-
+    # expert rows through the held experts' products in local training
+    # (both passes of the sequential round); per eval round, the eval's
+    # mean loss and its expert rows
+    expert_rows: list = dataclasses.field(default_factory=list)
+    eval_loss: list = dataclasses.field(default_factory=list)
+    eval_expert_rows: list = dataclasses.field(default_factory=list)
 
 
 class FedServer:
@@ -83,7 +91,7 @@ class FedServer:
 
     def __init__(
         self,
-        model: str,  # "mlr" | "cnn"
+        model,  # "mlr" | "cnn" | a clients.ClientModel
         fl: fl_mod.FLConfig,
         nodes: list,  # list[Dataset]
         test: Dataset,
@@ -104,28 +112,24 @@ class FedServer:
         # each step into a buffered-async server tick; `arrival_fn`
         # (fixed_arrival_schedule) then overrides the config's random
         # straggler/dropout draw.
+        # Each node trains fl.local_steps minibatches of `batch_size` a
+        # round, the first of a fresh permutation of its data (at most one
+        # epoch).
         self.fl = fl
         self.nodes = nodes
         self.test = test
         self.batch_size = batch_size
-        init_fn, self.apply_fn = small.MODELS[model]
-
-        def loss_of(apply_fn):
-            def loss(params, batch):
-                x, y = batch
-                return small.classification_loss(apply_fn, params, x, y)
-
-            return loss
-
-        loss_fn = loss_of(self.apply_fn)
-        if model in small.CLIENTS_APPLY:  # batch leaves (B, K, ...)
-            loss_fn.clients = loss_of(small.CLIENTS_APPLY[model])
+        client = (model if isinstance(model, clients_mod.ClientModel)
+                  else clients_mod.image_client(model))
+        init_fn, self.apply_fn = client.init, client.apply
+        loss_fn = client.loss
         # "batched", "vmap" or "sequential": how the step's local updates
         # run (`fl.local_update_path`); every run's manifest records it
         self.local_update = fl_mod.local_update_path(loss_fn, fl, mesh)
 
-        self.data = driver_mod.stack_nodes(nodes, batch_size)
-        eval_fn = driver_mod.make_eval_fn(self.apply_fn, test.x, test.y)
+        self.data = client.stack(nodes, batch_size, fl.local_steps)
+        eval_fn = client.make_eval(test)
+        self._eval_fn = eval_fn
         self._step_fn = driver_mod.make_step_fn(
             loss_fn, fl, self.data, eval_fn=eval_fn, angle_pred=angle_pred,
             mesh=mesh, arrival_fn=arrival_fn)
@@ -178,7 +182,10 @@ class FedServer:
             return jax.device_get(metrics)
 
     def evaluate(self) -> float:
-        """Host-side test accuracy of the current master params."""
+        """Test accuracy of the current master params (on the host for an
+        image model)."""
+        if self.apply_fn is None:
+            return float(jax.jit(self._eval_fn)(self.state.params)["accuracy"])
         return small.accuracy(self.apply_fn, self.state.params,
                               self.test.x, self.test.y)
 
@@ -239,7 +246,7 @@ class FedServer:
                                                    every=telemetry_every)
                     acc = float(m["accuracy"])
                     if tel_schema.is_real_accuracy(acc):
-                        hist.accuracy.append(acc)
+                        self._append_eval(hist, m)
                         if verbose:
                             print(f"round {r+1:4d} loss {m['loss']:.4f} "
                                   f"acc {acc:.4f}")
@@ -262,7 +269,8 @@ class FedServer:
                         self._append(hist, {k: v[r] for k, v in ms.items()})
                         acc = float(ms["accuracy"][r])
                         if tel_schema.is_real_accuracy(acc):
-                            hist.accuracy.append(acc)
+                            self._append_eval(
+                                hist, {k: v[r] for k, v in ms.items()})
         hist.final_accuracy = hist.accuracy[-1] if hist.accuracy else 0.0
         if sink is not None:
             tel_sinks.emit_summary(
@@ -342,6 +350,16 @@ class FedServer:
         hist.divergence.append(float(m["divergence"]))
         hist.thetas.append(np.asarray(m["theta_smoothed"]))
         hist.weights.append(np.asarray(m["weights"]))
+        if "expert_rows" in m:
+            hist.expert_rows.append(int(m["expert_rows"]))
+
+    @staticmethod
+    def _append_eval(hist: History, m: dict) -> None:
+        """The readings of a round whose eval ran."""
+        hist.accuracy.append(float(m["accuracy"]))
+        if "eval_loss" in m:
+            hist.eval_loss.append(float(m["eval_loss"]))
+            hist.eval_expert_rows.append(int(m["eval_expert_rows"]))
 
 
 def _epoch_batcher(ds: Dataset, batch_size: int, seed: int):

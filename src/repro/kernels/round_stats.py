@@ -38,6 +38,12 @@ in-register), and the server-side g / mask vectors ride along as even/odd
 (ROWS, LANE) views so every nibble pairs with its own g element without
 ever interleaving the wire buffer — one HBM pass over ~8x fewer bytes.
 
+`leaf_stats` serves the sequential round, which never builds a flat
+buffer: the statistics of one client's delta p - q (its trained and its
+starting params) against g, leaf by leaf, each leaf read in its own
+layout as (rows, last dim) row blocks, a view that only merges major
+dims, so no leaf is copied or padded and the delta is never written.
+
 Off a TPU the identical kernel body runs in the Pallas interpreter
 (`repro.kernels.interpret_mode`).
 """
@@ -304,3 +310,57 @@ def round_stats_q(values: jax.Array, scales: jax.Array, g: jax.Array,
                                masked=mask is not None)
     return _stats_call(kernel, operands, in_specs, k=K, tile=tile, kp=kp,
                        rows=ROWS, m=m, interpret=interpret)
+
+
+# values of one operand a leaf_stats grid step reads (1 MiB of f32)
+LEAF_BLOCK_ELEMS = 256 * 1024
+
+
+def _leaf_kernel(p_ref, q_ref, g_ref, dot_ref, sq_ref, sqg_ref, *, rows,
+                 blk):
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _init():
+        for ref in (dot_ref, sq_ref, sqg_ref):
+            ref[...] = jnp.zeros_like(ref)
+
+    d = p_ref[...].astype(jnp.float32) - q_ref[...].astype(jnp.float32)
+    g = g_ref[...].astype(jnp.float32)
+    if rows % blk:  # the ragged last block reads past the leaf's rows
+        row = i * blk + jax.lax.broadcasted_iota(jnp.int32, d.shape, 0)
+        d = jnp.where(row < rows, d, 0.0)
+        g = jnp.where(row < rows, g, 0.0)
+    dot_ref[...] += jnp.sum(d * g, axis=0, keepdims=True)
+    sq_ref[...] += jnp.sum(d * d, axis=0, keepdims=True)
+    sqg_ref[...] += jnp.sum(g * g, axis=0, keepdims=True)
+
+
+def leaf_stats(p: jax.Array, q: jax.Array, g: jax.Array, *, interpret=None,
+               min_kernel_elems=None):
+    """(<p - q, g>, ||p - q||^2, ||g||^2) of one leaf, f32, in one pass
+    over the three. The leaf is read as (rows, last dim) row blocks with
+    lane-dense (1, last dim) accumulators. Leaves below
+    `min_kernel_elems` elements (default SMALL_ELEMS; 0 forces Pallas),
+    and vectors, compute as plain XLA reductions."""
+    if p.ndim < 2 or _use_fallback(1, p.size, min_kernel_elems):
+        d = p.astype(jnp.float32) - q.astype(jnp.float32)
+        gf = g.astype(jnp.float32)
+        return jnp.sum(d * gf), jnp.sum(d * d), jnp.sum(gf * gf)
+    cols = p.shape[-1]
+    rows = p.size // cols
+    blk = max(8, LEAF_BLOCK_ELEMS // cols // 8 * 8)
+    if blk >= rows:
+        blk = rows
+    in_spec = pl.BlockSpec((blk, cols), lambda i: (i, 0))
+    out_spec = pl.BlockSpec((1, cols), lambda i: (0, 0))
+    acc = jax.ShapeDtypeStruct((1, cols), jnp.float32)
+    dot, sq, sqg = pl.pallas_call(
+        functools.partial(_leaf_kernel, rows=rows, blk=blk),
+        grid=(pl.cdiv(rows, blk),),
+        in_specs=[in_spec] * 3,
+        out_specs=(out_spec,) * 3,
+        out_shape=(acc,) * 3,
+        interpret=interpret_mode(interpret),
+    )(*(a.reshape(rows, cols) for a in (p, q, g)))
+    return jnp.sum(dot), jnp.sum(sq), jnp.sum(sqg)

@@ -25,6 +25,25 @@ class MoEConfig:
     # token sums at most top_k + shared expert outputs, so bf16 combine is
     # benign and halves the dominant dispatch-stream HBM traffic (§Perf).
     combine_dtype: str = "float32"
+    # DeepSeek-V2's gate (arXiv:2405.04434 §2.1.2; HF `MoEGate`): the
+    # top-k softmax scores are renormalized to sum to one only where
+    # `norm_topk_prob`, then scaled by `routed_scaling_factor`.
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # "switch": the load-balance loss over the whole batch's assignments;
+    # "seq": DeepSeek's sequence-level one (`seq_aux`), per sequence
+    # sum_e (E f_e / (T k)) mean_t p_te, averaged over the batch.
+    aux_loss: str = "switch"
+    # router logits at "default" matmul precision or "highest" (the
+    # published gate computes them in float32)
+    router_precision: str = "default"
+    # The routed experts this layer holds, by id, as one chip of an
+    # expert-parallel deployment holds them. Such a layer routes over all
+    # `num_experts`, adds only its own experts' part of the output and
+    # drops no token-expert pair (`moe.py`'s dropless dispatch). () holds
+    # all `num_experts` (their stacks shardable over the mesh) at a fixed
+    # per-expert capacity.
+    held: Tuple[int, ...] = ()
     # apply MoE every `every` layers within a block pattern (hybrid use)
     # — for pure-MoE models all layers are MoE.
 
@@ -38,6 +57,19 @@ class MLAConfig:
     rope_head_dim: int = 64
     nope_head_dim: int = 128
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN RoPE scaling as DeepSeek-V2 applies it (HF
+    `DeepseekV2YarnRotaryEmbedding`; `layers.yarn_inv_freq`)."""
+
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,6 +120,7 @@ class ModelConfig:
     norm: str = "rmsnorm"  # rmsnorm | layernorm
     rope_theta: float = 10000.0
     rope_style: str = "rope"  # rope | mrope | none
+    yarn: Optional[YarnConfig] = None  # YaRN-scaled RoPE frequencies
     mrope_sections: Tuple[int, int, int] = (16, 24, 24)  # of head_dim/2
     sliding_window: int = 0  # 0 => full attention
     q_chunk: int = 0  # >0: query-blocked attention (memory-term opt, §Perf)
@@ -103,6 +136,9 @@ class ModelConfig:
     # small smoke shapes only). Train/prefill full-sequence path only.
     attention_impl: str = "xla"
     tie_embeddings: bool = True
+    # leading dense blocks (MLP width `d_ff`) before the scanned groups,
+    # DeepSeek's `first_k_dense_replace`; `num_layers` counts them too
+    first_dense_layers: int = 0
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
@@ -127,11 +163,12 @@ class ModelConfig:
 
     @property
     def num_pattern_groups(self) -> int:
-        assert self.num_layers % len(self.block_pattern) == 0, (
-            f"{self.name}: num_layers={self.num_layers} not divisible by "
-            f"pattern length {len(self.block_pattern)}"
+        layers = self.num_layers - self.first_dense_layers
+        assert layers % len(self.block_pattern) == 0, (
+            f"{self.name}: {layers} layers after the dense ones not "
+            f"divisible by pattern length {len(self.block_pattern)}"
         )
-        return self.num_layers // len(self.block_pattern)
+        return layers // len(self.block_pattern)
 
     def layer_kinds(self) -> Tuple[Tuple[str, bool], ...]:
         """(kind, is_moe) per position within one repeating group."""

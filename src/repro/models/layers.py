@@ -8,6 +8,8 @@ Conventions:
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -96,11 +98,47 @@ def rope_freqs(head_dim: int, theta: float) -> np.ndarray:
     return 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
 
 
-def rope_cos_sin(positions: jax.Array, head_dim: int, theta: float):
-    """positions (..., T) int -> cos/sin (..., T, head_dim//2) f32."""
-    freqs = jnp.asarray(rope_freqs(head_dim, theta), jnp.float32)
-    ang = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.cos(ang), jnp.sin(ang)
+def yarn_mscale(scale: float, m: float) -> float:
+    """YaRN's attention temperature factor 0.1 m ln(scale) + 1."""
+    return 1.0 if scale <= 1 else 0.1 * m * math.log(scale) + 1.0
+
+
+def yarn_inv_freq(head_dim: int, theta: float, yarn) -> np.ndarray:
+    """DeepSeek-V2's YaRN frequencies: the plain RoPE frequencies blended
+    with the same over `yarn.factor` through a linear ramp between the
+    correction dims that `beta_fast` and `beta_slow` rotations give at
+    the original context length."""
+    def corr_dim(rot):
+        return (head_dim * math.log(yarn.original_max_position_embeddings
+                                    / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    extra = rope_freqs(head_dim, theta)
+    ramp = np.clip((np.arange(head_dim // 2) - low) / (high - low), 0, 1)
+    keep = 1.0 - ramp  # 1 where the plain frequency is kept
+    return extra / yarn.factor * (1.0 - keep) + extra * keep
+
+
+def rope_cos_sin(positions: jax.Array, head_dim: int, theta: float,
+                 yarn=None):
+    """positions (..., T) int -> cos/sin (..., T, head_dim//2) f32; with
+    `yarn` (a `config.YarnConfig`) the YaRN frequencies, and cos/sin
+    scaled by mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
+    if yarn is None:
+        freqs, scale = rope_freqs(head_dim, theta), 1.0
+    else:
+        freqs = yarn_inv_freq(head_dim, theta, yarn)
+        scale = (yarn_mscale(yarn.factor, yarn.mscale)
+                 / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+    ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(
+        freqs, jnp.float32)
+    if scale == 1.0:
+        return jnp.cos(ang), jnp.sin(ang)
+    return scale * jnp.cos(ang), scale * jnp.sin(ang)
 
 
 def mrope_cos_sin(positions: jax.Array, head_dim: int, theta: float, sections):

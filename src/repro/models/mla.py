@@ -38,6 +38,17 @@ def mla_init(key, cfg) -> dict:
     return p
 
 
+def softmax_scale(cfg) -> float:
+    """(q/k head dim)^-1/2, times mscale(factor, mscale_all_dim)^2 under
+    YaRN (HF `DeepseekV2Attention.softmax_scale`)."""
+    m = cfg.mla
+    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
+    if cfg.yarn is not None and cfg.yarn.mscale_all_dim:
+        scale *= layers.yarn_mscale(cfg.yarn.factor,
+                                    cfg.yarn.mscale_all_dim) ** 2
+    return scale
+
+
 def _queries(p, cfg, x, cos, sin):
     m = cfg.mla
     H = cfg.num_heads
@@ -71,7 +82,7 @@ def mla_forward(p: dict, cfg, x: jax.Array, cos, sin, *,
     k_nope = (c_kv @ p["wk_b"]).reshape(B, T, H, m.nope_head_dim)
     v = (c_kv @ p["wv_b"]).reshape(B, T, H, m.v_head_dim)
 
-    scale = 1.0 / jnp.sqrt(float(m.nope_head_dim + m.rope_head_dim))
+    scale = softmax_scale(cfg)
     s = jnp.einsum("bthe,bshe->bhts", q_nope.astype(jnp.float32), k_nope.astype(jnp.float32))
     s += jnp.einsum("bthe,bse->bhts", q_rope.astype(jnp.float32), k_rope.astype(jnp.float32))
     if cfg.act_constrain:
@@ -114,7 +125,7 @@ def mla_decode(p: dict, cfg, x: jax.Array, cache: dict, pos, cos, sin):
     s = jnp.einsum("bthr,bsr->bhts", q_lat, ckv.astype(jnp.float32))
     s += jnp.einsum("bthe,bse->bhts", q_rope.astype(jnp.float32),
                     krope.astype(jnp.float32))
-    scale = 1.0 / jnp.sqrt(float(m.nope_head_dim + m.rope_head_dim))
+    scale = softmax_scale(cfg)
     valid = jnp.arange(ckv.shape[1]) <= pos
     probs = jax.nn.softmax(
         jnp.where(valid[None, None, None, :], s * scale, NEG_INF), axis=-1

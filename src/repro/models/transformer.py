@@ -8,10 +8,22 @@ Block kinds: "attn" (GQA/MQA or MLA; + cross-attention for enc-dec),
 "mamba", "rwkv". Every non-rwkv block has an FFN slot (dense MLP or MoE
 according to cfg.moe_pattern); rwkv blocks embed their own channel-mix.
 
+`cfg.first_dense_layers` leading blocks (DeepSeek's
+`first_k_dense_replace`) run before the groups as a scan of their own,
+with a dense MLP of width `cfg.d_ff` ("dense" in the params and, where
+there are any, in the decode cache beside "blocks").
+
 Three entry points:
   forward(..., mode="train")    -> (logits, aux_loss)
   forward(..., mode="prefill")  -> (logits, aux_loss, cache)
   decode_step(...)              -> (logits, cache)   # one token
+and, for a federated client, `next_token_loss` / `next_token_eval`.
+
+The model's parts run under `jax.named_scope`s outside the round's
+"fl." ones: "model.mla" (latent attention), "model.moe" (routing,
+dispatch, combine and the shared experts), "model.moe_gmm" (the grouped
+expert products alone, inside "model.moe"), "model.mlp" (a dense FFN)
+and "model.head" (final norm, unembedding and loss).
 """
 from __future__ import annotations
 
@@ -26,6 +38,8 @@ from repro.models import attention, layers, mamba, mla, moe, rwkv6
 from repro.models.config import ModelConfig
 
 PyTree = Any
+
+DENSE_KINDS = (("attn", False),)  # the leading dense blocks' pattern
 
 
 # =================================================================== init
@@ -59,9 +73,10 @@ def _block_init(key, cfg, kind: str, is_moe: bool, cross: bool) -> dict:
     return p
 
 
-def _stack_init(key, cfg, *, cross: bool, num_groups: int) -> dict:
+def _stack_init(key, cfg, *, cross: bool, num_groups: int,
+                kinds=None) -> dict:
     """Stacked block params: {"p{i}": leaves with leading G axis}."""
-    kinds = cfg.layer_kinds()
+    kinds = kinds or cfg.layer_kinds()
     out = {}
     for i, (kind, is_moe) in enumerate(kinds):
         keys = jax.random.split(jax.random.fold_in(key, i), num_groups)
@@ -82,6 +97,10 @@ def init_params(key, cfg: ModelConfig) -> PyTree:
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = layers.dense_init(kh, cfg.d_model, cfg.vocab_size, cfg.jdtype)
+    if cfg.first_dense_layers:
+        params["dense"] = _stack_init(
+            jax.random.fold_in(key, 4), cfg, cross=cfg.encoder_layers > 0,
+            num_groups=cfg.first_dense_layers, kinds=DENSE_KINDS)
     if cfg.encoder_layers:
         # encoder is a plain full-attention stack (one group per layer pair)
         enc_groups = cfg.encoder_layers
@@ -115,7 +134,7 @@ def _rope_for(cfg, batch, B, T, offset=0):
             pos = jnp.broadcast_to(base[None], (3, B, T))
         return layers.mrope_cos_sin(pos, hd, cfg.rope_theta, cfg.mrope_sections)
     pos = jnp.arange(T)[None].repeat(B, 0) + offset
-    return layers.rope_cos_sin(pos, hd, cfg.rope_theta)
+    return layers.rope_cos_sin(pos, hd, cfg.rope_theta, cfg.yarn)
 
 
 # =============================================================== blocks
@@ -125,12 +144,14 @@ def _mixer(bp, cfg, kind, x, cos, sin, mode, cache, pos, window):
     """Dispatch one mixer. Returns (y, new_cache_or_None)."""
     if kind == "attn":
         if cfg.mla:
-            if mode == "decode":
-                return mla.mla_decode(bp["mixer"], cfg, x, cache, pos, cos, sin)
-            return mla.mla_forward(
-                bp["mixer"], cfg, x, cos, sin,
-                return_cache=(mode == "prefill"), max_len=cache,
-            )
+            with jax.named_scope("model.mla"):
+                if mode == "decode":
+                    return mla.mla_decode(bp["mixer"], cfg, x, cache, pos,
+                                          cos, sin)
+                return mla.mla_forward(
+                    bp["mixer"], cfg, x, cos, sin,
+                    return_cache=(mode == "prefill"), max_len=cache,
+                )
         if mode == "decode":
             return attention.attn_decode(
                 bp["mixer"], cfg, x, cache, pos, cos, sin, window=window
@@ -147,8 +168,10 @@ def _mixer(bp, cfg, kind, x, cos, sin, mode, cache, pos, window):
 
 
 def _block(bp, cfg, kind, is_moe, x, ctx, cache, mode):
-    """One block. Returns (x, new_cache, aux)."""
+    """One block. Returns (x, new_cache, aux, rows): `rows` counts the
+    token-expert pairs through the held experts' products."""
     aux = jnp.zeros((), jnp.float32)
+    rows = jnp.zeros((), jnp.int32)
     h = layers.norm_apply(bp["norm1"], x)
     if kind == "rwkv":
         st = cache if mode == "decode" else None
@@ -165,7 +188,7 @@ def _block(bp, cfg, kind, is_moe, x, ctx, cache, mode):
         new_cache = None
         if mode in ("prefill", "decode"):
             new_cache = dict(tm_state, cm_last=new_cm)
-        return x, new_cache, aux
+        return x, new_cache, aux, rows
 
     y, new_cache = _mixer(bp, cfg, kind, h, ctx["cos"], ctx["sin"], mode,
                           cache, ctx["pos"], ctx["window"])
@@ -184,20 +207,22 @@ def _block(bp, cfg, kind, is_moe, x, ctx, cache, mode):
                              cross_v=cache["cross_v"])
     hf = layers.norm_apply(bp["norm2"], x)
     if is_moe:
-        yf, aux = moe.moe_apply(bp["ffn"], cfg, hf)
+        with jax.named_scope("model.moe"):
+            yf, aux, rows = moe.moe_apply(bp["ffn"], cfg, hf)
     else:
-        yf = layers.mlp_apply(bp["ffn"], hf, cfg.mlp)
-    return x + yf, new_cache, aux
+        with jax.named_scope("model.mlp"):
+            yf = layers.mlp_apply(bp["ffn"], hf, cfg.mlp)
+    return x + yf, new_cache, aux, rows
 
 
-def _run_stack(blocks, cfg, x, ctx, mode, cache=None, *, encoder=False):
-    """Scan the stacked groups. Returns (x, aux, new_cache|None)."""
-    kinds = (("attn", False),) * 1 if encoder else cfg.layer_kinds()
-    if encoder:
-        kinds = (("attn", False),)
+def _run_stack(blocks, cfg, x, ctx, mode, cache=None, *, encoder=False,
+               kinds=None):
+    """Scan the stacked groups (of `kinds`, the config's pattern by
+    default). Returns (x, aux, rows, new_cache|None)."""
+    kinds = DENSE_KINDS if encoder else (kinds or cfg.layer_kinds())
 
     def group_body(carry, xs):
-        x, aux = carry
+        x, aux, rows = carry
         bp = xs[0] if isinstance(xs, tuple) else xs
         cache_g = xs[1] if isinstance(xs, tuple) else None
         new_cache_g = {}
@@ -217,25 +242,44 @@ def _run_stack(blocks, cfg, x, ctx, mode, cache=None, *, encoder=False):
                 hf = layers.norm_apply(sub["norm2"], x)
                 x = x + layers.mlp_apply(sub["ffn"], hf, cfg.mlp)
                 a = jnp.zeros((), jnp.float32)
+                r = jnp.zeros((), jnp.int32)
                 nc = None
             else:
-                x, nc, a = _block(sub, cfg, kind, is_moe, x, ctx, c_in, mode)
+                x, nc, a, r = _block(sub, cfg, kind, is_moe, x, ctx, c_in,
+                                     mode)
             aux = aux + a
+            rows = rows + r
             if nc is not None:
                 new_cache_g[f"p{i}"] = nc
         ys = new_cache_g if new_cache_g else jnp.zeros(())
-        return (x, aux), ys
+        return (x, aux, rows), ys
 
-    carry0 = (x, jnp.zeros((), jnp.float32))
+    carry0 = (x, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32))
     xs = blocks if mode != "decode" else (blocks, cache)
     body = group_body
     if mode == "train":
         # activation checkpointing per scanned group: O(G) residual stream
         # saves instead of O(G x per-layer activations) for the backward.
         body = jax.checkpoint(group_body)
-    (x, aux), ys = jax.lax.scan(body, carry0, xs)
+    (x, aux, rows), ys = jax.lax.scan(body, carry0, xs)
     new_cache = ys if mode in ("prefill", "decode") else None
-    return x, aux, new_cache
+    return x, aux, rows, new_cache
+
+
+def _trunk(params, cfg, x, ctx, mode, cache=None):
+    """The leading dense blocks, then the scanned groups. Returns (x, aux,
+    rows, new_cache|None); with dense blocks the cache is {"dense": ...,
+    "blocks": ...}."""
+    if not cfg.first_dense_layers:
+        return _run_stack(params["blocks"], cfg, x, ctx, mode, cache)
+    dense_c = cache["dense"] if mode == "decode" else None
+    blocks_c = cache["blocks"] if mode == "decode" else None
+    x, a1, r1, c1 = _run_stack(params["dense"], cfg, x, ctx, mode, dense_c,
+                               kinds=DENSE_KINDS)
+    x, a2, r2, c2 = _run_stack(params["blocks"], cfg, x, ctx, mode, blocks_c)
+    new_cache = ({"dense": c1, "blocks": c2}
+                 if mode in ("prefill", "decode") else None)
+    return x, a1 + a2, r1 + r2, new_cache
 
 
 # ================================================================ public
@@ -270,15 +314,16 @@ def forward(params, cfg: ModelConfig, batch, *, mode: str = "train",
         enc = batch["enc_embeds"].astype(x.dtype)  # stub frame embeddings
         ectx = {"cos": None, "sin": None, "pos": None, "window": 0,
                 "enc": None, "max_len": 0}
-        enc, _, _ = _run_stack(params["encoder"]["blocks"], cfg, enc, ectx,
-                               "train", encoder=True)
+        enc, _, _, _ = _run_stack(params["encoder"]["blocks"], cfg, enc,
+                                  ectx, "train", encoder=True)
         enc = layers.norm_apply(params["encoder"]["final_norm"], enc)
 
     ctx = {"cos": cos, "sin": sin, "pos": None, "window": cfg.sliding_window,
            "enc": enc, "max_len": max(max_len, T)}
-    x, aux, cache = _run_stack(params["blocks"], cfg, x, ctx, mode)
-    x = layers.norm_apply(params["final_norm"], x)
-    logits = unembed(params, cfg, x)
+    x, aux, _, cache = _trunk(params, cfg, x, ctx, mode)
+    with jax.named_scope("model.head"):
+        x = layers.norm_apply(params["final_norm"], x)
+        logits = unembed(params, cfg, x)
     if mode == "prefill":
         return logits, aux, cache
     return logits, aux, text_offset
@@ -292,6 +337,12 @@ def unembed(params, cfg, x):
 
 def hidden_forward(params, cfg: ModelConfig, batch):
     """Forward up to the final norm, WITHOUT the unembed projection."""
+    x, aux, _, text_offset = _hidden_and_rows(params, cfg, batch)
+    return x, aux, text_offset
+
+
+def _hidden_and_rows(params, cfg: ModelConfig, batch):
+    """`hidden_forward`, with the expert-row count beside the aux loss."""
     x, text_offset = embed_inputs(params, cfg, batch)
     B, T = x.shape[0], x.shape[1]
     cos, sin = _rope_for(cfg, batch, B, T)
@@ -302,13 +353,15 @@ def hidden_forward(params, cfg: ModelConfig, batch):
         enc = batch["enc_embeds"].astype(x.dtype)
         ectx = {"cos": None, "sin": None, "pos": None, "window": 0,
                 "enc": None, "max_len": 0}
-        enc, _, _ = _run_stack(params["encoder"]["blocks"], cfg, enc, ectx,
-                               "train", encoder=True)
+        enc, _, _, _ = _run_stack(params["encoder"]["blocks"], cfg, enc,
+                                  ectx, "train", encoder=True)
         enc = layers.norm_apply(params["encoder"]["final_norm"], enc)
     ctx = {"cos": cos, "sin": sin, "pos": None, "window": cfg.sliding_window,
            "enc": enc, "max_len": T}
-    x, aux, _ = _run_stack(params["blocks"], cfg, x, ctx, "train")
-    return layers.norm_apply(params["final_norm"], x), aux, text_offset
+    x, aux, rows, _ = _trunk(params, cfg, x, ctx, "train")
+    with jax.named_scope("model.head"):
+        x = layers.norm_apply(params["final_norm"], x)
+    return x, aux, rows, text_offset
 
 
 def _chunked_ce(params, cfg, x_pred, labels):
@@ -356,6 +409,29 @@ def loss_fn(params, cfg: ModelConfig, batch):
     return ce + aux
 
 
+def next_token_loss(params, cfg: ModelConfig, x, y):
+    """Mean next-token cross-entropy of ids `x` (B, T) against their
+    targets `y` (B, T), plus the MoE balance loss. Returns (loss, rows),
+    `rows` the token-expert pairs through the held experts' products."""
+    h, aux, rows, _ = _hidden_and_rows(params, cfg, {"tokens": x})
+    with jax.named_scope("model.head"):
+        ce = layers.softmax_cross_entropy(unembed(params, cfg, h), y)
+    return ce + aux, rows
+
+
+def next_token_eval(params, cfg: ModelConfig, x, y):
+    """(correct argmax predictions, summed cross-entropy, rows) of ids
+    `x` (B, T) against their targets `y`."""
+    h, _, rows, _ = _hidden_and_rows(params, cfg, {"tokens": x})
+    with jax.named_scope("model.head"):
+        logits = unembed(params, cfg, h).astype(jnp.float32)
+        nll = jax.nn.logsumexp(logits, axis=-1) - jnp.sum(
+            jnp.where(jnp.arange(logits.shape[-1]) == y[..., None], logits,
+                      0.0), axis=-1)
+        correct = jnp.sum((jnp.argmax(logits, axis=-1) == y).astype(jnp.int32))
+    return correct, jnp.sum(nll), rows
+
+
 def decode_step(params, cfg: ModelConfig, token, cache, pos, batch_extras=None):
     """One-token decode. token (B,1) int32; pos scalar int32 absolute position.
 
@@ -371,17 +447,28 @@ def decode_step(params, cfg: ModelConfig, token, cache, pos, batch_extras=None):
         cos, sin = _rope_for(cfg, batch, B, 1, offset=pos)
     ctx = {"cos": cos, "sin": sin, "pos": pos, "window": cfg.sliding_window,
            "enc": None, "max_len": 0}
-    x, _, cache = _run_stack(params["blocks"], cfg, x, ctx, "decode", cache)
-    x = layers.norm_apply(params["final_norm"], x)
-    return unembed(params, cfg, x), cache
+    x, _, _, cache = _trunk(params, cfg, x, ctx, "decode", cache)
+    with jax.named_scope("model.head"):
+        x = layers.norm_apply(params["final_norm"], x)
+        return unembed(params, cfg, x), cache
 
 
 def init_cache(cfg: ModelConfig, B: int, max_len: int) -> PyTree:
     """Zero-initialized decode cache (leaves stacked over groups)."""
-    G = cfg.num_pattern_groups
+    blocks = _stack_cache(cfg, B, max_len, cfg.num_pattern_groups,
+                          cfg.layer_kinds())
+    if not cfg.first_dense_layers:
+        return blocks
+    return {"dense": _stack_cache(cfg, B, max_len, cfg.first_dense_layers,
+                                  DENSE_KINDS),
+            "blocks": blocks}
+
+
+def _stack_cache(cfg: ModelConfig, B: int, max_len: int, G: int,
+                 kinds) -> PyTree:
     S = min(cfg.sliding_window, max_len) if cfg.sliding_window else max_len
     out = {}
-    for i, (kind, _) in enumerate(cfg.layer_kinds()):
+    for i, (kind, _) in enumerate(kinds):
         if kind == "attn":
             if cfg.mla:
                 m = cfg.mla

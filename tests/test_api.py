@@ -30,6 +30,7 @@ from repro.data import synthetic
 def test_facade_all_is_pinned():
     assert repro.__all__ == [
         "CSVSink",
+        "ClientModel",
         "FLConfig",
         "FedServer",
         "History",
@@ -39,6 +40,7 @@ def test_facade_all_is_pinned():
         "SpanTimer",
         "fixed_arrival_schedule",
         "init_round_state",
+        "lm_client",
         "make_mesh",
         "make_round_fn",
         "run_manifest",

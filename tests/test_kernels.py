@@ -319,3 +319,25 @@ def test_kernel_micro_bench_runs_tiny(capsys):
                      for k in ("grad_dot", "weighted_agg", "round_stats")
                      for v in ("pallas_interp", "xla_ref")], names
     assert all(np.isfinite(float(ln.split(",")[1])) for ln in lines)
+
+
+@pytest.mark.parametrize("shape", [(3, 40, 136), (2, 20, 64), (300, 96),
+                                   (4, 7), (50,)])
+def test_leaf_stats(shape):
+    """One leaf's delta statistics, (<p - q, g>, ||p - q||^2, ||g||^2),
+    in row blocks of the leaf's own layout: a ragged last block, a last
+    dim narrower than a lane row, and the XLA path of small leaves."""
+    from repro.kernels import round_stats
+
+    rng = np.random.default_rng(0)
+    p, q, g = (rng.normal(size=shape).astype(np.float32) for _ in range(3))
+    round_stats.LEAF_BLOCK_ELEMS, keep = 1024, round_stats.LEAF_BLOCK_ELEMS
+    try:
+        got = round_stats.leaf_stats(jnp.asarray(p), jnp.asarray(q),
+                                     jnp.asarray(g), min_kernel_elems=0)
+    finally:
+        round_stats.LEAF_BLOCK_ELEMS = keep
+    d = p.astype(np.float64) - q
+    gf = g.astype(np.float64)
+    want = [np.sum(d * gf), np.sum(d * d), np.sum(gf * gf)]
+    np.testing.assert_allclose([float(v) for v in got], want, rtol=1e-5)

@@ -397,8 +397,10 @@ def test_run_counts_its_host_spans(mode, rounds, block, want):
     ("flat", {}, SCOPES),
     ("tree", {}, SCOPES),
     ("flat", {"aggregation": "buffered", "buffer_m": 2}, SCOPES),
+    # the sequential round ships no wire: it streams each delta's
+    # statistics leaf by leaf, with no raveled uplink buffer
     ("tree", {"mode": "sequential", "transport": "f32", "downlink": "f32"},
-     SCOPES - {"fl.downlink"}),
+     SCOPES - {"fl.downlink", "fl.uplink"}),
 ], ids=["flat", "tree", "buffered", "sequential"])
 def test_round_phases_are_named_scopes_in_the_hlo(engine, extra, want):
     """Each phase scope reaches the `op_name` metadata of the lowered step

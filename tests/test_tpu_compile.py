@@ -8,7 +8,9 @@ attached) v5e chip at the paper CNN's width, N = 1,663,370, for one
 client chunk (K=10) and a ragged second chunk (K=33), and check that the
 compiled program holds the Mosaic kernel (`tpu_custom_call`). They also
 compile the paper cell's local update (K=10 CNN clients, B=32, 18 steps)
-and read the layouts XLA chose for its 28x28 stage.
+and read the layouts XLA chose for its 28x28 stage, and the sequential
+round's `leaf_stats` at DeepSeek-V2-Lite's leaf widths, where it must read
+each leaf in place (no copy of it in the compiled program).
 """
 import os
 import re
@@ -135,3 +137,21 @@ def test_cnn_clients_local_update_keeps_clients_on_lanes(one_chip):
     pools = [layouts[operand] for operand in re.findall(
         rf"= f32\[{b},14,14,{k * 32}\]\S* reduce\(%([\w.-]+),", text)]
     assert pools and all(m == k * 32 for _, m in pools), pools
+
+
+# leaves of the dsv2lite.silo chip share, layer-stacked: routed experts,
+# MLA's q projection, the vocabulary slice. (Where the last dim is not a
+# multiple of 128, as the dense MLP's 10,944 and the kv projection's 576,
+# the compiler may give the argument another layout and copy it.)
+LEAVES = [(4, 8, 2048, 1408), (4, 8, 1408, 2048), (5, 2048, 3072),
+          (12800, 2048), (2048, 12800)]
+
+
+@pytest.mark.parametrize("shape", LEAVES, ids=["x".join(map(str, s))
+                                               for s in LEAVES])
+def test_leaf_stats_compiles_in_place_for_v5e(one_chip, shape):
+    arg = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    text = jax.jit(lambda p, q, g: round_stats.leaf_stats(
+        p, q, g, interpret=False)).lower(arg, arg, arg).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"= f32\[[\d,]+\][^ ]* (copy|pad|transpose)\(", text)
