@@ -14,14 +14,23 @@ want a 3-D grid streaming K/V from HBM (left as the documented next step —
 the q-chunked jnp path in models/attention.py already covers that regime).
 
 Validated against ref.flash_attention (pure jnp) in interpret mode.
+
+`causal_attention` is the other entry: causal attention fused forward AND
+backward, with separate q/k and v head dims and any softmax scale (MLA's
+train, eval and prefill core). It runs JAX's splash attention kernels
+under a causal mask: scores and probabilities stay in VMEM in the
+forward, the dkv and dq kernels alike, and tiles above the diagonal are
+skipped.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
 from repro.kernels import interpret_mode
 
@@ -138,3 +147,54 @@ def gqa_flash(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
     o = flash_attention(flat(q), flat(kx), flat(vx), causal, blk_q, blk_k,
                         interpret)
     return jnp.moveaxis(o.reshape(B, H, T, hd), 1, 2)
+
+
+# (q, kv) tiles of the causal kernel's forward and of its backward's dkv
+# and dq kernels, swept on a v5e at T = 2,048; a sequence takes the
+# largest tile of each that divides it. (Splash's fused backward, which
+# computes dq inside the dkv kernel, was faster there but writes each key
+# tile's share of dq in bfloat16 and sums the shares after: a rounding
+# the einsum core does not make.)
+CAUSAL_TILES = {"fwd": (512, 512), "dkv": (1024, 1024), "dq": (1024, 1024)}
+LANES = 128  # keys lie on the lanes of a score tile
+
+
+def causal_blocks(T: int) -> splash.BlockSizes | None:
+    """The causal kernel's tiles at sequence length T, or None where T is
+    not a multiple of 128, which the kernel cannot tile."""
+    if T % LANES:
+        return None
+    (fq, fk), (kq, kk), (dq, dk) = (
+        tuple(math.gcd(T, b) for b in CAUSAL_TILES[n])
+        for n in ("fwd", "dkv", "dq"))
+    return splash.BlockSizes(
+        block_q=fq, block_kv=fk, block_kv_compute=fk,
+        block_q_dkv=kq, block_kv_dkv=kk, block_kv_dkv_compute=kk,
+        block_q_dq=dq, block_kv_dq=dk)
+
+
+def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, scale: float,
+                     *, interpret=None) -> jax.Array:
+    """softmax(scale * q k^T, causal) v, fused forward and backward.
+
+    q, k: (B, H, T, d_qk); v: (B, H, T, d_v); returns (B, H, T, d_v) in
+    q's dtype. T must be a multiple of 128 (`causal_blocks`). q is scaled
+    in its own dtype; then q, k and v enter the products as XLA's default
+    precision takes an f32 product's on this backend (bfloat16 on a TPU,
+    the inputs' dtype elsewhere). Products accumulate in f32, and the
+    running max, sum and logsumexp are f32. The backward's dkv and dq
+    kernels recompute the probabilities tile by tile from the forward's
+    logsumexp and accumulate dk, dv and dq in f32. The output and dq, dk,
+    dv leave the kernels in the operands' dtype (bfloat16 on a TPU) and
+    are cast back to q's, k's and v's: one rounding of each finished
+    value, per head.
+    """
+    B, H, T, _ = q.shape
+    dtype = jnp.bfloat16 if jax.default_backend() == "tpu" else q.dtype
+    mask = splash.MultiHeadMask([splash.CausalMask((T, T))] * H)
+    kernel = splash.make_splash_mha(
+        mask, block_sizes=causal_blocks(T), head_shards=1, q_seq_shards=1,
+        interpret=interpret_mode(interpret))
+    out = jax.vmap(kernel)((q * scale).astype(dtype), k.astype(dtype),
+                           v.astype(dtype))
+    return out.astype(q.dtype)

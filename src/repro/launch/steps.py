@@ -170,8 +170,8 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, shape: shapes_mod.InputShape,
             ]
             return jax.tree.unflatten(treedef, out)
 
-    round_fn = fl_mod.make_round_fn(loss, flcfg, delta_constraint, angle_pred,
-                                    grad_constraint)
+    round_fn = sharding.traced_on(mesh, fl_mod.make_round_fn(
+        loss, flcfg, delta_constraint, angle_pred, grad_constraint))
     if fl_mode == "parallel":
         b_shard = sharding.shard_batch_dim(mesh, batch_sds, default_dim=0)
     else:
@@ -219,6 +219,7 @@ def build_prefill_step(cfg: ModelConfig, mesh: Mesh, shape: shapes_mod.InputShap
     if fsdp is None:
         fsdp = cfg.param_count() > SEQUENTIAL_THRESHOLD
 
+    @functools.partial(sharding.traced_on, mesh)
     def prefill_step(params, batch):
         logits, aux, cache = transformer.forward(
             params, cfg, batch, mode="prefill", max_len=T

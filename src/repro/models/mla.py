@@ -11,9 +11,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import flash_attn
 from repro.models import layers
+from repro.models import sharding as shmod
 
 NEG_INF = -1e30
+ATTN_SCOPE = "model.mla_attn"  # the fused attention core alone
 
 
 def mla_init(key, cfg) -> dict:
@@ -71,9 +74,49 @@ def _latents(p, cfg, x, cos, sin):
     return c_kv, k_rope
 
 
+def _einsum_core(cfg, q_nope, q_rope, k_nope, k_rope, v, scale):
+    """Causal attention as f32 einsums over materialized (B, H, T, T)
+    scores; under `act_constrain` the partitioner splits them by head."""
+    T = q_nope.shape[1]
+    s = jnp.einsum("bthe,bshe->bhts", q_nope.astype(jnp.float32), k_nope.astype(jnp.float32))
+    s += jnp.einsum("bthe,bse->bhts", q_rope.astype(jnp.float32), k_rope.astype(jnp.float32))
+    if cfg.act_constrain:
+        s = shmod.constrain(s, "batch", "model", None, None)
+    mask = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
+    probs = jax.nn.softmax(jnp.where(mask, s * scale, NEG_INF), axis=-1)
+    out = jnp.einsum("bhts,bshe->bthe", probs, v.astype(jnp.float32))
+    if cfg.act_constrain:
+        out = shmod.constrain(out, "batch", None, "model", None)
+    return out
+
+
+def _fused_core(q_nope, q_rope, k_nope, k_rope, v, scale):
+    """The same attention as one fused causal kernel, forward and
+    backward, under `ATTN_SCOPE`: q and k are the nope and RoPE parts
+    side by side, the shared RoPE key broadcast over the heads. On a TPU
+    each head's share of the RoPE key's gradient leaves the kernel in
+    bfloat16 and the heads are summed in f32 after; the einsum core sums
+    them inside one f32 accumulation."""
+    B, T, H, _ = q_nope.shape
+    q = jnp.concatenate([q_nope, q_rope], -1)
+    k_rope = jnp.broadcast_to(k_rope[:, :, None], (B, T, H, k_rope.shape[-1]))
+    k = jnp.concatenate([k_nope, k_rope], -1)
+
+    def heads(t):  # (B, T, H, d) <-> (B, H, T, d)
+        return t.transpose(0, 2, 1, 3)
+
+    with jax.named_scope(ATTN_SCOPE):
+        out = flash_attn.causal_attention(heads(q), heads(k), heads(v), scale)
+    return heads(out)
+
+
 def mla_forward(p: dict, cfg, x: jax.Array, cos, sin, *,
                 return_cache: bool = False, max_len: int = 0):
-    """Train/prefill: expanded (non-absorbed) attention over the sequence."""
+    """Train/prefill: expanded (non-absorbed) attention over the sequence.
+    The attention core runs fused (`_fused_core`) where the kernel tiles
+    T and the step runs on one device, else as einsums (`_einsum_core`),
+    which the partitioner can split: on a mesh (`sharding.traced_on`) or
+    where `act_constrain` asks it to shard the scores."""
     m = cfg.mla
     B, T, _ = x.shape
     H = cfg.num_heads
@@ -83,17 +126,11 @@ def mla_forward(p: dict, cfg, x: jax.Array, cos, sin, *,
     v = (c_kv @ p["wv_b"]).reshape(B, T, H, m.v_head_dim)
 
     scale = softmax_scale(cfg)
-    s = jnp.einsum("bthe,bshe->bhts", q_nope.astype(jnp.float32), k_nope.astype(jnp.float32))
-    s += jnp.einsum("bthe,bse->bhts", q_rope.astype(jnp.float32), k_rope.astype(jnp.float32))
-    if cfg.act_constrain:
-        from repro.models import sharding as shmod
-
-        s = shmod.constrain(s, "batch", "model", None, None)
-    mask = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
-    probs = jax.nn.softmax(jnp.where(mask, s * scale, NEG_INF), axis=-1)
-    out = jnp.einsum("bhts,bshe->bthe", probs, v.astype(jnp.float32))
-    if cfg.act_constrain:
-        out = shmod.constrain(out, "batch", None, "model", None)
+    if (cfg.act_constrain or shmod.step_devices() > 1
+            or flash_attn.causal_blocks(T) is None):
+        out = _einsum_core(cfg, q_nope, q_rope, k_nope, k_rope, v, scale)
+    else:
+        out = _fused_core(q_nope, q_rope, k_nope, k_rope, v, scale)
     y = out.reshape(B, T, H * m.v_head_dim).astype(x.dtype) @ p["wo"]
 
     cache = None

@@ -12,6 +12,8 @@ muddies the roofline numbers).
 """
 from __future__ import annotations
 
+import contextvars
+import functools
 from typing import Any, Optional
 
 import jax
@@ -142,6 +144,29 @@ def replicated(mesh: Mesh, tree: PyTree):
 
 
 _CONSTRAINT_MESH: list = [None]
+_STEP_DEVICES = contextvars.ContextVar("step_devices", default=1)
+
+
+def traced_on(mesh, fn):
+    """`fn`, which reads `mesh`'s device count through `step_devices`
+    while it is traced. A step jitted over a mesh shows model code no
+    sharding (its tracers carry none under Auto axes), so a builder that
+    lays a step on a mesh wraps it in this."""
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        token = _STEP_DEVICES.set(mesh.size)
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            _STEP_DEVICES.reset(token)
+
+    return traced
+
+
+def step_devices() -> int:
+    """Devices of the mesh the step being traced is laid on (1 outside
+    `traced_on`)."""
+    return _STEP_DEVICES.get()
 
 
 def set_constraint_mesh(mesh) -> None:

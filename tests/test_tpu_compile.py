@@ -10,19 +10,24 @@ compiled program holds the Mosaic kernel (`tpu_custom_call`). They also
 compile the paper cell's local update (K=10 CNN clients, B=32, 18 steps)
 and read the layouts XLA chose for its 28x28 stage, and the sequential
 round's `leaf_stats` at DeepSeek-V2-Lite's leaf widths, where it must read
-each leaf in place (no copy of it in the compiled program).
+each leaf in place (no copy of it in the compiled program), and MLA's
+fused causal attention, forward and backward, at that cell's shape, and
+in a prefill step laid on one chip and on a (2, 2) mesh.
 """
 import os
 import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
 from repro import transport
+from repro.configs import registry, shapes
 from repro.core import fl
-from repro.kernels import round_stats, weighted_agg
+from repro.kernels import flash_attn, round_stats, weighted_agg
+from repro.launch import steps
 from repro.models import small
 
 N = 1_663_370  # models/small.py CNN parameter count
@@ -155,3 +160,44 @@ def test_leaf_stats_compiles_in_place_for_v5e(one_chip, shape):
         p, q, g, interpret=False)).lower(arg, arg, arg).compile().as_text()
     assert "tpu_custom_call" in text
     assert not re.search(r"= f32\[[\d,]+\][^ ]* (copy|pad|transpose)\(", text)
+
+
+def test_mla_causal_attention_compiles_for_v5e(one_chip):
+    """The fused core at dsv2lite.silo's shape (B=2, 16 heads, T=2,048,
+    q/k heads 192, v heads 128, bf16 operands): the forward and the
+    backward's dkv and dq kernels, with no (T, T) array left."""
+    B, H, T = 2, 16, 2048
+
+    def shape(d, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((B, H, T, d), dt, sharding=one_chip)
+
+    def loss(q, k, v, w):
+        out = flash_attn.causal_attention(q, k, v, 0.1147, interpret=False)
+        return jnp.sum(out.astype(jnp.float32) * w)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(192), shape(192), shape(128),
+        shape(128, jnp.float32)).compile().as_text()
+    for kernel in ("fwd", "dkv", "dq"):
+        assert re.search(rf"%splash_mha_{kernel}\w*\.?\d* = .*"
+                         r'custom_call_target="tpu_custom_call"', text), kernel
+    assert not re.search(rf"\[[\d,]*{T},{T}\]", text)
+
+
+@pytest.mark.parametrize("dims", [(1, 1), (2, 2)])
+def test_mla_prefill_compiles_for_v5e_meshes(topo, dims, monkeypatch):
+    """DeepSeek-V2-Lite's smoke prefill at T = 256, its kernels in
+    Mosaic: on one chip attention runs the fused core's kernels; on a
+    (2, 2) data x model mesh it runs as einsums, since the partitioner
+    refuses to split a Mosaic call."""
+    monkeypatch.setattr(flash_attn, "interpret_mode", lambda interpret: False)
+    mesh = Mesh(np.array(topo.devices[:dims[0] * dims[1]]).reshape(dims),
+                ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+    fn, args, ins, outs, _ = steps.build_prefill_step(
+        registry.get("deepseek-v2-lite-16b-smoke"), mesh,
+        shapes.InputShape("prefill", 256, 4, "prefill"))
+    text = jax.jit(fn, in_shardings=ins, out_shardings=outs).lower(
+        *args).compile().as_text()
+    fused = re.search(r"%splash_mha_fwd\w*\.?\d* = .*"
+                      r'custom_call_target="tpu_custom_call"', text)
+    assert bool(fused) == (dims == (1, 1))
