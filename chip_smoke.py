@@ -180,14 +180,14 @@ class Smoke:
         evaluation of Eqs. 1, 8, 9 and 11 on the same dequantized wire.
         Round 1: no smoothing history, equal data sizes."""
         from repro import transport
-        from repro.core import fl_shard_map
+        from repro.core import fl_shard_map, weighting
 
         kb, kx = jax.random.split(jax.random.key(7))
         spread = jnp.linspace(0.5, 3.0, k)[:, None]  # a range of angles
         x = 1e-3 * (jax.random.normal(kb, (n,))
                     + spread * jax.random.normal(kx, (k, n)))
         if up == "f32":
-            wire, ref = (x,), x
+            wire, ref = (x, None), x
         else:
             q = transport.quantize(x, up)
             wire, ref = (q.values, q.scales), transport.dequantize(q)
@@ -199,12 +199,14 @@ class Smoke:
                            constant_values=fill)
 
         rows = fl_shard_map.flat_client_sharding(mesh)
-        wire = tuple(jax.device_put(pad(a, fill), rows)
+        wire = tuple(a if a is None else jax.device_put(pad(a, fill), rows)
                      for a, fill in zip(wire, (0.0, 1.0)))
         sizes = jnp.full((k,), 600.0, jnp.float32)
-        ops = fl_shard_map.make_round_ops(mesh, alpha=alpha, transport=up)
+        ops = fl_shard_map.make_round_ops(
+            mesh, weigh=lambda t, d: weighting.fedadp_weights(t, d, alpha),
+            transport=up)
         g, dots, sqs, sqg, delta, theta, _, w = jax.jit(ops)(
-            *wire, pad(sizes / jnp.sum(sizes)), jnp.ones((n,), jnp.float32),
+            *wire, jnp.ones((n,), jnp.float32), pad(sizes / jnp.sum(sizes)),
             jnp.zeros((kp,), jnp.float32), jnp.zeros((kp,), jnp.int32),
             pad(sizes))
 

@@ -45,6 +45,7 @@ whole training run into a single `lax.scan` with the state as the carry.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, NamedTuple, Optional
 
@@ -56,7 +57,7 @@ from repro.core import fl_shard_map, treemath, weighting
 from repro.core import buffer as buffer_mod
 from repro.core.weighting import AngleState
 from repro.kernels import round_stats as round_stats_mod
-from repro.kernels import weighted_agg as weighted_agg_mod
+from repro.models import sharding as models_sharding
 
 PyTree = Any
 
@@ -72,35 +73,33 @@ class FLConfig:
     lr_decay: float = 0.995  # per communication round (paper Sec. V)
     mode: str = "parallel"  # parallel | sequential
     stale_angles: bool = False  # sequential one-pass variant
-    # parallel-mode execution engine:
+    # parallel-mode execution engine: how the round's one FedAdp
+    # aggregation step (`_engine_step`: Eq. 8 stats against the FedAvg
+    # reference g, Eq. 9, the weights, the weighted sum) runs. The sync
+    # round and the buffered flush share it.
     #   "tree" — per-leaf treemath reductions (reference; keeps sharded
     #            leaves sharded, the right trade on a model-sharded mesh)
-    #   "flat" — deltas raveled once into a contiguous (K, N) f32 buffer;
-    #            angle stats + aggregation run as single-HBM-pass Pallas
-    #            kernels (round_stats / weighted_agg). The client axis is
-    #            CHUNKED inside the kernels (<= kernels.weighted_agg.K_TILE
-    #            clients per VMEM tile), so any K is supported — there is
-    #            no MAX_K ceiling.
-    #   "flat_sharded" — the flat buffer row-sharded over the mesh client
-    #            axis ("pod","data"); the WHOLE round (per-shard kernel
-    #            calls, stat psums, replicated weighting, aggregate psum)
-    #            is one shard_map region via fl_shard_map.make_round_ops.
-    #            Requires passing `mesh=` to make_round_fn; any
-    #            clients_per_round works (K % shards != 0 zero-pads the
-    #            client axis — padded rows get exactly zero weight).
-    #            On a 2D (client x model) mesh — a "model" axis of size
-    #            > 1 — the buffer becomes a (client x model) grid of
-    #            (K_loc, N_loc) tiles (fl_shard_map.make_round_ops_2d):
-    #            each device ravels its LOCAL model-shard leaf blocks
-    #            (no all-gather), quantizes them shard-locally (scale
-    #            chunks never straddle a model-axis split — the 2D wire
-    #            layout), and the aggregated delta keeps model-sharded
-    #            leaves sharded. The tree engine on the same mesh
-    #            consumes the identical shard-local wire via a blocked
-    #            quantize->dequantize roundtrip, so tree and flat still
-    #            agree to 1e-5 per transport. error_feedback is
-    #            incompatible with a quantized 2D wire (the residual is
-    #            a global tree-ravel-order buffer) and raises.
+    #   "flat" — deltas raveled once into a contiguous (K, N) buffer in
+    #            the wire dtype; stats and aggregation run as single-HBM-
+    #            pass Pallas kernels (fl_shard_map.wire_kernels picks them
+    #            by wire). The client axis is CHUNKED inside the kernels,
+    #            so any K is supported.
+    #   "flat_sharded" — the same kernels in ONE shard_map region
+    #            (fl_shard_map.make_round_ops: per-shard kernel calls,
+    #            stat psums, replicated Eq. 9 and weights, aggregate psum)
+    #            over the buffer row-sharded on the mesh client axis
+    #            ("pod","data"). Requires `mesh=` in make_round_fn; K not
+    #            divisible by the client axis is zero-padded (padded rows
+    #            get exactly zero weight). On a 2D (client x model) mesh
+    #            the buffer is a grid of (K_loc, N_loc) tiles: each device
+    #            ravels its LOCAL leaf blocks and quantizes them
+    #            shard-locally (scale chunks never straddle a model-axis
+    #            split — the 2D wire layout), and the aggregate keeps
+    #            model-sharded leaves sharded. The tree engine on such a
+    #            mesh reads the same shard-local wire, dequantized, so the
+    #            engines agree to 1e-5 per transport. error_feedback is
+    #            incompatible with a quantized 2D wire (the residual is in
+    #            global ravel order) and raises.
     # The sequential mode's pass-2 statistics stream through the
     # round_stats module's `leaf_stats` kernel, one call per leaf of each
     # client's trained and starting params against the global delta.
@@ -714,19 +713,26 @@ def local_update_path(loss_fn: Callable, fl: FLConfig, mesh=None) -> str:
 
 
 def _local_train(loss_fn, path: str, params, batches, lr, fl: FLConfig,
-                 grad_constraint):
+                 grad_constraint, delta_constraint=None):
     """The parallel round's local updates of the K clients, all from
-    `params`, by the path `local_update_path` chose."""
-    if path == "batched":
-        k = jax.tree.leaves(batches)[0].shape[0]
-        stacked = jax.tree.map(
-            lambda p: jnp.broadcast_to(p, (k, *p.shape)), params)
-        return local_update_clients(loss_fn.clients, stacked, batches, lr,
-                                    fl.prox_mu, grad_constraint)
-    return jax.vmap(
-        lambda b: local_update(loss_fn, params, b, lr, fl.prox_mu,
-                               grad_constraint)
-    )(batches)
+    `params`, by the path `local_update_path` chose; `delta_constraint`
+    then places the stacked deltas. Returns (deltas, losses)."""
+    with jax.named_scope("fl.local_train"):
+        if path == "batched":
+            k = jax.tree.leaves(batches)[0].shape[0]
+            stacked = jax.tree.map(
+                lambda p: jnp.broadcast_to(p, (k, *p.shape)), params)
+            deltas, losses = local_update_clients(
+                loss_fn.clients, stacked, batches, lr, fl.prox_mu,
+                grad_constraint)
+        else:
+            deltas, losses = jax.vmap(
+                lambda b: local_update(loss_fn, params, b, lr, fl.prox_mu,
+                                       grad_constraint)
+            )(batches)
+        if delta_constraint is not None:
+            deltas = delta_constraint(deltas)
+    return deltas, losses
 
 
 def angle_keep_list(params: PyTree, pred: Callable) -> list:
@@ -800,22 +806,29 @@ def make_round_fn(loss_fn: Callable, fl: FLConfig,
     `state` is a `RoundState` (see `init_round_state`) and is threaded
     IDENTICALLY through every engine — params, Eq. 9 angle state, the
     previous aggregated delta, both EF residuals, the per-client
-    broadcast state (downlink_delta), the device RNG key (untouched here; the driver's
-    data pipeline owns it), and the round counter (incremented here; it
-    drives the lr schedule). batches leaves: (K, tau, B, ...); sel_idx
-    (K,) int32 population slots; data_sizes (K,) f32.
-    `delta_constraint` optionally applies sharding constraints to the
-    stacked deltas (parallel mode). `mesh` is required by
-    engine="flat_sharded" (the client axis of the flat buffer is sharded
-    over the mesh's ("pod","data") axes; K not divisible by the client
-    axis is zero-padded before sharding). If the mesh also has a "model"
-    axis of size > 1, the flat buffer becomes a 2D (client x model) tile
-    grid — model-sharded leaves (models/sharding.param_pspecs rules)
-    ravel shard-locally, quantization chunks are shard-local, and the
-    aggregate keeps sharded leaves sharded; the TREE engine on such a
-    mesh routes quantized transports through the same shard-local wire
-    (fl_shard_map.make_blocked_roundtrip), so engine equivalence holds
-    per transport on the 2D mesh too. Otherwise `mesh` is ignored.
+    broadcast state (downlink_delta), the device RNG key (untouched here;
+    the driver's data pipeline owns it), and the round counter
+    (incremented here; it drives the lr schedule). batches leaves:
+    (K, tau, B, ...); sel_idx (K,) int32 population slots; data_sizes (K,)
+    f32. `delta_constraint` optionally applies sharding constraints to
+    the stacked deltas (parallel mode).
+
+    A parallel round is built from shared phases — the state check, the
+    downlink broadcast, local training, the uplink compression with error
+    feedback, the engine's aggregation step and the core metrics — and
+    `fl.engine` picks that step once, here (`_engine_step`): per-leaf
+    reductions ("tree"), the fused kernels over the (K, N) wire buffer
+    ("flat"), or those kernels in one shard_map region over the mesh
+    (`fl_shard_map.make_round_ops`, "flat_sharded", which needs `mesh`:
+    the buffer's client axis shards over ("pod","data"), zero-padded to
+    the axis size). If the mesh also has a "model" axis of size > 1, the
+    buffer becomes a 2D (client x model) tile grid: model-sharded leaves
+    (models/sharding.param_pspecs rules) ravel shard-locally, quantization
+    chunks are shard-local, and the aggregate keeps sharded leaves
+    sharded; the TREE engine on such a mesh routes quantized transports
+    through the same shard-local wire (fl_shard_map.make_blocked_roundtrip),
+    so engine equivalence holds per transport on the 2D mesh too.
+    Otherwise `mesh` only turns off the client-batched local update.
 
     With `fl.error_feedback` the round reads and rewrites `state.ef`
     ((num_clients, N) f32, rows of unselected clients untouched); with
@@ -841,8 +854,9 @@ def make_round_fn(loss_fn: Callable, fl: FLConfig,
     `angle_pred` overrides the config.
 
     `fl.aggregation == "buffered"` builds the buffered-async tick instead
-    of the lockstep round (same signature, same engines): reports are
-    admitted into `state.buf` and the params advance only on flush ticks.
+    of the lockstep round (same signature, same engine step, with the
+    staleness-aware weights): reports are admitted into `state.buf` and
+    the params advance only on flush ticks.
     `arrival_fn(tick) -> (delay (K,) i32, drop (K,) bool)` overrides the
     config's random straggler/dropout draw with an explicit schedule
     (`core.server.fixed_arrival_schedule`); sync mode ignores it.
@@ -869,14 +883,15 @@ def make_round_fn(loss_fn: Callable, fl: FLConfig,
         raise ValueError(
             "a loss that returns counters (loss_fn.has_aux) trains its "
             "clients one at a time: use mode='sequential'")
-    if fl.mode == "parallel":
-        if fl.aggregation == "buffered":
-            return _make_buffered_round(loss_fn, fl, delta_constraint,
-                                        angle_pred, grad_constraint, mesh,
-                                        arrival_fn)
-        return _make_parallel_round(loss_fn, fl, delta_constraint, angle_pred,
-                                    grad_constraint, mesh)
-    return _make_sequential_round(loss_fn, fl, angle_pred, grad_constraint)
+    if fl.mode == "sequential":
+        return _make_sequential_round(loss_fn, fl, angle_pred,
+                                      grad_constraint)
+    step = _engine_step(fl, mesh, angle_pred)
+    if fl.aggregation == "buffered":
+        return _make_buffered_round(loss_fn, fl, step, delta_constraint,
+                                    grad_constraint, mesh, arrival_fn)
+    return _make_parallel_round(loss_fn, fl, step, delta_constraint,
+                                grad_constraint, mesh)
 
 
 def _lr_at(fl: FLConfig, round_idx):
@@ -967,24 +982,266 @@ def _pad_rows(a, kp: int, fill=0.0):
                    constant_values=jnp.asarray(fill, a.dtype))
 
 
-def _derive_param_pspecs(params, mesh):
-    """UNSTACKED param PartitionSpecs for the 2D wire (config-derived:
-    the same name-based rules the launch layer shards params with)."""
-    from repro.models import sharding as models_sharding
+def _check_state(fl: FLConfig, state: RoundState) -> None:
+    """Raise if `state` lacks a buffer the config calls for."""
+    for needed, flag, field, init in (
+            (fl.aggregation == "buffered", "aggregation='buffered'", "buf",
+             "core.buffer.init_report_buffer"),
+            (fl.error_feedback, "error_feedback=True", "ef",
+             "transport.init_error_feedback"),
+            (fl.downlink_error_feedback, "downlink_error_feedback=True",
+             "dl_ef", "transport.downlink.init_downlink_error_feedback"),
+            (fl.downlink_delta, "downlink_delta=True", "bcast",
+             "transport.downlink.init_broadcast_state")):
+        if needed and getattr(state, field) is None:
+            raise ValueError(
+                f"fl.{flag}: state.{field} is missing — build the state "
+                f"with fl.init_round_state (or {init})")
 
-    return models_sharding.param_pspecs(params, mesh)
+
+def _downlink(fl: FLConfig, state: RoundState, sel_idx, pulled=None):
+    """Server -> client broadcast: compress the global model to the
+    `fl.downlink` wire.
+
+    The server keeps `state.params` as its uncompressed master copy (the
+    aggregated delta is applied to it); every client trains from the SAME
+    dequantized reconstruction, so the engines cannot fork. `pulled`
+    masks the selected rows that actually pull this broadcast (buffered
+    admission; None = all): only they move their last-pulled version and
+    are charged downlink bytes. Returns (the params the clients train
+    from, the new `dl_ef`, the new `bcast`, the telemetry's (delta, full)
+    downlink bytes or None)."""
+    params = state.params
+    new_dl, new_bcast, down_split = state.dl_ef, state.bcast, None
+    with jax.named_scope("fl.downlink"):
+        if fl.downlink != "f32":
+            pvec, punravel = treemath.tree_ravel(params)
+            if fl.downlink_delta:
+                # delta encoding: compress the model DIFF against the
+                # chain head (the canonical reconstruction) — per-round
+                # diffs are small, so the quant scales track them tightly.
+                pvec = pvec - state.bcast.head
+            if fl.downlink_error_feedback:
+                # EF-SGD mirror: replay the carried broadcast residual,
+                # then carry what this round's compression drops.
+                pvec = pvec + state.dl_ef
+            qd = transport_mod.downlink.compress(pvec, fl.downlink)
+            recon = transport_mod.downlink.decompress(qd)
+            if fl.downlink_error_feedback:
+                new_dl = pvec - recon
+            if fl.downlink_delta:
+                # publish version v = head_ver + 1 into the ring and
+                # advance the chain head (recon is this version's delta
+                # reconstruction D_v); a pulling client receives the new
+                # head (delta-decoded or resynced), so its last-pulled
+                # version moves to v.
+                new_bcast = transport_mod.downlink.advance_broadcast(
+                    state.bcast, recon)
+                recon = new_bcast.head
+                v = new_bcast.head_ver
+                if fl.telemetry:
+                    down_split = _down_byte_split(
+                        fl, pvec.shape[0], state.bcast.ver[sel_idx], v,
+                        pulled)
+                if pulled is not None:
+                    v = jnp.where(pulled, v, new_bcast.ver[sel_idx])
+                new_bcast = new_bcast._replace(
+                    ver=new_bcast.ver.at[sel_idx].set(v))
+            params = punravel(recon)
+    return params, new_dl, new_bcast, down_split
 
 
-def _make_parallel_round(loss_fn, fl: FLConfig, delta_constraint, angle_pred=None,
-                         grad_constraint=None, mesh=None):
-    round_ops = None
+def _compress_uplink(fl: FLConfig, deltas, ef, sel_idx, pulled=None):
+    """Client uplink: ravel the stacked deltas into the (K, N) f32 buffer
+    and compress it to the `fl.transport` wire.
+
+    With `fl.error_feedback` (EF-SGD) the selected rows' carried residual
+    is replayed into this round's signal and what quantization drops is
+    carried instead, except on rows that did not ship (`pulled` False:
+    buffered admission), which keep theirs. Returns (the wire as a
+    `transport.QuantizedDelta`, the unravel of an (N,) vector to one
+    param tree, the new `ef`)."""
+    flat, unravel = treemath.tree_ravel_stacked(deltas)
+    if fl.transport == "f32":
+        return transport_mod.QuantizedDelta(flat, None, "f32"), unravel, ef
+    if fl.error_feedback:
+        flat = flat + ef[sel_idx]
+    q = transport_mod.quantize(flat, fl.transport, group_size=fl.group_size)
+    if fl.error_feedback:
+        resid = flat - transport_mod.dequantize(q)
+        if pulled is not None:
+            resid = jnp.where(pulled[:, None], resid, ef[sel_idx])
+        ef = ef.at[sel_idx].set(resid)
+    return q, unravel, ef
+
+
+def _column_mask(params, angle_pred):
+    """(N,) 0/1 angle-filter mask over the ravel order, or None."""
+    if not angle_pred:
+        return None
+    return treemath.segment_mask(params, angle_keep_list(params, angle_pred))
+
+
+def _engine_step(fl: FLConfig, mesh, angle_pred) -> Callable:
+    """The FedAdp aggregation step of `fl.engine`, chosen once per round
+    builder: Eq. 8's statistics against the psi-weighted reference delta
+    g, Eq. 9, the weights and the weighted sum.
+
+    step(params, reports, psi, smoothed_sel, count_sel, weigh, wargs)
+      -> (g, dots, sqs, sqg, delta, theta, theta_sm, w)
+
+    `reports` is the K-stacked delta tree (the tree engine, and
+    flat_sharded in the sync round on a 2D mesh, which ravels and
+    quantizes in its region) or the (wire, unravel) pair of
+    `_compress_uplink` (the flat engines). `psi` weighs the reference g,
+    `smoothed_sel`/`count_sel` are the reports' Eq. 9 slots, and
+    `weigh(theta_sm, *wargs) -> w` weighs the reports (None: w = psi and
+    the delta is g — FedAvg and FedProx). `params` gives the angle
+    filter's leaves and the param dtypes: g comes back an f32 tree, delta
+    a tree at the param dtypes (f32 accumulate, one cast).
+
+    "tree" runs the per-leaf treemath reductions (the reference), "flat"
+    the fused kernels of `fl_shard_map.wire_kernels` over the (K, N)
+    buffer, and "flat_sharded" the same kernels in one shard_map region
+    (`fl_shard_map.make_round_ops`) with the client axis zero-padded to
+    the mesh: padded rows get zero psi and size, so exactly zero weight
+    and zero stats contribution, and the (K,) outputs are sliced back.
+    """
+    if fl.engine == "tree":
+        return functools.partial(_tree_step, angle_pred)
+    if fl.engine == "flat":
+        return functools.partial(_flat_step, angle_pred)
+    csize = fl_shard_map.client_axis_size(mesh)
+    msize = fl_shard_map.model_axis_size(mesh)
+    tree_in = msize > 1 and fl.aggregation == "sync"
+    sharding = fl_shard_map.flat_client_sharding(mesh)
+
+    def step(params, reports, psi, smoothed_sel, count_sel, weigh, wargs):
+        k = psi.shape[0]
+        kp = -(-k // csize) * csize
+        rows = [a if jnp.ndim(a) == 0 else _pad_rows(a, kp)
+                for a in (psi, smoothed_sel, count_sel, *wargs)]
+        if tree_in:
+            with jax.named_scope("fl.uplink"):
+                deltas = jax.tree.map(lambda d: _pad_rows(d, kp), reports)
+            keep = angle_keep_list(params, angle_pred) if angle_pred else None
+            op = fl_shard_map.make_round_ops(
+                mesh, weigh=weigh, transport=fl.transport,
+                group_size=fl.group_size, template=deltas,
+                pspecs=models_sharding.param_pspecs(params, mesh), keep=keep)
+            g, dots, sqs, sqg, delta, theta, theta_sm, w = op(deltas, *rows)
+            with jax.named_scope("fl.aggregate"):
+                delta = jax.tree.map(lambda d, p: d.astype(p.dtype), delta,
+                                     params)
+        else:
+            q, unravel = reports
+            n = q.n if q.n >= 0 else q.values.shape[1]
+            npad = -(-n // msize) * msize
+            mask = _column_mask(params, angle_pred)
+            if mask is None:
+                mask = jnp.ones((n,), jnp.float32)
+            with jax.named_scope("fl.uplink"):
+                values = _pad_rows(q.values, kp)
+                if npad != n:
+                    # zero columns: zero in the rows and in g, so every
+                    # stat contribution is exactly zero; sliced off below.
+                    values = jnp.pad(values, ((0, 0), (0, npad - n)))
+                    mask = jnp.pad(mask, (0, npad - n))
+                values = jax.lax.with_sharding_constraint(values, sharding)
+                scales = None if q.scales is None else \
+                    jax.lax.with_sharding_constraint(
+                        _pad_rows(q.scales, kp, 1.0), sharding)
+            op = fl_shard_map.make_round_ops(
+                mesh, weigh=weigh, transport=q.transport,
+                group_size=fl.group_size)
+            g, dots, sqs, sqg, delta, theta, theta_sm, w = op(
+                values, scales, mask, *rows)
+            if npad != n:
+                g, delta = g[:n], delta[:n]
+            with jax.named_scope("fl.stats"):
+                g = unravel(g, jnp.float32)
+            with jax.named_scope("fl.aggregate"):
+                delta = unravel(delta)
+        return g, dots[:k], sqs[:k], sqg, delta, theta[:k], theta_sm[:k], w[:k]
+
+    return step
+
+
+def _tree_step(angle_pred, params, deltas, psi, smoothed_sel, count_sel,
+               weigh, wargs):
+    """`_engine_step` of the tree engine."""
+    with jax.named_scope("fl.stats"):
+        angle_mask = (build_angle_mask(params, angle_pred)
+                      if angle_pred else None)
+        # f32: rounding g to a (possibly bf16) leaf dtype before the stats
+        # would lose the angle signal and diverge from the flat engines;
+        # it also matches init_prev_delta's f32 threading.
+        g = treemath.tree_weighted_sum(deltas, psi, jnp.float32)
+        d_view = angle_mask(deltas) if angle_mask else deltas
+        g_view = angle_mask(g) if angle_mask else g
+        dots = treemath.tree_vdot_batched(d_view, g_view)
+        sqs = treemath.tree_sqnorm_batched(d_view)
+        sqg = treemath.tree_sqnorm(g_view)
+        theta = weighting.instantaneous_angle(dots, sqs, sqg)
+        theta_sm = weighting.smoothed_angle(theta, smoothed_sel, count_sel)
+    w, delta = fl_shard_map.weigh_and_sum(
+        weigh, wargs, theta_sm, psi, g,
+        lambda w: treemath.tree_weighted_sum(deltas, w, jnp.float32))
+    with jax.named_scope("fl.aggregate"):
+        delta = jax.tree.map(lambda d, p: d.astype(p.dtype), delta, params)
+    return g, dots, sqs, sqg, delta, theta, theta_sm, w
+
+
+def _flat_step(angle_pred, params, reports, psi, smoothed_sel, count_sel,
+               weigh, wargs):
+    """`_engine_step` of the flat engine: one pass of the fused kernels
+    over the (K, N) wire buffer for g and the stats, one for the weighted
+    sum (the client axis is chunked inside the kernels, so any K fits)."""
+    q, unravel = reports
+    agg, stats = fl_shard_map.wire_kernels(q)
+    with jax.named_scope("fl.stats"):
+        mask = _column_mask(params, angle_pred)
+        g_flat = agg(psi)
+        dots, sqs, sqg = stats(g_flat, mask)
+        g = unravel(g_flat, jnp.float32)
+        theta = weighting.instantaneous_angle(dots, sqs, sqg)
+        theta_sm = weighting.smoothed_angle(theta, smoothed_sel, count_sel)
+    w, delta = fl_shard_map.weigh_and_sum(weigh, wargs, theta_sm, psi,
+                                          g_flat, agg)
+    with jax.named_scope("fl.aggregate"):
+        delta = unravel(delta)
+    return g, dots, sqs, sqg, delta, theta, theta_sm, w
+
+
+def _core_metrics(losses, lr, dots, sqs, sqg, theta, theta_sm, w,
+                  landed=None, n_landed=None):
+    """The metrics every round reports. The divergence is Fig. 7's
+    (1/K) sum_i ||dF - dF_i|| with dF ~ -delta/lr, over the `landed` rows
+    (`n_landed` of them) where given."""
+    gap = jnp.sqrt(jnp.maximum(sqs - 2 * dots + sqg, 0.0))
+    if landed is None:
+        div = jnp.mean(gap) / lr
+    else:
+        div = jnp.sum(jnp.where(landed, gap, 0.0)) / n_landed / lr
+    return {
+        "loss": jnp.mean(losses), "theta": theta, "theta_smoothed": theta_sm,
+        "weights": w, "divergence": div, "lr": lr, "cos": jnp.cos(theta),
+        "expected_contribution": weighting.expected_contribution(
+            w, jnp.cos(theta)),
+    }
+
+
+def _make_parallel_round(loss_fn, fl: FLConfig, step, delta_constraint,
+                         grad_constraint, mesh):
+    """The lockstep round: every selected client pulls the broadcast,
+    trains and reports, and the server aggregates all K reports."""
     path = local_update_path(loss_fn, fl, mesh)
     # A mesh with a "model" axis of size > 1 switches the wire to the 2D
     # (client x model) blocked layout: quantization chunks are SHARD-LOCAL
-    # (never straddling a model-axis split), model-sharded leaves are
-    # raveled per shard (no all-gather), and the flat engine's aggregate
-    # keeps sharded leaves sharded. The tree engine consumes the same
-    # wire through fl_shard_map.make_blocked_roundtrip.
+    # (never straddling a model-axis split) and model-sharded leaves are
+    # raveled per shard (no all-gather). flat_sharded quantizes inside its
+    # region; the tree engine consumes the same wire through
+    # fl_shard_map.make_blocked_roundtrip.
     wire_2d = (mesh is not None and fl.engine in ("tree", "flat_sharded")
                and fl_shard_map.model_axis_size(mesh) > 1)
     if wire_2d and fl.transport != "f32" and fl.error_feedback:
@@ -993,311 +1250,71 @@ def _make_parallel_round(loss_fn, fl: FLConfig, delta_constraint, angle_pred=Non
             "tree-ravel order, but a (client x model) mesh quantizes the "
             "wire in shard-local blocked order; drop error_feedback or "
             "use a client-only mesh")
-    if fl.engine == "flat_sharded":
-        csize = fl_shard_map.client_axis_size(mesh)
-        if not wire_2d:
-            round_ops = fl_shard_map.make_round_ops(
-                mesh, alpha=fl.alpha, method=fl.method,
-                transport=fl.transport,
-                group_size=fl.group_size)
-            row_sharding = fl_shard_map.flat_client_sharding(mesh)
-    elif wire_2d:
-        csize = fl_shard_map.client_axis_size(mesh)
+    weigh = None
+    if fl.method == "fedadp":
+        def weigh(theta_sm, sizes):
+            return weighting.fedadp_weights(theta_sm, sizes, fl.alpha)
 
     def round_fn(state: RoundState, batches, sel_idx, data_sizes):
-        if fl.error_feedback and state.ef is None:
-            raise ValueError(
-                "fl.error_feedback=True: state.ef is missing — build the "
-                "state with fl.init_round_state (or "
-                "transport.init_error_feedback)")
-        if fl.downlink_error_feedback and state.dl_ef is None:
-            raise ValueError(
-                "fl.downlink_error_feedback=True: state.dl_ef is missing "
-                "— build the state with fl.init_round_state (or "
-                "transport.downlink.init_downlink_error_feedback)")
-        if fl.downlink_delta and state.bcast is None:
-            raise ValueError(
-                "fl.downlink_delta=True: state.bcast is missing — build "
-                "the state with fl.init_round_state (or "
-                "transport.downlink.init_broadcast_state)")
-        params, angle_state = state.params, state.angle
-        ef_state, dl_state = state.ef, state.dl_ef
+        _check_state(fl, state)
+        angle_state = state.angle
         lr = _lr_at(fl, state.round)
-
-        # ---- server -> client downlink: compress the broadcast model ----
-        # The server keeps `params` as its uncompressed master copy (the
-        # aggregated delta is applied to it below); every client trains
-        # from the SAME dequantized reconstruction, so the three engines
-        # cannot fork — the branch is upstream of all of them.
-        params_srv = params
-        new_dl, new_bcast = dl_state, state.bcast
-        down_split = None
-        with jax.named_scope("fl.downlink"):
-            if fl.downlink != "f32":
-                pvec, punravel = treemath.tree_ravel(params)
-                if fl.downlink_delta:
-                    # delta encoding: compress the model DIFF against the
-                    # chain head (the canonical reconstruction) — per-round
-                    # diffs are small, so the quant scales track them tightly.
-                    pvec = pvec - state.bcast.head
-                if fl.downlink_error_feedback:
-                    # EF-SGD mirror: replay the carried broadcast residual,
-                    # then carry what this round's compression drops.
-                    pvec = pvec + dl_state
-                qd = transport_mod.downlink.compress(pvec, fl.downlink)
-                recon = transport_mod.downlink.decompress(qd)
-                if fl.downlink_error_feedback:
-                    new_dl = pvec - recon
-                if fl.downlink_delta:
-                    # publish version v = head_ver + 1 into the ring and
-                    # advance the chain head (recon is this version's delta
-                    # reconstruction D_v); every selected client pulls the
-                    # new head (delta-decoded or resynced), so its
-                    # last-pulled version moves to v.
-                    new_bcast = transport_mod.downlink.advance_broadcast(
-                        state.bcast, recon)
-                    recon = new_bcast.head
-                    v = new_bcast.head_ver
-                    if fl.telemetry:
-                        down_split = _down_byte_split(
-                            fl, pvec.shape[0], state.bcast.ver[sel_idx], v)
-                    new_bcast = new_bcast._replace(
-                        ver=new_bcast.ver.at[sel_idx].set(v))
-                params = punravel(recon)
-
-        with jax.named_scope("fl.local_train"):
-            deltas, losses = _local_train(loss_fn, path, params, batches,
-                                          lr, fl, grad_constraint)
-            if delta_constraint is not None:
-                deltas = delta_constraint(deltas)
-
+        params, new_dl, new_bcast, down_split = _downlink(fl, state, sel_idx)
+        deltas, losses = _local_train(loss_fn, path, params, batches, lr,
+                                      fl, grad_constraint, delta_constraint)
         with jax.named_scope("fl.weights"):
             psi_avg = weighting.fedavg_weights(data_sizes)
-        new_ef = ef_state
 
-        # ---- client uplink: compress the stacked deltas to the wire ----
+        new_ef = state.ef
         with jax.named_scope("fl.uplink"):
-            if fl.transport != "f32" and wire_2d:
-                # 2D (client x model) mesh: the wire is quantized per-shard in
-                # blocked order (see fl_shard_map.make_round_ops_2d). The
-                # flat_sharded engine quantizes INSIDE its region; the tree
-                # engine consumes the identical reconstruction through the
-                # blocked roundtrip region here (per-leaf reference reductions
-                # then run on the dequantized tree, so "tree never reads the
-                # wire buffer" still holds).
-                if fl.engine == "tree":
+            if wire_2d:
+                reports = deltas
+                if fl.engine == "tree" and fl.transport != "f32":
+                    # the tree reference reads the dequantized shard-local
+                    # wire (flat_sharded quantizes it in its region).
                     k = data_sizes.shape[0]
+                    csize = fl_shard_map.client_axis_size(mesh)
                     kp = -(-k // csize) * csize
-                    deltas_p = jax.tree.map(lambda d: _pad_rows(d, kp), deltas)
+                    padded = jax.tree.map(lambda d: _pad_rows(d, kp), deltas)
                     rt = fl_shard_map.make_blocked_roundtrip(
-                        mesh, deltas_p, _derive_param_pspecs(params, mesh),
+                        mesh, padded,
+                        models_sharding.param_pspecs(params, mesh),
                         transport=fl.transport, group_size=fl.group_size)
-                    deltas = jax.tree.map(lambda d: d[:k], rt(deltas_p))
-            elif fl.transport != "f32":
-                flat0, unravel0 = treemath.tree_ravel_stacked(deltas)
-                if fl.error_feedback:
-                    # EF-SGD: replay the carried residual into this round's
-                    # signal, then carry what quantization drops this round.
-                    flat0 = flat0 + ef_state[sel_idx]
-                q = transport_mod.quantize(flat0, fl.transport,
-                                           group_size=fl.group_size)
-                if fl.error_feedback:
-                    new_ef = ef_state.at[sel_idx].set(
-                        flat0 - transport_mod.dequantize(q))
-                if fl.engine == "tree":
-                    # reference contract: the tree engine never reads the wire
-                    # buffer — dequantize back to the stacked tree and run the
-                    # per-leaf reference reductions on the reconstruction.
-                    # f32 leaves: rounding the dequantized values to a bf16
-                    # leaf dtype would add a second loss the flat engines
-                    # (which stream the wire directly) never incur.
-                    deltas = treemath.tree_unravel_stacked(
-                        deltas, transport_mod.dequantize(q), jnp.float32)
-
-        # (N,) 0/1 segment mask over the ravel order — ONE copy shared by
-        # both flat engines (the tree engine masks per-leaf views instead),
-        # so the angle_filter semantics cannot fork between them. The 2D
-        # engine bakes the same per-leaf keep flags into its shard-local
-        # blocked mask instead (treemath.blocked_segment_mask).
-        with jax.named_scope("fl.stats"):
-            maskv = None
-            if fl.engine != "tree" and angle_pred and not wire_2d:
-                maskv = treemath.segment_mask(params,
-                                              angle_keep_list(params, angle_pred))
-
-        if fl.engine == "flat_sharded" and wire_2d:
-            # one shard_map region over the (client x model) tile grid:
-            # per-tile shard-local ravel + quantize + fused kernels, stat
-            # psums over both axes, replicated Eq.9 + Gompertz, aggregate
-            # psum over the client axis only — model-sharded leaves come
-            # back still sharded (no full-N gather anywhere).
-            k = data_sizes.shape[0]
-            kp = -(-k // csize) * csize
-            with jax.named_scope("fl.uplink"):
-                deltas_p = jax.tree.map(lambda d: _pad_rows(d, kp), deltas)
-            keep = (angle_keep_list(params, angle_pred)
-                    if angle_pred else None)
-            round_ops_2d = fl_shard_map.make_round_ops_2d(
-                mesh, deltas_p, _derive_param_pspecs(params, mesh),
-                alpha=fl.alpha, method=fl.method,
-                transport=fl.transport,
-                group_size=fl.group_size, keep=keep)
-            # padded rows: zero deltas, zero data size -> -inf softmax
-            # logit -> exactly zero weight and zero stats contribution.
-            g_avg, dots, sqs, sqg, delta, theta, _, w = round_ops_2d(
-                deltas_p, _pad_rows(psi_avg, kp),
-                _pad_rows(angle_state.smoothed[sel_idx], kp),
-                _pad_rows(angle_state.count[sel_idx], kp),
-                _pad_rows(data_sizes, kp))
-            dots, sqs = dots[:k], sqs[:k]
-            theta, w = theta[:k], w[:k]
-            # f32 in-region accumulate, ONE cast to the param leaf dtype —
-            # the same rounding schedule as the 1D engines' unravel.
-            with jax.named_scope("fl.aggregate"):
-                delta = jax.tree.map(lambda d, p: d.astype(p.dtype), delta,
-                                     params)
-        elif fl.engine == "flat_sharded":
-            # the WHOLE round is one shard_map call (stats psums ->
-            # replicated Eq.9 + Gompertz weighting -> aggregate psum):
-            # rows sharded over ("pod","data"), per-shard fused kernels.
-            with jax.named_scope("fl.uplink"):
-                if fl.transport == "f32":
-                    flat, unravel = treemath.tree_ravel_stacked(deltas)
-                    values, scales = flat, None
-                    n_logical = flat.shape[1]
-                else:
-                    values, scales, unravel = q.values, q.scales, unravel0
-                    # int4 packs two params per byte: the wire buffer
-                    # width is NOT the logical width the mask/g vectors
-                    # live in.
-                    n_logical = flat0.shape[1]
-                k = values.shape[0]
-                kp = -(-k // csize) * csize  # pad the client axis
-                values = jax.lax.with_sharding_constraint(
-                    _pad_rows(values, kp), row_sharding)
-                wire = (values,) if scales is None else (
-                    values, jax.lax.with_sharding_constraint(
-                        _pad_rows(scales, kp, 1.0), row_sharding))
-            mvec = (maskv if maskv is not None
-                    else jnp.ones((n_logical,), jnp.float32))
-            # padded rows: zero deltas, zero data size -> -inf softmax
-            # logit -> exactly zero weight and zero stats contribution.
-            g_flat, dots, sqs, sqg, delta_flat, theta, _, w = round_ops(
-                *wire, _pad_rows(psi_avg, kp), mvec,
-                _pad_rows(angle_state.smoothed[sel_idx], kp),
-                _pad_rows(angle_state.count[sel_idx], kp),
-                _pad_rows(data_sizes, kp))
-            dots, sqs = dots[:k], sqs[:k]
-            theta, w = theta[:k], w[:k]
-            with jax.named_scope("fl.stats"):
-                g_avg = unravel(g_flat, jnp.float32)
-            with jax.named_scope("fl.aggregate"):
-                delta = unravel(delta_flat)
-        elif fl.engine == "flat":
-            # single (K, N) ravel; stats + both aggregations are fused
-            # single-HBM-pass kernels over the contiguous buffer
-            # (chunked over the client axis, so any K fits the VMEM
-            # envelope). Quantized wire buffers flow through the
-            # fused-dequant kernel variants untouched.
-            if fl.transport == "f32":
-                with jax.named_scope("fl.uplink"):
-                    flat, unravel = treemath.tree_ravel_stacked(deltas)
-                wire_x, wire_s = flat, None
+                    reports = jax.tree.map(lambda d: d[:k], rt(padded))
+            elif fl.engine == "tree" and fl.transport == "f32":
+                reports = deltas
             else:
-                unravel = unravel0
-                wire_x, wire_s = q.values, q.scales
+                q, unravel, new_ef = _compress_uplink(fl, deltas, state.ef,
+                                                      sel_idx)
+                # reference contract: the tree engine never reads the wire
+                # buffer — it reads the dequantized stacked tree, in f32
+                # (rounding to a bf16 leaf dtype would add a second loss
+                # the flat engines, which stream the wire, never incur).
+                reports = ((q, unravel) if fl.engine != "tree" else
+                           treemath.tree_unravel_stacked(
+                               deltas, transport_mod.dequantize(q),
+                               jnp.float32))
 
-            def agg_wire(wvec):
-                if wire_s is None:
-                    return weighted_agg_mod.weighted_agg(
-                        wvec, wire_x, out_dtype=jnp.float32)
-                if fl.transport == "int4":
-                    return weighted_agg_mod.weighted_agg_q4(
-                        wvec, wire_x, wire_s, n=flat0.shape[1],
-                        group_size=fl.group_size)
-                return weighted_agg_mod.weighted_agg_q(
-                    wvec, wire_x, wire_s)
-
-            with jax.named_scope("fl.stats"):
-                g_flat = agg_wire(psi_avg)
-                if wire_s is None:
-                    dots, sqs, sqg = round_stats_mod.round_stats(
-                        wire_x, g_flat, maskv)
-                elif fl.transport == "int4":
-                    dots, sqs, sqg = round_stats_mod.round_stats_q4(
-                        wire_x, wire_s, g_flat, maskv,
-                        group_size=fl.group_size)
-                else:
-                    dots, sqs, sqg = round_stats_mod.round_stats_q(
-                        wire_x, wire_s, g_flat, maskv)
-                g_avg = unravel(g_flat, jnp.float32)
-                theta = weighting.instantaneous_angle(dots, sqs, sqg)
-        else:
-            with jax.named_scope("fl.stats"):
-                angle_mask = (build_angle_mask(params, angle_pred)
-                              if angle_pred else None)
-                # f32: rounding g to the (possibly bf16) leaf dtype before
-                # the stats would lose the angle signal and diverge from
-                # the flat engine; also matches init_prev_delta's f32
-                # threading.
-                g_avg = treemath.tree_weighted_sum(deltas, psi_avg,
-                                                   jnp.float32)
-                d_view = angle_mask(deltas) if angle_mask else deltas
-                g_view = angle_mask(g_avg) if angle_mask else g_avg
-                dots = treemath.tree_vdot_batched(d_view, g_view)
-                sqs = treemath.tree_sqnorm_batched(d_view)
-                sqg = treemath.tree_sqnorm(g_view)
-                theta = weighting.instantaneous_angle(dots, sqs, sqg)
-
-        # Eq. 9 scatter — ONE copy for all engines (flat_sharded computed
-        # the same float ops in-region for its weighting; this scatter is
-        # its state bookkeeping and must stay op-identical).
         with jax.named_scope("fl.stats"):
-            new_state = _scatter_angles(angle_state, sel_idx, theta)
-            theta_sm = new_state.smoothed[sel_idx]
-        if fl.engine != "flat_sharded":
-            with jax.named_scope("fl.weights"):
-                if fl.method == "fedadp":
-                    w = weighting.fedadp_weights(theta_sm, data_sizes,
-                                                 fl.alpha)
-                else:  # fedavg / fedprox aggregate by data size
-                    w = psi_avg
-            with jax.named_scope("fl.aggregate"):
-                if fl.engine == "flat":
-                    # fedavg/fedprox aggregate with w == psi_avg: reuse
-                    # g_flat rather than re-streaming the buffer (no
-                    # Pallas CSE)
-                    delta_flat = (g_flat if fl.method != "fedadp"
-                                  else agg_wire(w))
-                    delta = unravel(delta_flat)
-                else:
-                    # f32 accumulate, ONE cast to the param leaf dtype —
-                    # same rounding schedule as the flat engines'
-                    # unravel, and it keeps params at their dtype when the
-                    # transport path reconstructed the deltas as f32
-                    # leaves.
-                    delta = jax.tree.map(
-                        lambda d, p: d.astype(p.dtype),
-                        treemath.tree_weighted_sum(deltas, w, jnp.float32),
-                        params)
+            smoothed_sel = angle_state.smoothed[sel_idx]
+            count_sel = angle_state.count[sel_idx]
+        g_avg, dots, sqs, sqg, delta, theta, theta_sm, w = step(
+            params, reports, psi_avg, smoothed_sel, count_sel, weigh,
+            (data_sizes,))
+        with jax.named_scope("fl.stats"):
+            new_angle = _scatter_angles(angle_state, sel_idx, theta)
         # the delta lands on the server's uncompressed master params — the
         # downlink reconstruction is what the CLIENTS trained from.
         with jax.named_scope("fl.aggregate"):
-            new_params = treemath.tree_add(params_srv, delta)
+            new_params = treemath.tree_add(state.params, delta)
 
-        # Fig.7 divergence: (1/K) sum_i ||dF - dF_i|| with dF ~ -delta/lr
-        div = jnp.mean(jnp.sqrt(jnp.maximum(sqs - 2 * dots + sqg, 0.0))) / lr
-        metrics = {
-            "loss": jnp.mean(losses), "theta": theta, "theta_smoothed": theta_sm,
-            "weights": w, "divergence": div, "lr": lr,
-            "cos": jnp.cos(theta),
-            "expected_contribution": weighting.expected_contribution(w, jnp.cos(theta)),
-        }
+        metrics = _core_metrics(losses, lr, dots, sqs, sqg, theta, theta_sm,
+                                w)
         if fl.telemetry:
             metrics.update(_telemetry_metrics(fl, params, sel_idx, w,
                                               down_split=down_split))
         return state._replace(
-            params=new_params, angle=new_state, prev_delta=g_avg,
+            params=new_params, angle=new_angle, prev_delta=g_avg,
             ef=new_ef, dl_ef=new_dl, bcast=new_bcast,
             round=state.round + 1,
         ), metrics
@@ -1305,9 +1322,8 @@ def _make_parallel_round(loss_fn, fl: FLConfig, delta_constraint, angle_pred=Non
     return round_fn
 
 
-def _make_buffered_round(loss_fn, fl: FLConfig, delta_constraint,
-                         angle_pred=None, grad_constraint=None, mesh=None,
-                         arrival_fn=None):
+def _make_buffered_round(loss_fn, fl: FLConfig, step, delta_constraint,
+                         grad_constraint, mesh, arrival_fn):
     """The buffered-async server tick (aggregation="buffered").
 
     Same `round_fn(state, batches, sel_idx, data_sizes)` signature as the
@@ -1335,52 +1351,18 @@ def _make_buffered_round(loss_fn, fl: FLConfig, delta_constraint,
                   and (fl.straggle_prob > 0 or fl.dropout_prob > 0))
     path = local_update_path(loss_fn, fl, mesh)
     m_flush = fl.buffer_m if fl.buffer_m > 0 else fl.clients_per_round
-    flush_ops = None
-    if fl.engine == "flat_sharded":
-        # wire compression happens at admission (the buffer holds
-        # dequantized f32 rows), so the flush region never needs scales.
-        flush_ops = fl_shard_map.make_buffered_flush_ops(
-            mesh, alpha=fl.alpha, method=fl.method, beta=fl.staleness_beta)
-        row_sharding = fl_shard_map.flat_client_sharding(mesh)
-        csize = fl_shard_map.client_axis_size(mesh)
-        # 2D (client x model) mesh: the flush region also tiles the
-        # buffer's COLUMNS over the model axis (admission stays the
-        # global f32 buffer — only the flush's layout changes). Columns
-        # are zero-padded to a multiple of the model-axis size and the
-        # model-sharded outputs sliced back below.
-        msize = fl_shard_map.model_axis_size(mesh)
-        if msize > 1:
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            caxes = fl_shard_map._client_axes(mesh)
-            row_sharding = NamedSharding(
-                mesh, PartitionSpec(
-                    caxes if len(caxes) > 1 else caxes[0],
-                    fl_shard_map.MODEL_AXIS))
+    weigh = None
+    if fl.method == "fedadp":
+        def weigh(theta_sm, sizes, age, landed, flush, held):
+            # a non-flush tick applies no Eq. 9: its weights are those of
+            # the carried angles.
+            return weighting.buffered_fedadp_weights(
+                jnp.where(flush, theta_sm, held), sizes, age, landed,
+                fl.alpha, fl.staleness_beta)
 
     def round_fn(state: RoundState, batches, sel_idx, data_sizes):
-        if state.buf is None:
-            raise ValueError(
-                "fl.aggregation='buffered': state.buf is missing — build "
-                "the state with fl.init_round_state (or "
-                "core.buffer.init_report_buffer)")
-        if fl.error_feedback and state.ef is None:
-            raise ValueError(
-                "fl.error_feedback=True: state.ef is missing — build the "
-                "state with fl.init_round_state (or "
-                "transport.init_error_feedback)")
-        if fl.downlink_error_feedback and state.dl_ef is None:
-            raise ValueError(
-                "fl.downlink_error_feedback=True: state.dl_ef is missing "
-                "— build the state with fl.init_round_state (or "
-                "transport.downlink.init_downlink_error_feedback)")
-        if fl.downlink_delta and state.bcast is None:
-            raise ValueError(
-                "fl.downlink_delta=True: state.bcast is missing — build "
-                "the state with fl.init_round_state (or "
-                "transport.downlink.init_broadcast_state)")
-        params, angle_state = state.params, state.angle
-        ef_state, dl_state = state.ef, state.dl_ef
+        _check_state(fl, state)
+        angle_state = state.angle
         lr = _lr_at(fl, state.round)
         k = fl.clients_per_round
 
@@ -1403,87 +1385,33 @@ def _make_buffered_round(loss_fn, fl: FLConfig, delta_constraint,
             else:
                 delay = jnp.zeros((k,), jnp.int32)
                 drop = jnp.zeros((k,), bool)
-
-        # ---- server -> client downlink (identical to the sync round:
-        # candidates pull the CURRENT broadcast every tick, so the
-        # downlink EF / broadcast-chain bookkeeping advances per tick;
-        # the per-client version rows move only for ADMITTED candidates,
-        # below, once the admission mask is known — admission is when a
-        # pull actually happens in the simulation, which is what fixes a
-        # buffered client's decode base at admission time) ----
-        params_srv = params
-        new_dl, new_bcast = dl_state, state.bcast
-        bcast_v, n_ravel = None, 0
-        with jax.named_scope("fl.downlink"):
-            if fl.downlink != "f32":
-                pvec, punravel = treemath.tree_ravel(params)
-                n_ravel = pvec.shape[0]
-                if fl.downlink_delta:
-                    pvec = pvec - state.bcast.head
-                if fl.downlink_error_feedback:
-                    pvec = pvec + dl_state
-                qd = transport_mod.downlink.compress(pvec, fl.downlink)
-                recon = transport_mod.downlink.decompress(qd)
-                if fl.downlink_error_feedback:
-                    new_dl = pvec - recon
-                if fl.downlink_delta:
-                    new_bcast = transport_mod.downlink.advance_broadcast(
-                        state.bcast, recon)
-                    recon = new_bcast.head
-                    bcast_v = new_bcast.head_ver
-                params = punravel(recon)
-
-        # ---- candidate local updates (all K slots compute; admission
-        # masks decide whose report actually enters the buffer) ----
-        with jax.named_scope("fl.local_train"):
-            deltas, losses = _local_train(loss_fn, path, params, batches,
-                                          lr, fl, grad_constraint)
-            if delta_constraint is not None:
-                deltas = delta_constraint(deltas)
-
-        # a free slot admits its candidate unless the client already has
-        # a report in flight (full participation re-offers everyone) or
-        # the report drops in transit (the slot stays free — liveness
-        # never waits on a timeout).
-        with jax.named_scope("fl.uplink"):
+            # a free slot admits its candidate unless the client already
+            # has a report in flight (full participation re-offers
+            # everyone) or the report drops in transit (the slot stays
+            # free — liveness never waits on a timeout).
             busy = buffer_mod.population_busy(state.buf, fl.num_clients)
             admit = state.buf.free & ~busy[sel_idx] & ~drop
 
-        # admitted candidates actually pulled this tick's broadcast:
-        # their decode base — and so their last-pulled version — is
-        # fixed at admission time; busy/dropped candidates never pulled
-        # and are neither version-advanced nor charged downlink bytes.
-        down_split = None
-        with jax.named_scope("fl.downlink"):
-            if fl.downlink_delta:
-                if fl.telemetry:
-                    down_split = _down_byte_split(
-                        fl, n_ravel, state.bcast.ver[sel_idx], bcast_v,
-                        pulled=admit)
-                new_bcast = new_bcast._replace(
-                    ver=new_bcast.ver.at[sel_idx].set(
-                        jnp.where(admit, bcast_v, new_bcast.ver[sel_idx])))
+        # every candidate trains from the CURRENT broadcast, so the
+        # downlink EF and the broadcast chain advance every tick; only the
+        # ADMITTED candidates pull it — admission fixes a buffered
+        # client's decode base — so busy and dropped candidates are
+        # neither version-advanced nor charged downlink bytes.
+        params, new_dl, new_bcast, down_split = _downlink(
+            fl, state, sel_idx, pulled=admit)
+        deltas, losses = _local_train(loss_fn, path, params, batches, lr,
+                                      fl, grad_constraint, delta_constraint)
 
         # ---- client uplink: compress to the wire, buffer the f32
         # reconstruction (the tree engine never reads the wire, and rows
-        # must survive across ticks independent of the transport) ----
+        # must survive across ticks independent of the transport); a
+        # non-admitted report never shipped, so its EF residual stays
+        # carried ----
         with jax.named_scope("fl.uplink"):
-            flat0, unravel0 = treemath.tree_ravel_stacked(deltas)
-            new_ef = ef_state
-            if fl.transport == "f32":
-                rows = flat0
-            else:
-                if fl.error_feedback:
-                    flat0 = flat0 + ef_state[sel_idx]
-                q = transport_mod.quantize(flat0, fl.transport,
-                                           group_size=fl.group_size)
-                rows = transport_mod.dequantize(q)
-                if fl.error_feedback:
-                    # the residual of a non-admitted report stays carried —
-                    # that report never shipped, so nothing was dropped yet.
-                    new_ef = ef_state.at[sel_idx].set(
-                        jnp.where(admit[:, None], flat0 - rows,
-                                  ef_state[sel_idx]))
+            q, unravel, new_ef = _compress_uplink(fl, deltas, state.ef,
+                                                  sel_idx, pulled=admit)
+            rows = (q.values if fl.transport == "f32"
+                    else transport_mod.dequantize(q))
             buf = buffer_mod.admit(state.buf, admit, rows, sel_idx,
                                    data_sizes, delay)
 
@@ -1491,7 +1419,6 @@ def _make_buffered_round(loss_fn, fl: FLConfig, delta_constraint,
             landed = buffer_mod.landed_mask(buf)
             num_landed = jnp.sum(landed.astype(jnp.int32))
             do_flush = num_landed >= m_flush
-
             # staleness-discounted FedAvg weights over the landed rows — the
             # angle-reference global delta g, exactly psi_avg when every row
             # landed at age 0.
@@ -1499,66 +1426,16 @@ def _make_buffered_round(loss_fn, fl: FLConfig, delta_constraint,
                 buf.sizes, buf.age, landed, fl.staleness_beta)
 
         with jax.named_scope("fl.stats"):
-            maskv = None
-            if fl.engine != "tree" and angle_pred:
-                maskv = treemath.segment_mask(params,
-                                              angle_keep_list(params, angle_pred))
-
-        if fl.engine == "flat_sharded":
-            # same single-region schedule as the sync round, over the f32
-            # report rows; padded rows land False -> exactly zero weight.
-            kp = -(-k // csize) * csize
-            n = buf.data.shape[1]
-            npad = -(-n // msize) * msize
-            mvec = (maskv if maskv is not None
-                    else jnp.ones((n,), jnp.float32))
-            with jax.named_scope("fl.uplink"):
-                values = _pad_rows(buf.data, kp)
-                if npad != n:
-                    # zero columns: zero in both rows and aggregate, so
-                    # every stat contribution is exactly zero; sliced off
-                    # below.
-                    values = jnp.pad(values, ((0, 0), (0, npad - n)))
-                    mvec = jnp.pad(mvec, (0, npad - n))
-                values = jax.lax.with_sharding_constraint(values,
-                                                          row_sharding)
-            g_flat, dots, sqs, sqg, delta_flat, theta, _, w = flush_ops(
-                values, _pad_rows(psi_b, kp), mvec,
-                _pad_rows(angle_state.smoothed[buf.slot], kp),
-                _pad_rows(angle_state.count[buf.slot], kp),
-                _pad_rows(buf.sizes, kp, 1.0), _pad_rows(buf.age, kp),
-                _pad_rows(landed, kp, False))
-            dots, sqs = dots[:k], sqs[:k]
-            theta, w = theta[:k], w[:k]
-            if npad != n:
-                g_flat = g_flat[:n]
-                delta_flat = delta_flat[:n]
-            with jax.named_scope("fl.stats"):
-                g_avg = unravel0(g_flat, jnp.float32)
-            with jax.named_scope("fl.aggregate"):
-                delta = unravel0(delta_flat)
-        elif fl.engine == "flat":
-            with jax.named_scope("fl.stats"):
-                g_flat = weighted_agg_mod.weighted_agg(
-                    psi_b, buf.data, out_dtype=jnp.float32)
-                dots, sqs, sqg = round_stats_mod.round_stats(
-                    buf.data, g_flat, maskv)
-                g_avg = unravel0(g_flat, jnp.float32)
-                theta = weighting.instantaneous_angle(dots, sqs, sqg)
-        else:
-            with jax.named_scope("fl.stats"):
-                deltas_b = treemath.tree_unravel_stacked(deltas, buf.data,
-                                                         jnp.float32)
-                angle_mask = (build_angle_mask(params, angle_pred)
-                              if angle_pred else None)
-                g_avg = treemath.tree_weighted_sum(deltas_b, psi_b,
-                                                   jnp.float32)
-                d_view = angle_mask(deltas_b) if angle_mask else deltas_b
-                g_view = angle_mask(g_avg) if angle_mask else g_avg
-                dots = treemath.tree_vdot_batched(d_view, g_view)
-                sqs = treemath.tree_sqnorm_batched(d_view)
-                sqg = treemath.tree_sqnorm(g_view)
-                theta = weighting.instantaneous_angle(dots, sqs, sqg)
+            smoothed_sel = angle_state.smoothed[buf.slot]
+            count_sel = angle_state.count[buf.slot]
+            reports = (treemath.tree_unravel_stacked(deltas, buf.data,
+                                                     jnp.float32)
+                       if fl.engine == "tree" else
+                       (transport_mod.QuantizedDelta(buf.data, None, "f32"),
+                        unravel))
+        g_avg, dots, sqs, sqg, delta, theta, _, w = step(
+            params, reports, psi_b, smoothed_sel, count_sel, weigh,
+            (buf.sizes, buf.age, landed, do_flush, smoothed_sel))
 
         # Eq. 9 over the LANDED reports only, applied only on flush ticks
         # (both masks reduce to the sync scatter when everything landed).
@@ -1568,51 +1445,26 @@ def _make_buffered_round(loss_fn, fl: FLConfig, delta_constraint,
             new_angle = jax.tree.map(lambda a, b: jnp.where(do_flush, a, b),
                                      ang_flushed, angle_state)
             theta_sm = new_angle.smoothed[buf.slot]
-        if fl.engine != "flat_sharded":
-            with jax.named_scope("fl.weights"):
-                if fl.method == "fedadp":
-                    w = weighting.buffered_fedadp_weights(
-                        theta_sm, buf.sizes, buf.age, landed, fl.alpha,
-                        fl.staleness_beta)
-                else:
-                    w = psi_b
-            with jax.named_scope("fl.aggregate"):
-                if fl.engine == "flat":
-                    delta_flat = (g_flat if fl.method != "fedadp" else
-                                  weighted_agg_mod.weighted_agg(
-                                      w, buf.data, out_dtype=jnp.float32))
-                    delta = unravel0(delta_flat)
-                else:
-                    delta = jax.tree.map(
-                        lambda d, p: d.astype(p.dtype),
-                        treemath.tree_weighted_sum(deltas_b, w, jnp.float32),
-                        params)
 
         # flush: apply the aggregated delta to the master params — or, on
         # a non-flush tick, carry everything unchanged (where no-ops).
         with jax.named_scope("fl.aggregate"):
             new_params = jax.tree.map(
                 lambda a, b: jnp.where(do_flush, a, b),
-                treemath.tree_add(params_srv, delta), params_srv)
+                treemath.tree_add(state.params, delta), state.params)
             new_prev = jax.tree.map(lambda a, b: jnp.where(do_flush, a, b),
                                     g_avg, state.prev_delta)
             final_buf = buffer_mod.advance(buf, landed, do_flush)
 
         nl_f = jnp.maximum(num_landed.astype(jnp.float32), 1.0)
-        div = jnp.sum(jnp.where(
-            landed, jnp.sqrt(jnp.maximum(sqs - 2 * dots + sqg, 0.0)),
-            0.0)) / nl_f / lr
-        metrics = {
-            "loss": jnp.mean(losses), "theta": theta,
-            "theta_smoothed": theta_sm, "weights": w, "divergence": div,
-            "lr": lr, "cos": jnp.cos(theta),
-            "expected_contribution": weighting.expected_contribution(
-                w, jnp.cos(theta)),
+        metrics = _core_metrics(losses, lr, dots, sqs, sqg, theta, theta_sm,
+                                w, landed, nl_f)
+        metrics.update({
             "flushed": do_flush.astype(jnp.int32),
             "buffer_landed": num_landed,
             "staleness": jnp.sum(jnp.where(landed, buf.age, 0)
                                  .astype(jnp.float32)) / nl_f,
-        }
+        })
         if fl.telemetry:
             # attribution follows the BUFFER rows (theta/weights are
             # computed over them), not this tick's candidates; ages and
@@ -1755,14 +1607,10 @@ def _make_sequential_round(loss_fn, fl: FLConfig, angle_pred=None,
                 if fl.method == "fedadp"
                 else psi_avg
             )
-        div = jnp.mean(jnp.sqrt(jnp.maximum(sqs - 2 * dots + sqgs, 0.0))) / lr
-        metrics = {
-            "loss": jnp.mean(losses if losses is not None else losses2),
-            "theta": theta, "theta_smoothed": theta_sm, "weights": w,
-            "divergence": div, "lr": lr, "cos": jnp.cos(theta),
-            "expected_contribution": weighting.expected_contribution(w, jnp.cos(theta)),
-            **counts,
-        }
+        metrics = _core_metrics(
+            losses if losses is not None else losses2, lr, dots, sqs, sqgs,
+            theta, theta_sm, w)
+        metrics.update(counts)
         if fl.telemetry:
             metrics.update(_telemetry_metrics(fl, params, sel_idx, w))
         return state._replace(

@@ -66,6 +66,15 @@ def update_smoothed_angle(
     return AngleState(smoothed=smoothed, count=new_count)
 
 
+def smoothed_angle(theta: jax.Array, smoothed_prev: jax.Array,
+                   count_prev: jax.Array) -> jax.Array:
+    """Eq. 9 on the selected clients' own slots: their smoothed angle
+    after this round, from the slots' values before it — the same float
+    ops `update_smoothed_angle` writes back. All (K,)."""
+    cnt = count_prev.astype(jnp.float32) + 1.0
+    return ((cnt - 1.0) * smoothed_prev + theta) / cnt
+
+
 def gompertz(theta: jax.Array, alpha: float = DEFAULT_ALPHA) -> jax.Array:
     """Non-linear contribution mapping f(theta), Eq. 10.
 

@@ -137,9 +137,10 @@ def _toy_problem(K=4, tau=3, B=8, d=12, seed=0):
     return params, loss_fn, (jnp.asarray(X), jnp.asarray(Y))
 
 
-def _run_rounds(cfg, rounds, sel, mesh=None):
+def _run_rounds(cfg, rounds, sel, mesh=None, angle_pred=None):
     params, loss_fn, batches = _toy_problem(K=len(sel))
-    rf = jax.jit(fl.make_round_fn(loss_fn, cfg, mesh=mesh))
+    rf = jax.jit(fl.make_round_fn(loss_fn, cfg, mesh=mesh,
+                                  angle_pred=angle_pred))
     st = fl.init_round_state(cfg, params)
     sizes = jnp.asarray([10.0 * (i + 1) for i in range(len(sel))])
     ws = []
@@ -149,36 +150,64 @@ def _run_rounds(cfg, rounds, sel, mesh=None):
     return st, ws
 
 
-# (engine, uplink, downlink, error_feedback, rounds, atol): f32 paths are
-# bit-exact over multiple rounds; quantized flat wires pin one round at
-# 1e-5 (fused-dequant vs dequant-then-aggregate fp reassociation).
+def _drop_b(keys, leaf):
+    """Angle filter: the toy's bias leaves the statistics."""
+    return keys[-1] != "b"
+
+
+# (engine, uplink, downlink, error_feedback, method, angle_pred, rounds,
+# atol): f32 paths are bit-exact over multiple rounds; quantized flat
+# wires pin one round at 1e-5 (fused-dequant vs dequant-then-aggregate fp
+# reassociation). Under FedAvg the flat engines' params stay bit-exact
+# but their angles, which weigh nothing there, differ by an ulp between
+# the two programs (XLA fuses the shared g differently).
 EQUIV_CASES = [
-    ("tree", "f32", "f32", False, 3, 0.0),
-    ("flat", "f32", "f32", False, 3, 0.0),
-    ("tree", "int8", "f32", True, 3, 0.0),
-    ("flat", "int4", "int8", False, 1, 1e-5),
-    ("flat_sharded", "int8", "int8", True, 1, 1e-5),
+    ("tree", "f32", "f32", False, "fedadp", None, 3, 0.0),
+    ("flat", "f32", "f32", False, "fedadp", None, 3, 0.0),
+    ("tree", "int8", "f32", True, "fedadp", None, 3, 0.0),
+    ("flat", "int4", "int8", False, "fedadp", None, 1, 1e-5),
+    ("flat_sharded", "int8", "int8", True, "fedadp", None, 1, 1e-5),
+    ("flat_sharded", "f32", "f32", False, "fedadp", None, 3, 0.0),
+    ("flat_sharded", "int4", "int8", False, "fedadp", None, 1, 1e-5),
+    ("tree", "f32", "f32", False, "fedavg", None, 3, 0.0),
+    ("flat", "f32", "f32", False, "fedavg", None, 3, 1e-6),
+    ("flat_sharded", "f32", "f32", False, "fedavg", None, 3, 1e-6),
+    ("tree", "f32", "f32", False, "fedadp", _drop_b, 3, 0.0),
+    ("flat", "f32", "f32", False, "fedadp", _drop_b, 3, 0.0),
+    ("flat_sharded", "f32", "f32", False, "fedadp", _drop_b, 3, 0.0),
 ]
 
 
-@pytest.mark.parametrize("engine,uplink,downlink,ef,rounds,atol",
-                         EQUIV_CASES)
+def _equiv_id(case):
+    engine, uplink, downlink, ef, method, pred, rounds, atol = case
+    tags = [engine, uplink, downlink, ef, rounds, atol]
+    tags += [method] if method != "fedadp" else []
+    tags += [pred.__name__.strip("_")] if pred else []
+    return "-".join(map(str, tags))
+
+
+@pytest.mark.parametrize(
+    "engine,uplink,downlink,ef,method,angle_pred,rounds,atol", EQUIV_CASES,
+    ids=[_equiv_id(c) for c in EQUIV_CASES])
 def test_buffered_full_cohort_matches_sync(engine, uplink, downlink, ef,
-                                           rounds, atol):
+                                           method, angle_pred, rounds, atol):
     """buffer_m == K with no stragglers admits, lands, and flushes the
-    whole cohort every tick — the sync round, engine by engine. Subset
-    selection (8-client population, explicit 3-cohort) exercises the
-    masked Eq. 9 scatter and the EF rows under buffering."""
+    whole cohort every tick — the sync round, engine by engine, through
+    the engine step both share. Subset selection (8-client population,
+    explicit 3-cohort) exercises the masked Eq. 9 scatter and the EF rows
+    under buffering."""
     mesh = make_mesh((1,), ("data",)) if engine == "flat_sharded" \
         else None
     base = dict(num_clients=8, clients_per_round=3, local_steps=3,
-                method="fedadp", base_lr=0.05, engine=engine,
+                method=method, base_lr=0.05, engine=engine,
                 transport=uplink, downlink=downlink, error_feedback=ef,
                 group_size=512)
     sel = [1, 4, 6]
-    st_s, w_s = _run_rounds(fl.FLConfig(**base), rounds, sel, mesh=mesh)
+    st_s, w_s = _run_rounds(fl.FLConfig(**base), rounds, sel, mesh=mesh,
+                            angle_pred=angle_pred)
     st_b, w_b = _run_rounds(
-        fl.FLConfig(**base, aggregation="buffered"), rounds, sel, mesh=mesh)
+        fl.FLConfig(**base, aggregation="buffered"), rounds, sel, mesh=mesh,
+        angle_pred=angle_pred)
     jax.tree.map(
         lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=0, atol=atol),

@@ -471,8 +471,10 @@ def test_flat_sharded_2d_keeps_sharded_leaves_sharded():
         psi = jnp.full((K,), 1.0 / K, jnp.float32)
         z = jnp.zeros((K,), jnp.float32)
         sizes = jnp.ones((K,), jnp.float32)
-        op = fl_shard_map.make_round_ops_2d(
-            mesh, deltas, pspecs, alpha=5.0, transport="int8")
+        from repro.core import weighting
+        op = fl_shard_map.make_round_ops(
+            mesh, weigh=lambda t, d: weighting.fedadp_weights(t, d, 5.0),
+            transport="int8", template=deltas, pspecs=pspecs)
         jop = jax.jit(op)
         g, dots, sqs, sqg, delta, theta, tsm, w = jop(deltas, psi, z, z, sizes)
         # (a) output sharding retains the model axis on the sharded leaf

@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from repro import transport
-from repro.core import fl, fl_shard_map, treemath
+from repro.core import fl, treemath
 import _oracle as oracle
 from repro.kernels import round_stats, weighted_agg
 from repro.launch.mesh import make_mesh
@@ -693,47 +693,6 @@ def test_sequential_mode_rejects_quantized_downlink():
                       mode="sequential", downlink="int8")
     with pytest.raises(ValueError, match="parallel"):
         fl.make_round_fn(loss_fn, cfg)
-
-
-def test_shard_map_tree_engine_rejects_quantized_transport():
-    """The ROADMAP contract: the tree engine never reads quantized buffers;
-    fedadp_aggregate must refuse rather than silently dequantize."""
-    mesh = make_mesh((1, 1), ("data", "model"))
-    from jax.sharding import PartitionSpec as P
-    with pytest.raises(ValueError, match="tree"):
-        fl_shard_map.fedadp_aggregate(mesh, {"a": P("data")}, alpha=5.0,
-                                      engine="tree", transport="int8")
-
-
-def test_shard_map_flat_engine_quantized_matches_f32_loosely():
-    """fedadp_aggregate(engine="flat", transport="int8"/"int4") on a 1-way
-    mesh: runs end-to-end and stays near the f32 wire (int4's bound scales
-    with its 16x coarser step)."""
-    from jax.sharding import PartitionSpec as P
-    mesh = make_mesh((1,), ("data",))
-    Kk = 4
-    deltas = {
-        "a": jax.random.normal(jax.random.key(0), (Kk, 8, 6)) * 0.1,
-        "b": jax.random.normal(jax.random.key(1), (Kk, 16)) * 0.1,
-    }
-    pspecs = {"a": P("data"), "b": P("data")}
-    sizes = jnp.asarray([10.0, 20.0, 30.0, 40.0])
-    sm_prev = jnp.zeros((Kk,))
-    cnt_prev = jnp.zeros((Kk,), jnp.int32)
-    outs = {}
-    for tr in ("f32", "int8", "int4"):
-        agg = fl_shard_map.fedadp_aggregate(mesh, pspecs, alpha=5.0,
-                                            engine="flat", transport=tr,
-                                            group_size=32)
-        with mesh:
-            outs[tr] = jax.jit(agg)(deltas, sizes, sm_prev, cnt_prev)
-    for tr, atol in (("int8", 5e-3), ("int4", 5e-2)):
-        jax.tree.map(
-            lambda a, b: np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), atol=atol),
-            outs["f32"][0], outs[tr][0])
-        np.testing.assert_allclose(np.asarray(outs["f32"][1]),
-                                   np.asarray(outs[tr][1]), atol=10 * atol)
 
 
 # ---------------------------------------------------------------------------
