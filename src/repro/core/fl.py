@@ -11,11 +11,15 @@ Two execution modes (DESIGN.md §6):
   This is the faithful high-throughput path for models that fit K-way.
 
 * ``sequential`` — one model copy (FSDP-shardable), clients advanced by
-  `lax.scan`. FedAdp needs the round's global gradient *before* weighting,
-  so the exact variant runs TWO passes (local training recomputed in pass
-  2 — compute x2, memory x1/K; no raveled copy of any delta). The key
-  identity making two (not three) passes suffice: softmax weights factor
-  as w_i = D_i e^{f(θ̃_i)} with a scalar denominator, so pass 2 can
+  `lax.scan`. FedAdp needs the round's global gradient *before* weighting.
+  Where the K clients' trained params fit beside the round's other trees
+  (`single_pass_fits`, from K, the params' bytes and the device's
+  limit), each client trains once and its trained params are kept; g,
+  the angles and the weighted sum are read from them afterwards.
+  Elsewhere the exact variant runs TWO passes (local training recomputed
+  in pass 2 — compute x2, memory x1/K; no raveled copy of any delta). The
+  key identity making two (not three) passes suffice: softmax weights
+  factor as w_i = D_i e^{f(θ̃_i)} with a scalar denominator, so pass 2 can
   accumulate Σ w_i Δ_i and Σ w_i online.
 
   ``stale_angles=True`` is the beyond-paper one-pass variant: angles are
@@ -100,9 +104,9 @@ class FLConfig:
     #            engines agree to 1e-5 per transport. error_feedback is
     #            incompatible with a quantized 2D wire (the residual is in
     #            global ravel order) and raises.
-    # The sequential mode's pass-2 statistics stream through the
-    # round_stats module's `leaf_stats` kernel, one call per leaf of each
-    # client's trained and starting params against the global delta.
+    # The sequential mode's statistics stream through the round_stats
+    # module's `leaf_stats` kernel, one call per leaf of each client's
+    # trained and starting params against the global delta.
     engine: str = "tree"  # tree | flat | flat_sharded
     # Delta transport — the client-uplink wire format (repro.transport):
     #   "f32"  — reference wire, deltas ship unmodified.
@@ -1485,18 +1489,64 @@ def _make_buffered_round(loss_fn, fl: FLConfig, step, delta_constraint,
     return round_fn
 
 
+# Full-size trees the single-pass sequential round holds besides its K
+# kept clients: the params, the FedAvg delta g (in `prev_delta`'s place)
+# and the training client's grads; a client trains in the slot it is
+# kept in.
+SINGLE_PASS_TREES = 3
+# The share of the device's limit left to the rest of the round:
+# activations, batches, the stats' leaf copies and the allocator's
+# fragmentation. Calibrated on dsv2lite's chip share (K = 4, 2.14 GB
+# trees): its 2-round block compiles for a v5e to 16,270,754,376 B, 7.6 %
+# of the chip's 16,909,336,064 B above its (K + 3) trees.
+SINGLE_PASS_MARGIN = 0.08
+
+
+def device_bytes_limit() -> Optional[int]:
+    """The local device's memory limit in bytes, or None where the device
+    reports none (the CPU)."""
+    stats = jax.local_devices()[0].memory_stats()
+    return (stats or {}).get("bytes_limit")
+
+
+def single_pass_fits(k: int, tree_bytes: int, limit: Optional[int]) -> bool:
+    """Whether the sequential round can keep all K clients' trained params
+    and train each once a round: (K + SINGLE_PASS_TREES) trees of
+    `tree_bytes` (the whole params tree, not one device's shard, so an
+    FSDP mesh errs toward two passes) within `limit` less
+    SINGLE_PASS_MARGIN of it. A device that reports no limit (`limit`
+    None, the CPU) gets the two-pass round: nothing says K trees fit."""
+    if not limit:
+        return False
+    return (k + SINGLE_PASS_TREES) * tree_bytes <= (
+        1.0 - SINGLE_PASS_MARGIN) * limit
+
+
 def _make_sequential_round(loss_fn, fl: FLConfig, angle_pred=None,
                            grad_constraint=None):
     """The round that trains one client at a time, for a model of which
-    the chip holds one training copy. Besides the params and
-    `prev_delta` it holds three full-size f32 trees: the weighted sum
-    and the training client's params and grads; no client's delta is
-    ever written out. Pass 1 accumulates the FedAvg delta g in what
-    becomes `prev_delta`; pass 2 retrains the same clients on the same
-    batches, streams each client's statistics against g leaf by leaf
-    through `round_stats.leaf_stats` (from its trained and its starting
-    params), and accumulates the Gompertz-weighted sum. A loss with
-    counters (`loss_fn.has_aux`) adds them, summed over both passes, to
+    the chip holds one training copy. No client's delta is ever written
+    out: deltas are read as (trained params - params), leaf by leaf, and
+    the statistics stream through `round_stats.leaf_stats`. Which
+    schedule runs follows from K, the params' bytes and the device's
+    limit (`single_pass_fits`, at trace time):
+
+    - single pass: each client trains once and its trained params are
+      kept, K trees in the params' dtype (the last one where it trained).
+      g, each client's statistics against g, Eq. 9, the weights and the
+      weighted sum are then read from the kept trees, in client order
+      and with the two-pass round's float ops.
+    - two passes, where the K trees would not fit: pass 1 accumulates the
+      FedAvg delta g in what becomes `prev_delta`; pass 2 retrains the
+      same clients on the same batches, streams each client's statistics
+      against g and accumulates the Gompertz-weighted sum. Besides the
+      params and `prev_delta` it holds three full-size f32 trees: the
+      weighted sum and the training client's params and grads.
+
+    `stale_angles` measures angles against the previous round's g in one
+    pass of the second kind. The metrics' `train_passes` counts how often
+    the round trained its clients (1.0 or 2.0). A loss with counters
+    (`loss_fn.has_aux`) adds them, summed over every client trained, to
     the round's metrics."""
     has_aux = getattr(loss_fn, "has_aux", False)
 
@@ -1530,6 +1580,13 @@ def _make_sequential_round(loss_fn, fl: FLConfig, angle_pred=None,
     def add(a, b):
         return jax.tree.map(jnp.add, a, b)
 
+    def apply(params, num, den):
+        """params + num / den, cast back to the params' dtypes."""
+        delta = treemath.tree_scale(num, 1.0 / jnp.maximum(den, 1e-12))
+        return jax.tree.map(
+            lambda p, d: (p.astype(jnp.float32) + d).astype(p.dtype),
+            params, delta)
+
     def round_fn(state: RoundState, batches, sel_idx, data_sizes):
         params, angle_state = state.params, state.angle
         lr = _lr_at(fl, state.round)
@@ -1545,60 +1602,116 @@ def _make_sequential_round(loss_fn, fl: FLConfig, angle_pred=None,
             counts0 = jax.tree.map(
                 jnp.zeros_like, jax.eval_shape(loss_fn, params, step0)[1])
 
-        if not fl.stale_angles:
-            # ---- pass 1: global (FedAvg-weighted) delta ----
-            def p1(carry, xs):
-                acc, counts = carry
-                b_i, psi_i = xs
+        def smoothed(dot, sq, sqg, idx):
+            """Eq. 8's angle and Eq. 9's mean of the clients in `idx`."""
+            theta = weighting.instantaneous_angle(dot, sq, sqg)
+            return theta, weighting.smoothed_angle(
+                theta, angle_state.smoothed[idx], angle_state.count[idx])
+
+        def unnormalised(sm, D):
+            """The weights before normalisation: D e^{f(sm)}, or D."""
+            if fl.method == "fedadp":
+                return D * jnp.exp(weighting.gompertz(sm, fl.alpha))
+            return D
+
+        k = sel_idx.shape[0]
+        tree_bytes = sum(p.size * p.dtype.itemsize
+                         for p in jax.tree.leaves(params))
+        single = not fl.stale_angles and single_pass_fits(
+            k, tree_bytes, device_bytes_limit())
+
+        if single:
+            # ---- one pass: train each client once and keep what it
+            # trained, the K - 1 first stacked and the K-th where it
+            # trained. Client i writes client i - 1's params into the
+            # stack before it trains (the barrier orders the two), so it
+            # trains in the slot they leave. ----
+            def p0(carry, xs):
+                kept, last, counts = carry
+                b_i, i = xs
+                if k > 1:
+                    kept = jax.tree.map(
+                        lambda s, l: jax.lax.dynamic_update_index_in_dim(
+                            s, l, jnp.maximum(i - 1, 0), 0), kept, last)
+                kept, start = jax.lax.optimization_barrier((kept, params))
+                with jax.named_scope("fl.local_train"):
+                    p_i, loss, c_i = train(start, b_i, lr)
+                return (kept, p_i, add(counts, c_i)), loss
+
+            kept0 = jax.tree.map(
+                lambda p: jnp.zeros((k - 1,) + p.shape, p.dtype), params)
+            (kept, p_k, counts), losses = jax.lax.scan(
+                p0, (kept0, params, counts0),
+                (batches, jnp.arange(k, dtype=jnp.int32)))
+            trained = [jax.tree.map(lambda a, i=i: a[i], kept)
+                       for i in range(k - 1)] + [p_k]
+
+            with jax.named_scope("fl.stats"):
+                g_ref = zeros32
+                for i, p_i in enumerate(trained):
+                    g_ref = axpy_delta(psi_avg[i], p_i, params, g_ref)
+                dots, sqs, sqgs = (jnp.stack(s) for s in zip(
+                    *(stats(p_i, params, g_ref, keep) for p_i in trained)))
+                theta, theta_sm = smoothed(dots, sqs, sqgs, sel_idx)
+            with jax.named_scope("fl.weights"):
+                w_un = unnormalised(theta_sm, data_sizes.astype(jnp.float32))
+            with jax.named_scope("fl.aggregate"):
+                num, den = zeros32, jnp.zeros((), jnp.float32)
+                for i, p_i in enumerate(trained):
+                    num = axpy_delta(w_un[i], p_i, params, num)
+                    den = den + w_un[i]
+                new_params = apply(params, num, den)
+            new_prev, passes = g_ref, 1.0
+        else:
+            if not fl.stale_angles:
+                # ---- pass 1: global (FedAvg-weighted) delta ----
+                def p1(carry, xs):
+                    acc, counts = carry
+                    b_i, psi_i = xs
+                    with jax.named_scope("fl.local_train"):
+                        p_i, loss, c_i = train(params, b_i, lr)
+                    with jax.named_scope("fl.stats"):
+                        acc = axpy_delta(psi_i, p_i, params, acc)
+                    return (acc, add(counts, c_i)), loss
+
+                (g_ref, counts0), losses = jax.lax.scan(
+                    p1, (zeros32, counts0), (batches, psi_avg))
+                g_acc0 = None
+            else:
+                g_ref = state.prev_delta
+                losses = None
+                g_acc0 = zeros32
+
+            # ---- pass 2 (or single stale pass): stats + online weighted
+            # sum ----
+            def p2(carry, xs):
+                num, den, g_acc, counts = carry
+                b_i, psi_i, D_i, idx_i = xs
                 with jax.named_scope("fl.local_train"):
                     p_i, loss, c_i = train(params, b_i, lr)
                 with jax.named_scope("fl.stats"):
-                    acc = axpy_delta(psi_i, p_i, params, acc)
-                return (acc, add(counts, c_i)), loss
+                    dot, sq, sqg_i = stats(p_i, params, g_ref, keep)
+                    theta_i, sm = smoothed(dot, sq, sqg_i, idx_i)
+                with jax.named_scope("fl.weights"):
+                    w_i = unnormalised(sm, D_i)
+                with jax.named_scope("fl.aggregate"):
+                    num = axpy_delta(w_i, p_i, params, num)
+                    if g_acc is not None:  # the stale pass's FedAvg delta
+                        g_acc = axpy_delta(psi_i, p_i, params, g_acc)
+                return ((num, den + w_i, g_acc, add(counts, c_i)),
+                        (theta_i, sm, dot, sq, sqg_i, loss))
 
-            (g_ref, counts0), losses = jax.lax.scan(
-                p1, (zeros32, counts0), (batches, psi_avg))
-            g_acc0 = None
-        else:
-            g_ref = state.prev_delta
-            losses = None
-            g_acc0 = zeros32
-
-        # ---- pass 2 (or single stale pass): stats + online weighted sum ----
-        def p2(carry, xs):
-            num, den, g_acc, counts = carry
-            b_i, psi_i, D_i, idx_i = xs
-            with jax.named_scope("fl.local_train"):
-                p_i, loss, c_i = train(params, b_i, lr)
-            with jax.named_scope("fl.stats"):
-                dot, sq, sqg_i = stats(p_i, params, g_ref, keep)
-                theta_i = weighting.instantaneous_angle(dot, sq, sqg_i)
-                cnt = angle_state.count[idx_i].astype(jnp.float32) + 1.0
-                sm = ((cnt - 1.0) * angle_state.smoothed[idx_i]
-                      + theta_i) / cnt
-            with jax.named_scope("fl.weights"):
-                if fl.method == "fedadp":
-                    w_i = D_i * jnp.exp(weighting.gompertz(sm, fl.alpha))
-                else:
-                    w_i = D_i
-            with jax.named_scope("fl.aggregate"):
-                num = axpy_delta(w_i, p_i, params, num)
-                if g_acc is not None:  # the stale pass's FedAvg delta
-                    g_acc = axpy_delta(psi_i, p_i, params, g_acc)
-            return ((num, den + w_i, g_acc, add(counts, c_i)),
-                    (theta_i, sm, dot, sq, sqg_i, loss))
-
-        (num, den, g_acc, counts), ys = jax.lax.scan(
-            p2, (zeros32, jnp.zeros((), jnp.float32), g_acc0, counts0),
-            (batches, psi_avg, data_sizes.astype(jnp.float32), sel_idx),
-        )
-        theta, theta_sm, dots, sqs, sqgs, losses2 = ys
-        with jax.named_scope("fl.aggregate"):
-            delta = treemath.tree_scale(num, 1.0 / jnp.maximum(den, 1e-12))
-            new_params = jax.tree.map(
-                lambda p, d: (p.astype(jnp.float32) + d).astype(p.dtype),
-                params, delta
+            (num, den, g_acc, counts), ys = jax.lax.scan(
+                p2, (zeros32, jnp.zeros((), jnp.float32), g_acc0, counts0),
+                (batches, psi_avg, data_sizes.astype(jnp.float32), sel_idx),
             )
+            theta, theta_sm, dots, sqs, sqgs, losses2 = ys
+            with jax.named_scope("fl.aggregate"):
+                new_params = apply(params, num, den)
+            if fl.stale_angles:
+                losses, new_prev, passes = losses2, g_acc, 1.0
+            else:
+                new_prev, passes = g_ref, 2.0
         with jax.named_scope("fl.stats"):
             new_state = _scatter_angles(angle_state, sel_idx, theta)
         with jax.named_scope("fl.weights"):
@@ -1607,15 +1720,14 @@ def _make_sequential_round(loss_fn, fl: FLConfig, angle_pred=None,
                 if fl.method == "fedadp"
                 else psi_avg
             )
-        metrics = _core_metrics(
-            losses if losses is not None else losses2, lr, dots, sqs, sqgs,
-            theta, theta_sm, w)
+        metrics = _core_metrics(losses, lr, dots, sqs, sqgs, theta,
+                                theta_sm, w)
         metrics.update(counts)
+        metrics["train_passes"] = jnp.float32(passes)
         if fl.telemetry:
             metrics.update(_telemetry_metrics(fl, params, sel_idx, w))
         return state._replace(
-            params=new_params, angle=new_state,
-            prev_delta=g_ref if g_acc is None else g_acc,
+            params=new_params, angle=new_state, prev_delta=new_prev,
             round=state.round + 1,
         ), metrics
 

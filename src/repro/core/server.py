@@ -79,11 +79,15 @@ class History:
     weights: list
     # a language model's (`clients.lm_client`): per round, the token-
     # expert rows through the held experts' products in local training
-    # (both passes of the sequential round); per eval round, the eval's
-    # mean loss and its expert rows
+    # (every client trained, in each pass the sequential round ran); per
+    # eval round, the eval's mean loss and its expert rows
     expert_rows: list = dataclasses.field(default_factory=list)
     eval_loss: list = dataclasses.field(default_factory=list)
     eval_expert_rows: list = dataclasses.field(default_factory=list)
+    # the sequential round's: per round, how often it trained its clients
+    # (1.0: once, its trained params kept; 2.0: trained again for the
+    # angles)
+    train_passes: list = dataclasses.field(default_factory=list)
 
 
 class FedServer:
@@ -352,6 +356,8 @@ class FedServer:
         hist.weights.append(np.asarray(m["weights"]))
         if "expert_rows" in m:
             hist.expert_rows.append(int(m["expert_rows"]))
+        if "train_passes" in m:
+            hist.train_passes.append(float(m["train_passes"]))
 
     @staticmethod
     def _append_eval(hist: History, m: dict) -> None:
